@@ -229,6 +229,8 @@ def main(argv=None) -> int:
         import tempfile
 
         from benchmarks import serving_throughput
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
         with tempfile.NamedTemporaryFile(suffix=".json", mode="r") as tmp:
             serving_throughput.run(fast=True, json_path=tmp.name)
             cand = json.load(tmp)

@@ -24,6 +24,8 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     fast = not args.full
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (fig2_fidelity, fig3_scaling, roofline_report,
                             serving_throughput, table1_accuracy,
                             table2_granularity, table3_throughput)

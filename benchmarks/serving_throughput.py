@@ -974,6 +974,8 @@ def main():
     args = ap.parse_args()
     if args.arrival_rate <= 0:
         ap.error(f"--arrival-rate must be > 0, got {args.arrival_rate}")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     run(fast=not args.full, engines=args.engine, json_path=args.json,
         arrival_rate=args.arrival_rate)
 

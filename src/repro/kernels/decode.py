@@ -160,11 +160,41 @@ def _decode_kernel(scale_ref, theta_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                  sm_denom=sm_denom)
 
 
-def _paged_kernel(tbl_ref, len_ref, scale_ref, theta_ref, ks_ref, vs_ref,
-                  q_ref, k_ref, v_ref, o_ref, m_scr, z_scr, acc_scr, *,
-                  num_kv: int, group: int, block_size: int, block_k: int,
-                  mode: str, static_max: bool, sm_denom: float,
-                  quantized: bool):
+_SC_TILE = 8 * 128      # flattened scales per (8, 128) f32 VMEM tile
+
+
+def _flat_scales(scales):
+    """(N, Hkv) per-block dequant scales -> lane-dense (rows, 128) f32, row-
+    major over (block, kv head) and zero-padded to whole (8, 128) tiles.
+    Scale (e, kv) sits at flat index e * Hkv + kv."""
+    flat = scales.astype(jnp.float32).reshape(-1)
+    return jnp.pad(flat, (0, -flat.size % _SC_TILE)).reshape(-1, 128)
+
+
+def _scale_at(tile_ref, f):
+    """Flat scale f, read from the (8, 128) tile holding it, as a (1, 1)
+    value. The sum adds exact zeros to the one selected element, so the
+    value is bit-identical to an XLA gather of it."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    hit = rows * 128 + lanes == jax.lax.rem(f, _SC_TILE)
+    return jnp.sum(jnp.where(hit, tile_ref[...], 0.0), keepdims=True)
+
+
+def _tile_scales(scale_refs, entry, kv, num_kv: int):
+    """This tile's (k, v) dequant scales on an int8 pool, else (None, None).
+    A dead entry reads block 0's, which the masked tile never uses."""
+    if not scale_refs:
+        return None, None
+    f = jnp.maximum(entry, 0) * num_kv + kv
+    return tuple(_scale_at(r, f) for r in scale_refs)
+
+
+def _paged_kernel(tbl_ref, len_ref, scale_ref, theta_ref, q_ref, k_ref,
+                  v_ref, *rest, num_kv: int, group: int, block_size: int,
+                  block_k: int, mode: str, static_max: bool,
+                  sm_denom: float):
+    *scale_refs, o_ref, m_scr, z_scr, acc_scr = rest
     i = pl.program_id(0)                      # slot * num_kv + kv head
     ki = pl.program_id(2)                     # sub-tile of a table entry
     slot = i // num_kv
@@ -174,12 +204,7 @@ def _paged_kernel(tbl_ref, len_ref, scale_ref, theta_ref, ks_ref, vs_ref,
     entry = tbl_ref[slot, ti]                 # pool block id, -1 = dead
     nk = len_ref[slot]
     col0 = ti * block_size + jax.lax.rem(ki, per) * block_k
-    k_s = v_s = None
-    if quantized:
-        # per-(block, kv-head) dequant scalars for this tile; dead entries
-        # clamp to block 0 — the tile is never read (block_live is False)
-        e = jnp.maximum(entry, 0)
-        k_s, v_s = ks_ref[e, kv], vs_ref[e, kv]
+    k_s, v_s = _tile_scales(scale_refs, entry, kv, num_kv)
     # dead-block skip: a sentinel table entry is the paged analogue of the
     # dense kernel's past-the-frontier block (same pl.when skip path); the
     # frontier check also covers trailing sub-tiles of a partially-filled
@@ -191,11 +216,11 @@ def _paged_kernel(tbl_ref, len_ref, scale_ref, theta_ref, ks_ref, vs_ref,
                  sm_denom=sm_denom, k_scale=k_s, v_scale=v_s)
 
 
-def _packed_kernel(sid_ref, tbl_ref, len_ref, scale_ref, theta_ref, ks_ref,
-                   vs_ref, q_ref, k_ref, v_ref, o_ref, m_scr, z_scr, acc_scr,
-                   *, num_kv: int, group: int, block_size: int, block_k: int,
-                   mode: str, static_max: bool, sm_denom: float,
-                   quantized: bool):
+def _packed_kernel(sid_ref, tbl_ref, len_ref, scale_ref, theta_ref, q_ref,
+                   k_ref, v_ref, *rest, num_kv: int, group: int,
+                   block_size: int, block_k: int, mode: str,
+                   static_max: bool, sm_denom: float):
+    *scale_refs, o_ref, m_scr, z_scr, acc_scr = rest
     i = pl.program_id(0)                      # token * num_kv + kv head
     ki = pl.program_id(2)                     # sub-tile of a table entry
     tok = i // num_kv
@@ -206,10 +231,7 @@ def _packed_kernel(sid_ref, tbl_ref, len_ref, scale_ref, theta_ref, ks_ref,
     entry = tbl_ref[jnp.maximum(slot, 0), ti]
     nk = len_ref[tok]                         # per-TOKEN causal frontier
     col0 = ti * block_size + jax.lax.rem(ki, per) * block_k
-    k_s = v_s = None
-    if quantized:
-        e = jnp.maximum(entry, 0)
-        k_s, v_s = ks_ref[e, kv], vs_ref[e, kv]
+    k_s, v_s = _tile_scales(scale_refs, entry, kv, num_kv)
     # a pad lane (slot < 0) is a whole-row dead block: every tile skipped,
     # the epilogue still writes zeros (acc/z are zeroed unconditionally)
     _decode_tile(scale_ref, theta_ref, q_ref, k_ref, v_ref, o_ref,
@@ -311,6 +333,31 @@ def hccs_decode(q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array,
     return out[:, :, :d].reshape(b, h, d)
 
 
+def _pool_operands(tile, g: int, d_pad: int, bk: int, per: int, qp, kp, vp,
+                   k_scales, v_scales):
+    """BlockSpecs and operands (q, k, v[, k_scales, v_scales]) of the two
+    block-table kernels. `tile(i, ki, *prefetch_refs)` names the (pool block,
+    kv head) that grid step (i, ki) reads. An int8 pool's (N, Hkv) scales are
+    read through that same steer, as the (8, 128) VMEM tile of _flat_scales
+    holding the pair's scale, so nothing the size of the pool lands in SMEM
+    (SMEM pads an (N, Hkv) array's minor dimension to 128)."""
+    hkv = kp.shape[1]
+    kv = pl.BlockSpec((1, 1, bk, d_pad), lambda i, ph, ki, *refs: (
+        *tile(i, ki, *refs), jax.lax.rem(ki, per), 0))
+    in_specs = [pl.BlockSpec((1, g, d_pad), lambda i, ph, ki, *_: (i, 0, 0)),
+                kv, kv]
+    operands = [qp, kp, vp]
+    if k_scales is not None:
+        def sc_map(i, ph, ki, *refs):
+            e, kv_head = tile(i, ki, *refs)
+            return (e * hkv + kv_head) // _SC_TILE, 0
+
+        sc = pl.BlockSpec((8, 128), sc_map)
+        in_specs += [sc, sc]
+        operands += [_flat_scales(k_scales), _flat_scales(v_scales)]
+    return in_specs, operands
+
+
 @functools.partial(jax.jit, static_argnames=("mode", "static_max", "block_k",
                                              "interpret"))
 def hccs_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -334,8 +381,9 @@ def hccs_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     same pl.when path as the dense kernel's dead blocks); lengths: (B,) valid
     logical-KV counts; scale: (H,) f32; theta: (H, 3) int32.
     With kv_quant="int8" pools, `k_scales`/`v_scales` (N, Hkv) f32 carry the
-    per-block, per-kv-head dequant scales (scalar-prefetched alongside the
-    table); each KV tile is dequantized in-register after the load.
+    per-block, per-kv-head dequant scales (each read per tile through the
+    same block-table steer as its KV tile); each KV tile is dequantized
+    in-register after the load.
     Returns (B, H, d) in q.dtype. Rows with lengths == 0 return zeros.
     """
     b, h, d = q.shape
@@ -352,46 +400,31 @@ def hccs_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     nblk = block_table.shape[1]
     num_phases = 1 if static_max else 2
     grid = (b * hkv, num_phases, nblk * per)
-    quantized = k_scales is not None
-    if not quantized:                         # placeholder prefetch operands:
-        k_scales = v_scales = jnp.zeros((1, 1), jnp.float32)  # never read
 
-    def kv_spec():
+    def tile(i, ki, tbl, *_):
         # the block-table gather: sentinel entries are clamped to pool block
         # 0 so the DMA has a valid source; the kernel body never reads the
         # tile (block_live is False), so the clamp is semantically inert
-        return pl.BlockSpec(
-            (1, 1, bk, d_pad),
-            lambda i, ph, ki, tbl, ln, sc, th, ks, vs, KV=hkv, PER=per: (
-                jnp.maximum(tbl[i // KV, ki // PER], 0),
-                jax.lax.rem(i, KV), jax.lax.rem(ki, PER), 0))
+        return jnp.maximum(tbl[i // hkv, ki // per], 0), jax.lax.rem(i, hkv)
 
+    in_specs, operands = _pool_operands(tile, g, d_pad, bk, per, qp, kp, vp,
+                                        k_scales, v_scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,      # table, lengths, scale, theta, ks, vs
+        num_scalar_prefetch=4,      # table, lengths, scale, theta
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, g, d_pad),
-                         lambda i, ph, ki, tbl, ln, sc, th, ks, vs:
-                         (i, 0, 0)),
-            kv_spec(),
-            kv_spec(),
-        ],
-        out_specs=pl.BlockSpec((1, g, d_pad),
-                               lambda i, ph, ki, tbl, ln, sc, th, ks, vs:
-                               (i, 0, 0)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, g, d_pad), lambda i, ph, ki, *_: (i, 0, 0)),
         scratch_shapes=_decode_scratch(g, d_pad),
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, num_kv=hkv, group=g, block_size=bs,
                           block_k=bk, mode=mode, static_max=static_max,
-                          sm_denom=sm_denom, quantized=quantized),
+                          sm_denom=sm_denom),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, d_pad), q.dtype),
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      scale.astype(jnp.float32), theta.astype(jnp.int32),
-      k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-      qp, kp, vp)
+      scale.astype(jnp.float32), theta.astype(jnp.int32), *operands)
     return out[:, :, :d].reshape(b, h, d)
 
 
@@ -438,45 +471,31 @@ def hccs_packed_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     nblk = block_table.shape[1]
     num_phases = 1 if static_max else 2
     grid = (t * hkv, num_phases, nblk * per)
-    quantized = k_scales is not None
-    if not quantized:                         # placeholder prefetch operands:
-        k_scales = v_scales = jnp.zeros((1, 1), jnp.float32)  # never read
 
-    def kv_spec():
+    def tile(i, ki, sid, tbl, *_):
         # the slot-indirect block-table gather: pad lanes clamp to slot 0 and
         # sentinel entries to pool block 0 so the DMA has a valid source; the
         # kernel body never reads those tiles (block_live is False)
-        return pl.BlockSpec(
-            (1, 1, bk, d_pad),
-            lambda i, ph, ki, sid, tbl, ln, sc, th, ks, vs, KV=hkv, PER=per: (
-                jnp.maximum(
-                    tbl[jnp.maximum(sid[i // KV], 0), ki // PER], 0),
-                jax.lax.rem(i, KV), jax.lax.rem(ki, PER), 0))
+        slot = jnp.maximum(sid[i // hkv], 0)
+        return jnp.maximum(tbl[slot, ki // per], 0), jax.lax.rem(i, hkv)
 
+    in_specs, operands = _pool_operands(tile, g, d_pad, bk, per, qp, kp, vp,
+                                        k_scales, v_scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,     # sid, table, lengths, scale, theta, ks, vs
+        num_scalar_prefetch=5,      # sid, table, lengths, scale, theta
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, g, d_pad),
-                         lambda i, ph, ki, sid, tbl, ln, sc, th, ks, vs:
-                         (i, 0, 0)),
-            kv_spec(),
-            kv_spec(),
-        ],
-        out_specs=pl.BlockSpec((1, g, d_pad),
-                               lambda i, ph, ki, sid, tbl, ln, sc, th, ks, vs:
-                               (i, 0, 0)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, g, d_pad), lambda i, ph, ki, *_: (i, 0, 0)),
         scratch_shapes=_decode_scratch(g, d_pad),
     )
     out = pl.pallas_call(
         functools.partial(_packed_kernel, num_kv=hkv, group=g, block_size=bs,
                           block_k=bk, mode=mode, static_max=static_max,
-                          sm_denom=sm_denom, quantized=quantized),
+                          sm_denom=sm_denom),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t * hkv, g, d_pad), q.dtype),
         interpret=interpret,
     )(slot_ids.astype(jnp.int32), block_table.astype(jnp.int32),
       lengths.astype(jnp.int32), scale.astype(jnp.float32),
-      theta.astype(jnp.int32), k_scales.astype(jnp.float32),
-      v_scales.astype(jnp.float32), qp, kp, vp)
+      theta.astype(jnp.int32), *operands)
     return out[:, :, :d].reshape(t, h, d)

@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-interpret defaults to True off-TPU (this container is CPU-only; the kernels are
-validated bit-exactly in interpret mode and lower to Mosaic on real TPUs).
+The kernels lower to Mosaic on a TPU and run in Pallas interpret mode on the
+CPU, where the tests validate them bit-exactly. Any other backend raises.
 """
 from __future__ import annotations
 
@@ -17,7 +17,12 @@ from repro.kernels.decode import hccs_packed_prefill as _hccs_packed_prefill
 
 
 def _interp() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run on a TPU, or on the CPU in interpret "
+            f"mode; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def hccs_softmax(x_int8: jax.Array, theta: jax.Array, mode: str = "i16_div",

@@ -220,9 +220,6 @@ def build_cell(arch: str, shape_name: str, mesh, prob: str | None = None,
 def _measure(lowered) -> dict:
     compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    # jax < 0.5 returns a one-element list of dicts; >= 0.5 a flat dict
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     coll = RL.collective_bytes(compiled.as_text())
     return dict(flops=float(ca.get("flops", 0.0)),
                 bytes=float(ca.get("bytes accessed", 0.0)),

@@ -28,7 +28,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         dev_array = np.asarray(devices).reshape(shape)
         from jax.sharding import Mesh
         return Mesh(dev_array, axes)
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model's with_sharding_constraint calls need them, and
+    # make_mesh defaults to Explicit
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):

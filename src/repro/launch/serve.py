@@ -154,6 +154,8 @@ def main():
                          "queueing (continuous/paged scheduler, single-turn "
                          "only); implies --telemetry")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if (args.prefix_sharing or args.decode_sharing) \
             and args.scheduler != "paged":
         raise SystemExit("--prefix-sharing/--decode-sharing require "

@@ -5,27 +5,11 @@ when launched under a multi-device environment.
         --steps 200 --batch 8 --seq 256 --reduced
 
 --reduced uses the smoke-scale config (CPU-friendly); without it the full
-config is used (requires a real TPU slice). XLA latency-hiding flags for
-compute/communication overlap are set for TPU backends.
+config is used (requires a real TPU slice).
 """
 from __future__ import annotations
 
 import argparse
-import os
-
-
-def _tpu_overlap_flags():
-    """Collective/compute overlap: enable XLA's latency-hiding scheduler and
-    async collectives (the standard production knobs for hiding ICI time)."""
-    flags = [
-        "--xla_tpu_enable_async_collective_fusion=true",
-        "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-        "--xla_tpu_overlap_compute_collective_tc=true",
-        "--xla_enable_async_all_gather=true",
-        "--xla_enable_async_collective_permute=true",
-    ]
-    os.environ["LIBTPU_INIT_ARGS"] = (
-        os.environ.get("LIBTPU_INIT_ARGS", "") + " " + " ".join(flags))
 
 
 def main():
@@ -43,10 +27,9 @@ def main():
     ap.add_argument("--data", default="data")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     import jax
-    if jax.default_backend() == "tpu":
-        _tpu_overlap_flags()
-
     import jax.numpy as jnp
 
     from repro.configs import get_config, reduced_config
