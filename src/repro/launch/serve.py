@@ -19,14 +19,16 @@ behind; greedy outputs are token-identical to the synchronous loop:
 
 Telemetry (serve/telemetry.py): `--telemetry` records request lifecycles
 (TTFT/TPOT/E2E percentiles) and a per-step phase breakdown and prints the
-unified snapshot; `--trace-out trace.jsonl` additionally writes the step
-phases as Chrome-trace JSONL (open in Perfetto / chrome://tracing);
-`--arrival-rate R` replaces the batch-drain demo with an OPEN-LOOP load
-test — requests arrive on a seeded Poisson process at R req/s and latency
-percentiles are measured under genuine queueing:
+unified snapshot; `--trace-out DIR` serves inside a `jax.profiler.trace(DIR)`
+session, so DIR receives a profiler trace (`.xplane.pb`) holding the
+engine's `serve.step` / `serve.<phase>` spans and every device op on one
+clock (open it in Perfetto or TensorBoard's profiler); `--arrival-rate R`
+replaces the batch-drain demo with an OPEN-LOOP load test — requests arrive
+on a seeded Poisson process at R req/s and latency percentiles are measured
+under genuine queueing:
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b --reduced \
-        --scheduler paged --arrival-rate 16 --trace-out trace.jsonl
+        --scheduler paged --arrival-rate 16 --trace-out /tmp/serve-trace
 
 Overload robustness (serve/admission.py; strictly opt-in): mixed priority
 classes, per-request E2E deadlines, a bounded queue with backpressure, and
@@ -48,6 +50,7 @@ at the end:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 
@@ -143,10 +146,11 @@ def main():
                     help="record request lifecycles (TTFT/TPOT/E2E "
                          "percentiles) and per-step phase timings, and print "
                          "the unified telemetry snapshot after serving")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write the step-phase timeline as Chrome-trace "
-                         "JSONL to PATH (load in Perfetto or "
-                         "chrome://tracing); implies --telemetry")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="serve inside a jax.profiler trace written to DIR: "
+                         "the engine's serve.step / serve.<phase> spans and "
+                         "every device op on one clock (open in Perfetto or "
+                         "TensorBoard's profiler)")
     ap.add_argument("--arrival-rate", type=float, default=0.0, metavar="R",
                     help="serve OPEN-LOOP: requests arrive on a seeded "
                          "Poisson process at R req/s instead of being "
@@ -215,8 +219,7 @@ def main():
     if args.chaos is not None and (args.turns > 1 or args.arrival_rate):
         raise SystemExit("--chaos drives its own submission schedule; drop "
                          "--turns/--arrival-rate")
-    telemetry_on = bool(args.telemetry or args.trace_out
-                        or args.arrival_rate)
+    telemetry_on = bool(args.telemetry or args.arrival_rate)
 
     import jax
     import numpy as np
@@ -295,98 +298,109 @@ def main():
             kw["deadline_e2e"] = args.deadline_ms / 1000.0
         return kw
 
-    if args.chaos is not None:
-        crng = np.random.default_rng(args.chaos)
+    # --trace-out: a profiler session around the serving itself, so the
+    # trace holds the engine's serve.* spans beside every device op
+    tracing = (jax.profiler.trace(args.trace_out) if args.trace_out
+               else contextlib.nullcontext())
+    with tracing:
+        if args.chaos is not None:
+            crng = np.random.default_rng(args.chaos)
 
-        def mk(i):
-            plen = int(crng.integers(4, args.prompt_len + 1))
-            return Request(
-                uid=i,
-                prompt=crng.integers(0, cfg.vocab_size,
-                                     plen).astype(np.int32),
-                max_new_tokens=int(crng.integers(2, args.new_tokens + 1)),
-                **robust_kw(i))
+            def mk(i):
+                plen = int(crng.integers(4, args.prompt_len + 1))
+                return Request(
+                    uid=i,
+                    prompt=crng.integers(0, cfg.vocab_size,
+                                         plen).astype(np.int32),
+                    max_new_tokens=int(crng.integers(2, args.new_tokens + 1)),
+                    **robust_kw(i))
 
-        t0 = time.perf_counter()
-        report = ChaosMonkey(eng, seed=args.chaos, make_request=mk,
-                             n_requests=args.requests).run()
-        dt = time.perf_counter() - t0
-        done = report["finished"] + report["failed"]
-        faults = ", ".join(f"{k} x{v}"
-                           for k, v in sorted(report["faults"].items()))
-        print(f"chaos(seed={args.chaos}): survived {report['steps']} steps "
-              f"in {dt:.2f}s — {report['submitted']} submitted, "
-              f"{len(report['finished'])} finished, "
-              f"{len(report['failed'])} failed; "
-              f"faults: {faults or 'none injected'}")
-        print("invariants held after every step; pool fully reclaimed")
-    elif args.turns > 1:
-        # multi-turn demo: each "request" is a chat session; every turn
-        # submits a fresh user message on top of the engine-stored history,
-        # so with --decode-sharing the follow-up turns prefix-match prior
-        # prompts AND replies instead of re-prefilling them
-        t0 = time.perf_counter()
-        done = []
-        for turn in range(args.turns):
+            t0 = time.perf_counter()
+            report = ChaosMonkey(eng, seed=args.chaos, make_request=mk,
+                                 n_requests=args.requests).run()
+            dt = time.perf_counter() - t0
+            done = report["finished"] + report["failed"]
+            faults = ", ".join(f"{k} x{v}"
+                               for k, v in sorted(report["faults"].items()))
+            print(f"chaos(seed={args.chaos}): survived {report['steps']} "
+                  f"steps in {dt:.2f}s — {report['submitted']} submitted, "
+                  f"{len(report['finished'])} finished, "
+                  f"{len(report['failed'])} failed; "
+                  f"faults: {faults or 'none injected'}")
+            print("invariants held after every step; pool fully reclaimed")
+        elif args.turns > 1:
+            # multi-turn demo: each "request" is a chat session; every
+            # turn submits a fresh user message on top of the engine-stored
+            # history, so with --decode-sharing the follow-up turns
+            # prefix-match prior prompts AND replies instead of
+            # re-prefilling them
+            t0 = time.perf_counter()
+            done = []
+            for turn in range(args.turns):
+                for i in range(args.requests):
+                    msg = rng.integers(0, cfg.vocab_size,
+                                       args.prompt_len).astype(np.int32)
+                    try:
+                        eng.submit(Request(uid=args.requests * turn + i,
+                                           prompt=msg,
+                                           max_new_tokens=args.new_tokens,
+                                           **robust_kw(i)),
+                                   session=f"session-{i}")
+                    except QueueFull:
+                        pass    # rejected turn: the session stays reusable
+                done.extend(eng.run())
+            dt = time.perf_counter() - t0
+            total_new = sum(len(r.out_tokens) for r in done)
+            print(f"served {args.requests} sessions x {args.turns} turns, "
+                  f"{total_new} tokens in {dt:.2f}s "
+                  f"({total_new / dt:.1f} tok/s)")
+        elif args.arrival_rate:
+            # open-loop load test: arrivals come from a seeded Poisson
+            # process and do NOT wait for the system, so queueing shows up
+            # in TTFT. Warm the jit caches with one drained request first —
+            # otherwise compile time masquerades as the head of the latency
+            # distribution.
+            warm = rng.integers(0, cfg.vocab_size,
+                                args.prompt_len).astype(np.int32)
+            eng.submit(Request(uid=-1, prompt=warm, max_new_tokens=2))
+            eng.run()
+            tel.reset()
+            reqs = []
             for i in range(args.requests):
-                msg = rng.integers(0, cfg.vocab_size,
-                                   args.prompt_len).astype(np.int32)
+                tail = rng.integers(0, cfg.vocab_size,
+                                    args.prompt_len - shared_len
+                                    ).astype(np.int32)
+                reqs.append(Request(uid=i,
+                                    prompt=np.concatenate([shared, tail]),
+                                    max_new_tokens=args.new_tokens,
+                                    **robust_kw(i)))
+            arrivals = np.cumsum(rng.exponential(1.0 / args.arrival_rate,
+                                                 args.requests))
+            t0 = time.perf_counter()
+            done = drive_open_loop(eng, reqs, arrivals)
+            dt = time.perf_counter() - t0
+            total_new = sum(len(r.out_tokens) for r in done)
+            print(f"served {len(done)} requests open-loop at "
+                  f"{args.arrival_rate:g} req/s, {total_new} tokens in "
+                  f"{dt:.2f}s ({total_new / dt:.1f} tok/s)")
+        else:
+            for i in range(args.requests):
+                tail = rng.integers(0, cfg.vocab_size,
+                                    args.prompt_len - shared_len
+                                    ).astype(np.int32)
                 try:
-                    eng.submit(Request(uid=args.requests * turn + i,
-                                       prompt=msg,
+                    eng.submit(Request(uid=i,
+                                       prompt=np.concatenate([shared, tail]),
                                        max_new_tokens=args.new_tokens,
-                                       **robust_kw(i)),
-                               session=f"session-{i}")
+                                       **robust_kw(i)))
                 except QueueFull:
-                    pass    # rejected turn: the session stays reusable
-            done.extend(eng.run())
-        dt = time.perf_counter() - t0
-        total_new = sum(len(r.out_tokens) for r in done)
-        print(f"served {args.requests} sessions x {args.turns} turns, "
-              f"{total_new} tokens in {dt:.2f}s ({total_new / dt:.1f} tok/s)")
-    elif args.arrival_rate:
-        # open-loop load test: arrivals come from a seeded Poisson process
-        # and do NOT wait for the system, so queueing shows up in TTFT.
-        # Warm the jit caches with one drained request first — otherwise
-        # compile time masquerades as the head of the latency distribution.
-        warm = rng.integers(0, cfg.vocab_size,
-                            args.prompt_len).astype(np.int32)
-        eng.submit(Request(uid=-1, prompt=warm, max_new_tokens=2))
-        eng.run()
-        tel.reset()
-        reqs = []
-        for i in range(args.requests):
-            tail = rng.integers(0, cfg.vocab_size,
-                                args.prompt_len - shared_len).astype(np.int32)
-            reqs.append(Request(uid=i, prompt=np.concatenate([shared, tail]),
-                                max_new_tokens=args.new_tokens,
-                                **robust_kw(i)))
-        arrivals = np.cumsum(rng.exponential(1.0 / args.arrival_rate,
-                                             args.requests))
-        t0 = time.perf_counter()
-        done = drive_open_loop(eng, reqs, arrivals)
-        dt = time.perf_counter() - t0
-        total_new = sum(len(r.out_tokens) for r in done)
-        print(f"served {len(done)} requests open-loop at "
-              f"{args.arrival_rate:g} req/s, {total_new} tokens in {dt:.2f}s "
-              f"({total_new / dt:.1f} tok/s)")
-    else:
-        for i in range(args.requests):
-            tail = rng.integers(0, cfg.vocab_size,
-                                args.prompt_len - shared_len).astype(np.int32)
-            try:
-                eng.submit(Request(uid=i,
-                                   prompt=np.concatenate([shared, tail]),
-                                   max_new_tokens=args.new_tokens,
-                                   **robust_kw(i)))
-            except QueueFull:
-                pass        # counted in robust_counters.rejected below
-        t0 = time.perf_counter()
-        done = eng.run()
-        dt = time.perf_counter() - t0
-        total_new = sum(len(r.out_tokens) for r in done)
-        print(f"served {len(done)} requests, {total_new} tokens in {dt:.2f}s "
-              f"({total_new / dt:.1f} tok/s)")
+                    pass        # counted in robust_counters.rejected below
+            t0 = time.perf_counter()
+            done = eng.run()
+            dt = time.perf_counter() - t0
+            total_new = sum(len(r.out_tokens) for r in done)
+            print(f"served {len(done)} requests, {total_new} tokens in "
+                  f"{dt:.2f}s ({total_new / dt:.1f} tok/s)")
     if robust_on:
         rb = eng.robust_counters.snapshot()
         dm, rp = rb["deadline_misses"], rb["reprefill"]
@@ -450,9 +464,7 @@ def main():
     if telemetry_on:
         print(format_snapshot(eng.snapshot()))
     if args.trace_out:
-        n = tel.profiler.write_chrome_trace(args.trace_out)
-        print(f"wrote {n} trace events to {args.trace_out} "
-              f"(load in Perfetto / chrome://tracing)")
+        print(f"profiler trace in {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -114,10 +114,11 @@ def kv_store_geometry(cfg, max_len: int) -> tuple[int, int]:
 def _project_out(out, p, b, t):
     """Shared attention epilogue: merge heads -> output projection -> residual
     sharding constraint. out: (B, H, T, hd) or (B, T, H*hd)."""
-    if out.ndim == 4:
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
-    out = out @ p["wo"]
-    return constrain(out, "batch", "seq_act", "embed")
+    with jax.named_scope("attn.proj"):
+        if out.ndim == 4:
+            out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+        out = out @ p["wo"]
+        return constrain(out, "batch", "seq_act", "embed")
 
 
 def _slot_scatter(cache_kv, new_kv, lengths):
@@ -486,32 +487,40 @@ def _packed_attention(q, k_pool, v_pool, cache, cfg, hccs, hd,
             and hccs is not None):
         from repro.kernels.ops import hccs_packed_prefill
         theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
-        o = hccs_packed_prefill(qt.astype(jnp.float32), k_pool, v_pool,
-                                cache["block_table"], sid, cache["kv_len"],
-                                hccs["scale"], theta, mode=cfg.hccs_mode,
-                                static_max=(cfg.decode_kernel == "static_max"),
-                                k_scales=k_scales, v_scales=v_scales)
+        with jax.named_scope("attn.core"):
+            o = hccs_packed_prefill(
+                qt.astype(jnp.float32), k_pool, v_pool, cache["block_table"],
+                sid, cache["kv_len"], hccs["scale"], theta,
+                mode=cfg.hccs_mode,
+                static_max=(cfg.decode_kernel == "static_max"),
+                k_scales=k_scales, v_scales=v_scales)
         return o.astype(q.dtype).reshape(1, t, h * hd)
     q_pos_grid = cache["q_pos_grid"]                      # (B, Wb)
     gp = cache["grid_pos"]                                # (T,) spill = B*Wb
     k_len = cache["kv_len_slot"]                          # (B,)
     bs_, wb = q_pos_grid.shape
-    qg = jnp.zeros((bs_ * wb + 1, h, qt.shape[-1]), qt.dtype).at[gp].set(qt)
-    qg = qg[:bs_ * wb].reshape(bs_, wb, h, -1).transpose(0, 2, 1, 3)
-    kg = _paged_gather(k_pool, cache["block_table"], hd,
-                       scales=k_scales)                   # (B, Hkv, L, hd)
-    vg = _paged_gather(v_pool, cache["block_table"], hd, scales=v_scales)
+    with jax.named_scope("attn.gather"):
+        qg = jnp.zeros((bs_ * wb + 1, h, qt.shape[-1]),
+                       qt.dtype).at[gp].set(qt)
+        qg = qg[:bs_ * wb].reshape(bs_, wb, h, -1).transpose(0, 2, 1, 3)
+        kg = _paged_gather(k_pool, cache["block_table"], hd,
+                           scales=k_scales)               # (B, Hkv, L, hd)
+        vg = _paged_gather(v_pool, cache["block_table"], hd,
+                           scales=v_scales)
     tk = kg.shape[2]
     use_blockwise = (cfg.attention_impl == "blockwise" or
                      (cfg.attention_impl == "auto" and wb > 1 and
                       tk >= cfg.blockwise_threshold))
-    if use_blockwise:
-        out = _blockwise_attention(qg, kg, vg, q_pos_grid, k_len, cfg, hccs)
-    else:
-        valid = _block_valid(cfg, q_pos_grid, jnp.arange(tk), k_len)
-        out = _dense_attention(qg, kg, vg, valid, cfg, hccs)
-    out = out.transpose(0, 2, 1, 3).reshape(bs_ * wb, h * hd)
-    return out[jnp.where(sid >= 0, gp, 0)][None]          # (1, T, H*hd)
+    with jax.named_scope("attn.core"):
+        if use_blockwise:
+            out = _blockwise_attention(qg, kg, vg, q_pos_grid, k_len, cfg,
+                                       hccs)
+        else:
+            valid = _block_valid(cfg, q_pos_grid, jnp.arange(tk), k_len)
+            out = _dense_attention(qg, kg, vg, valid, cfg, hccs)
+    with jax.named_scope("attn.gather"):
+        out = out.transpose(0, 2, 1, 3).reshape(bs_ * wb, h * hd)
+        return out[jnp.where(sid >= 0, gp, 0)][None]      # (1, T, H*hd)
 
 
 def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
@@ -544,53 +553,58 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
     # degree even when the head count is not, e.g. hymba's 25 heads);
     # 'attn_seq' is None under the TP training profile and carries the
     # sequence shard under the serve_sp inference profile
-    qf = constrain(x @ p["wq"], "batch", "attn_seq", "model")
-    kf = constrain(x @ p["wk"], "batch", "attn_seq", "kv_model")
-    vf = constrain(x @ p["wv"], "batch", "attn_seq", "kv_model")
-    q = qf.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
-    k = kf.reshape(b, t, hkv, hd).transpose(0, 2, 1, 3)
-    v = vf.reshape(b, t, hkv, hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("attn.proj"):
+        qf = constrain(x @ p["wq"], "batch", "attn_seq", "model")
+        kf = constrain(x @ p["wk"], "batch", "attn_seq", "kv_model")
+        vf = constrain(x @ p["wv"], "batch", "attn_seq", "kv_model")
+        q = qf.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
+        k = kf.reshape(b, t, hkv, hd).transpose(0, 2, 1, 3)
+        v = vf.reshape(b, t, hkv, hd).transpose(0, 2, 1, 3)
 
     if positions is None:
         base = cache["length"] if cache is not None else jnp.zeros((), jnp.int32)
         # base is a scalar (lockstep decode) or a (B,) per-slot length vector
         positions = jnp.atleast_1d(base)[:, None] + jnp.arange(t)[None, :]
         positions = jnp.broadcast_to(positions, (b, t))
-    if cfg.rope == "rope":
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    elif cfg.rope == "mrope":
-        p3 = mrope_positions
-        if p3 is None:
-            p3 = jnp.broadcast_to(positions[None], (3,) + positions.shape)
-        q = apply_mrope(q, p3, cfg.rope_theta, cfg.mrope_sections)
-        k = apply_mrope(k, p3, cfg.rope_theta, cfg.mrope_sections)
+    with jax.named_scope("attn.proj"):
+        if cfg.rope == "rope":
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        elif cfg.rope == "mrope":
+            p3 = mrope_positions
+            if p3 is None:
+                p3 = jnp.broadcast_to(positions[None], (3,) + positions.shape)
+            q = apply_mrope(q, p3, cfg.rope_theta, cfg.mrope_sections)
+            k = apply_mrope(k, p3, cfg.rope_theta, cfg.mrope_sections)
 
     # ---- hot-buffer decode: append to the small replicated buffer and
     # merge main + hot segments against a shared max (see §Perf D) ----
     if cache is not None and "hot_k" in cache and t <= 8:
         hot_len = cache["hot_len"]
-        hk = jax.lax.dynamic_update_slice(
-            cache["hot_k"], k.astype(cache["hot_k"].dtype),
-            (0, 0, hot_len, 0))
-        hv = jax.lax.dynamic_update_slice(
-            cache["hot_v"], v.astype(cache["hot_v"].dtype),
-            (0, 0, hot_len, 0))
+        with jax.named_scope("attn.kv_write"):
+            hk = jax.lax.dynamic_update_slice(
+                cache["hot_k"], k.astype(cache["hot_k"].dtype),
+                (0, 0, hot_len, 0))
+            hv = jax.lax.dynamic_update_slice(
+                cache["hot_v"], v.astype(cache["hot_v"].dtype),
+                (0, 0, hot_len, 0))
         new_cache = dict(cache, hot_k=hk, hot_v=hv, hot_len=hot_len + t,
                          length=cache["length"] + t)
         main_len_s = cache["length"] - hot_len          # prompt tokens
         mk, mv = cache["k"], cache["v"]
-        valid_main = _block_valid(cfg, positions, jnp.arange(mk.shape[2]),
-                                  jnp.full((b,), main_len_s, jnp.int32))
-        hot_pos = main_len_s + jnp.arange(hk.shape[2])
-        valid_hot = _block_valid(cfg, positions, hot_pos,
-                                 jnp.full((b,), cache["length"] + t, jnp.int32))
-        m = jnp.maximum(_segment_max(q, mk, valid_main, cfg, hccs),
-                        _segment_max(q, hk, valid_hot, cfg, hccs))
-        m = jax.lax.stop_gradient(m)[..., None]
-        parts = [_segment_partials(q, mk, mv, valid_main, m, cfg, hccs),
-                 _segment_partials(q, hk, hv, valid_hot, m, cfg, hccs)]
-        out = _merge_segments(parts, cfg, hccs).astype(q.dtype)
+        with jax.named_scope("attn.core"):
+            valid_main = _block_valid(cfg, positions, jnp.arange(mk.shape[2]),
+                                      jnp.full((b,), main_len_s, jnp.int32))
+            hot_pos = main_len_s + jnp.arange(hk.shape[2])
+            valid_hot = _block_valid(
+                cfg, positions, hot_pos,
+                jnp.full((b,), cache["length"] + t, jnp.int32))
+            m = jnp.maximum(_segment_max(q, mk, valid_main, cfg, hccs),
+                            _segment_max(q, hk, valid_hot, cfg, hccs))
+            m = jax.lax.stop_gradient(m)[..., None]
+            parts = [_segment_partials(q, mk, mv, valid_main, m, cfg, hccs),
+                     _segment_partials(q, hk, hv, valid_hot, m, cfg, hccs)]
+            out = _merge_segments(parts, cfg, hccs).astype(q.dtype)
         return _project_out(out, p, b, t), new_cache
 
     new_cache = None
@@ -602,26 +616,28 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
         # paged arena: K/V live in a global block pool addressed through
         # per-slot block tables; the write targets (incl. trash routing for
         # tokens past each slot's valid count) were resolved on the host
-        quant = "k_scale" in cache
-        if quant:
-            # kv_quant="int8": reset scales of blocks allocated since the
-            # last step (their pool bytes and scales are stale from a prior
-            # owner), then run the quantizing per-row fold. COW copies are
-            # NOT in fresh_blocks — they arrive with payload+scales copied.
-            ks, vs = cache["k_scale"], cache["v_scale"]
-            fresh = cache.get("fresh_blocks")
-            if fresh is not None:
-                ks = ks.at[fresh].set(0.0)
-                vs = vs.at[fresh].set(0.0)
-            dr = cache.get("draft_rows")
-            kc, ks = paged_quant_scatter(cache["k"], ks, k,
-                                         cache["write_pos"], draft_rows=dr)
-            vc, vs = paged_quant_scatter(cache["v"], vs, v,
-                                         cache["write_pos"], draft_rows=dr)
-        else:
-            ks = vs = None
-            kc = _paged_scatter(cache["k"], k, cache["write_pos"])
-            vc = _paged_scatter(cache["v"], v, cache["write_pos"])
+        with jax.named_scope("attn.kv_write"):
+            quant = "k_scale" in cache
+            if quant:
+                # kv_quant="int8": reset scales of blocks allocated since
+                # the last step (their pool bytes and scales are stale from
+                # a prior owner), then run the quantizing per-row fold. COW
+                # copies are NOT in fresh_blocks — they arrive with
+                # payload+scales copied.
+                ks, vs = cache["k_scale"], cache["v_scale"]
+                fresh = cache.get("fresh_blocks")
+                if fresh is not None:
+                    ks = ks.at[fresh].set(0.0)
+                    vs = vs.at[fresh].set(0.0)
+                dr = cache.get("draft_rows")
+                kc, ks = paged_quant_scatter(cache["k"], ks, k,
+                                             cache["write_pos"], draft_rows=dr)
+                vc, vs = paged_quant_scatter(cache["v"], vs, v,
+                                             cache["write_pos"], draft_rows=dr)
+            else:
+                ks = vs = None
+                kc = _paged_scatter(cache["k"], k, cache["write_pos"])
+                vc = _paged_scatter(cache["v"], v, cache["write_pos"])
         new_cache = {kk: vv for kk, vv in cache.items()
                      if kk not in _PAGED_TRANSIENT}
         new_cache.update(k=kc, v=vc, length=cache["length"] + t)
@@ -651,34 +667,39 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
             # block-sparse fused decode: the kernel walks the block table
             from repro.kernels.ops import hccs_paged_decode
             theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
-            o = hccs_paged_decode(q[:, :, 0, :].astype(jnp.float32), kc, vc,
-                                  cache["block_table"], k_len, hccs["scale"],
-                                  theta, mode=cfg.hccs_mode,
-                                  static_max=(cfg.decode_kernel == "static_max"),
-                                  k_scales=ks, v_scales=vs)
+            with jax.named_scope("attn.core"):
+                o = hccs_paged_decode(
+                    q[:, :, 0, :].astype(jnp.float32), kc, vc,
+                    cache["block_table"], k_len, hccs["scale"], theta,
+                    mode=cfg.hccs_mode,
+                    static_max=(cfg.decode_kernel == "static_max"),
+                    k_scales=ks, v_scales=vs)
             out = o.astype(q.dtype).reshape(b, 1, h * hd)
             return _project_out(out, p, b, 1), new_cache
-        k = _paged_gather(kc, cache["block_table"], hd, scales=ks)
-        v = _paged_gather(vc, cache["block_table"], hd, scales=vs)
+        with jax.named_scope("attn.gather"):
+            k = _paged_gather(kc, cache["block_table"], hd, scales=ks)
+            v = _paged_gather(vc, cache["block_table"], hd, scales=vs)
     elif cache is not None:
-        if per_slot:
-            # continuous batching: every slot writes at its own frontier
-            kc = _slot_scatter(cache["k"], k, cache["length"])
-            vc = _slot_scatter(cache["v"], v, cache["length"])
-        elif cache["k"].shape[2:] == k.shape[2:]:
-            # prompt fills the whole cache (prefill at max_len): a plain
-            # overwrite avoids the dynamic-update-slice on the sharded seq
-            # dim, which XLA can only partition via a full gather (a
-            # lane-padded arena never matches and takes the DUS path below)
-            kc = k.astype(cache["k"].dtype)
-            vc = v.astype(cache["v"].dtype)
-        else:
-            kc = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype),
-                (0, 0, cache["length"], 0))
-            vc = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype),
-                (0, 0, cache["length"], 0))
+        with jax.named_scope("attn.kv_write"):
+            if per_slot:
+                # continuous batching: every slot writes at its own frontier
+                kc = _slot_scatter(cache["k"], k, cache["length"])
+                vc = _slot_scatter(cache["v"], v, cache["length"])
+            elif cache["k"].shape[2:] == k.shape[2:]:
+                # prompt fills the whole cache (prefill at max_len): a
+                # plain overwrite avoids the dynamic-update-slice on the
+                # sharded seq dim, which XLA can only partition via a full
+                # gather (a lane-padded arena never matches and takes the
+                # DUS path below)
+                kc = k.astype(cache["k"].dtype)
+                vc = v.astype(cache["v"].dtype)
+            else:
+                kc = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype),
+                    (0, 0, cache["length"], 0))
+                vc = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype),
+                    (0, 0, cache["length"], 0))
         # dict(cache, ...) preserves extra entries (hot buffers during the
         # prefill pass of a hot-buffer cache)
         new_cache = dict(cache, k=kc, v=vc, length=cache["length"] + t)
@@ -692,10 +713,11 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
             and "hot_k" not in cache):
         from repro.kernels.ops import hccs_decode
         theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
-        o = hccs_decode(q[:, :, 0, :].astype(jnp.float32),
-                        k, v, k_len, hccs["scale"], theta,
-                        mode=cfg.hccs_mode,
-                        static_max=(cfg.decode_kernel == "static_max"))
+        with jax.named_scope("attn.core"):
+            o = hccs_decode(q[:, :, 0, :].astype(jnp.float32),
+                            k, v, k_len, hccs["scale"], theta,
+                            mode=cfg.hccs_mode,
+                            static_max=(cfg.decode_kernel == "static_max"))
         out = o.astype(q.dtype).reshape(b, 1, h * hd)
         return _project_out(out, p, b, 1), new_cache
 
@@ -704,18 +726,19 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
         # padded buffer zero-copy above; XLA paths read the true lanes
         k, v = k[..., :hd], v[..., :hd]
 
-    tk = k.shape[2]
-    use_blockwise = (cfg.attention_impl == "blockwise" or
-                     (cfg.attention_impl == "auto" and t > 1 and
-                      tk >= cfg.blockwise_threshold))
-    if use_blockwise:
-        # single explicit gather point: both HCCS passes (max + accumulate)
-        # read the same seq-replicated K/V instead of re-gathering per pass
-        k = constrain(k, "batch", "kv_model", None, None)
-        v = constrain(v, "batch", "kv_model", None, None)
-        out = _blockwise_attention(q, k, v, positions, k_len, cfg, hccs)
-    else:
-        valid = _block_valid(cfg, positions, jnp.arange(tk), k_len)
-        out = _dense_attention(q, k, v, valid, cfg, hccs)
+    with jax.named_scope("attn.core"):
+        tk = k.shape[2]
+        use_blockwise = (cfg.attention_impl == "blockwise" or
+                         (cfg.attention_impl == "auto" and t > 1 and
+                          tk >= cfg.blockwise_threshold))
+        if use_blockwise:
+            # single explicit gather point: both HCCS passes (max + accumulate)
+            # read the same seq-replicated K/V instead of re-gathering per pass
+            k = constrain(k, "batch", "kv_model", None, None)
+            v = constrain(v, "batch", "kv_model", None, None)
+            out = _blockwise_attention(q, k, v, positions, k_len, cfg, hccs)
+        else:
+            valid = _block_valid(cfg, positions, jnp.arange(tk), k_len)
+            out = _dense_attention(q, k, v, valid, cfg, hccs)
 
     return _project_out(out, p, b, t), new_cache

@@ -119,7 +119,9 @@ def apply_block(p, x, cfg, hccs=None, cache=None, length=None, positions=None,
         y, aux = moe_mod.apply_moe(p["moe"], apply_norm(p["norm2"], x, cfg), cfg)
         x = x + y
     elif "mlp" in p:
-        x = x + apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+        h = apply_norm(p["norm2"], x, cfg)
+        with jax.named_scope("mlp"):
+            x = x + apply_mlp(p["mlp"], h, cfg)
     # Megatron-style sequence parallelism on the residual stream: between
     # blocks the carry is sharded over ("batch", seq->model); with remat=full
     # this shrinks the saved per-layer carry by the TP degree. 'seq_act' maps

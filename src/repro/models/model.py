@@ -151,7 +151,8 @@ def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
 
 
 def logits_from_hidden(weights, x, cfg):
-    return lm_logits(weights["embed"], weights, x, cfg)
+    with jax.named_scope("head"):
+        return lm_logits(weights["embed"], weights, x, cfg)
 
 
 def lm_loss(weights, hccs, batch, cfg):

@@ -6,8 +6,9 @@
 #                 bounded queue + backpressure, preemption policy
 # chaos.py      — seeded fault injector + engine invariant checker
 # telemetry.py  — request-lifecycle tracing (TTFT/TPOT/E2E percentiles),
-#                 step-phase profiler (Chrome-trace export), unified
-#                 schema-versioned snapshot, open-loop arrival driver
+#                 step-phase profiler (serve.* spans in the jax.profiler
+#                 trace), unified schema-versioned snapshot, open-loop
+#                 arrival driver
 from repro.serve.admission import (AdmissionConfig, AdmissionQueue,
                                    QueueFull, RobustnessCounters,
                                    choose_victim)

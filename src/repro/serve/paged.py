@@ -962,6 +962,12 @@ class PagedEngine:
         self.lanes_valid = 0
         self.lanes_total = 0
         self.pad_lanes_skipped = 0
+        # work counters: ctx_positions sums each valid lane's causal context
+        # (the keys it attends over), sampled_lanes the lanes whose logits
+        # a step samples from — with lanes_valid they give a step's model
+        # FLOPs without reading its inputs
+        self.ctx_positions = 0
+        self.sampled_lanes = 0
 
         # prefix sharing: exact-content trie over full-block chunks -> pool
         # block id (PrefixTrie above). The trie holds its own reference on
@@ -1550,7 +1556,9 @@ class PagedEngine:
         return dict(lanes_valid=self.lanes_valid,
                     lanes_total=self.lanes_total,
                     efficiency=self.lanes_valid / max(self.lanes_total, 1),
-                    pad_lanes_skipped=self.pad_lanes_skipped)
+                    pad_lanes_skipped=self.pad_lanes_skipped,
+                    ctx_positions=self.ctx_positions,
+                    sampled_lanes=self.sampled_lanes)
 
     # ------------------------------------------------------------- slots --
 
@@ -1838,6 +1846,9 @@ class PagedEngine:
                     t_valid[slot] = 1
             self.lanes_valid += int(t_valid.sum())
             self.lanes_total += self.max_batch * width
+            tv = t_valid.astype(np.int64)
+            self.ctx_positions += int((tv * self._lengths
+                                       + tv * (tv + 1) // 2).sum())
         with prof.phase("alloc_cow"):
             journal: list[tuple] = []
             try:
@@ -2003,6 +2014,7 @@ class PagedEngine:
             wp = packed_write_positions(t_valid, off, self._tables,
                                         self._lengths, self.block_size, width)
             kv_len = np.where(sid >= 0, positions + 1, 0).astype(np.int32)
+            self.ctx_positions += int(kv_len.sum())
             lane_idx = np.maximum(off + t_valid - 1, 0).astype(np.int32)
             # dirty-tracked device mirrors (see _step): skip the per-step
             # _tables/_lengths re-upload when the host copies are unchanged
@@ -2226,6 +2238,7 @@ class PagedEngine:
                                         self._lengths, self.block_size,
                                         width)
             kv_len = np.where(sid >= 0, positions + 1, 0).astype(np.int32)
+            self.ctx_positions += int(kv_len.sum())
             lane_idx = np.maximum(off + t_valid - 1, 0).astype(np.int32)
             # mirrors ride in `extras` (undonated; see _step)
             cache = self._cache
@@ -2267,6 +2280,7 @@ class PagedEngine:
             feed_len = np.asarray([len(f) if f is not None else 1 << 30
                                    for f in self._feeds])
             samples = live & (self._prompt_pos + t_valid >= feed_len)
+            self.sampled_lanes += int(samples.sum())
             was_prefill = live & (self._prompt_pos < feed_len)
             self._lengths_dev = None
             for slot in np.flatnonzero(live):
@@ -2326,30 +2340,30 @@ class PagedEngine:
                                                     "nan_logits"))
                     live[slot] = False
                     samples[slot] = False
-        for slot in np.flatnonzero(live):
-            req = self._slots[slot]
-            if req is None:
-                continue
-            if self.prefix_sharing and (p["was_prefill"][slot]
-                                        or self.decode_sharing):
-                covered = (int(p["lengths_after"][slot])
-                           if self.decode_sharing
-                           else min(int(p["prompt_pos_after"][slot]),
-                                    len(req.prompt)))
-                with prof.phase("register"):
-                    self._register_blocks(slot, req, covered=covered)
-            if not samples[slot]:
-                continue                     # still mid-prompt at dispatch
-            tok = int(sampled[slot])
-            req.out_tokens.append(tok)
-            if self.telemetry.enabled and len(req.out_tokens) == 1:
-                self.telemetry.metrics.on_first_token(req.uid)
-            self._last[slot] = tok
-            if (len(req.out_tokens) >= req.max_new_tokens or
-                    (self.eos_id is not None and tok == self.eos_id) or
-                    (not p["was_prefill"][slot] and
-                     p["lengths_after"][slot] >= self.max_len - 1)):
-                finished.append(self._finish(slot))
+            for slot in np.flatnonzero(live):
+                req = self._slots[slot]
+                if req is None:
+                    continue
+                if self.prefix_sharing and (p["was_prefill"][slot]
+                                            or self.decode_sharing):
+                    covered = (int(p["lengths_after"][slot])
+                               if self.decode_sharing
+                               else min(int(p["prompt_pos_after"][slot]),
+                                        len(req.prompt)))
+                    with prof.phase("register"):
+                        self._register_blocks(slot, req, covered=covered)
+                if not samples[slot]:
+                    continue                     # still mid-prompt at dispatch
+                tok = int(sampled[slot])
+                req.out_tokens.append(tok)
+                if self.telemetry.enabled and len(req.out_tokens) == 1:
+                    self.telemetry.metrics.on_first_token(req.uid)
+                self._last[slot] = tok
+                if (len(req.out_tokens) >= req.max_new_tokens or
+                        (self.eos_id is not None and tok == self.eos_id) or
+                        (not p["was_prefill"][slot] and
+                         p["lengths_after"][slot] >= self.max_len - 1)):
+                    finished.append(self._finish(slot))
         return finished
 
     def _call_device(self, fn, *args):
@@ -2398,37 +2412,40 @@ class PagedEngine:
                 self._key, logits, np.where(samples, self._temps, 0.0),
                 [r.uid if r else 0 for r in self._slots],
                 [len(r.out_tokens) if r else 0 for r in self._slots])
-        self._lengths_dev = None             # frontiers advance below
-        for slot in np.flatnonzero(live):
-            req = self._slots[slot]
-            tv = int(t_valid[slot])
-            was_prefill = self._prompt_pos[slot] < feed_len[slot]
-            self._lengths[slot] += tv
-            self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
-                                         feed_len[slot])
-            if self.prefix_sharing and (was_prefill or self.decode_sharing):
-                # registration precedes any possible _finish below, so a
-                # prompt that completes (or a block that fills at the decode
-                # frontier) on a terminating step still leaves its full-block
-                # KV cached; with decode sharing this runs every step, so
-                # generated blocks enter the trie the step they fill
-                with prof.phase("register"):
-                    self._register_blocks(slot, req)
-            if not samples[slot]:
-                continue                     # still mid-prompt
-            tok = int(nxt[slot])
-            req.out_tokens.append(tok)
-            if self.telemetry.enabled and len(req.out_tokens) == 1:
-                self.telemetry.metrics.on_first_token(req.uid)
-            self._last[slot] = tok
-            # the cache-full guard only applies to decode-written KV — the
-            # prefill-completion sample mirrors the continuous engine's
-            # admission sample, which is not length-guarded
-            if (len(req.out_tokens) >= req.max_new_tokens or
-                    (self.eos_id is not None and tok == self.eos_id) or
-                    (not was_prefill and
-                     self._lengths[slot] >= self.max_len - 1)):
-                finished.append(self._finish(slot))
+            self.sampled_lanes += int(samples.sum())
+            self._lengths_dev = None         # frontiers advance below
+            for slot in np.flatnonzero(live):
+                req = self._slots[slot]
+                tv = int(t_valid[slot])
+                was_prefill = self._prompt_pos[slot] < feed_len[slot]
+                self._lengths[slot] += tv
+                self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
+                                             feed_len[slot])
+                if self.prefix_sharing and (was_prefill
+                                            or self.decode_sharing):
+                    # registration precedes any possible _finish below, so
+                    # a prompt that completes (or a block that fills at the
+                    # decode frontier) on a terminating step still leaves
+                    # its full-block KV cached; with decode sharing this
+                    # runs every step, so generated blocks enter the trie
+                    # the step they fill
+                    with prof.phase("register"):
+                        self._register_blocks(slot, req)
+                if not samples[slot]:
+                    continue                     # still mid-prompt
+                tok = int(nxt[slot])
+                req.out_tokens.append(tok)
+                if self.telemetry.enabled and len(req.out_tokens) == 1:
+                    self.telemetry.metrics.on_first_token(req.uid)
+                self._last[slot] = tok
+                # the cache-full guard only applies to decode-written KV —
+                # the prefill-completion sample mirrors the continuous
+                # engine's admission sample, which is not length-guarded
+                if (len(req.out_tokens) >= req.max_new_tokens or
+                        (self.eos_id is not None and tok == self.eos_id) or
+                        (not was_prefill and
+                         self._lengths[slot] >= self.max_len - 1)):
+                    finished.append(self._finish(slot))
         return finished
 
     def _verify_and_finish(self, live, t_valid, drafts, off, wp, logits,
@@ -2479,82 +2496,83 @@ class PagedEngine:
                 np.repeat(uids, kk1),
                 (gen0[:, None] + col).reshape(-1),
             ).reshape(self.max_batch, kk1)
-        finished_slots: list[int] = []
-        replay = np.zeros(width, bool)       # committed verify lanes
-        keep_blocks: dict[int, int] = {}     # slot -> committed block count
-        any_reject = False
-        self._lengths_dev = None             # frontiers advance below
-        for slot in np.flatnonzero(live):
-            req = self._slots[slot]
-            tv = int(t_valid[slot])
-            was_prefill = self._prompt_pos[slot] < feed_len[slot]
-            if slot not in drafts:
-                # identical to the never-drafted tail (_sample_and_finish),
-                # except finishes are deferred until after rollback so EOS
-                # frees append to a free list rollback already restored
-                self._lengths[slot] += tv
-                self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
-                                             feed_len[slot])
-                if self.prefix_sharing and (was_prefill
-                                            or self.decode_sharing):
+            self.sampled_lanes += int(do.sum())
+            finished_slots: list[int] = []
+            replay = np.zeros(width, bool)       # committed verify lanes
+            keep_blocks: dict[int, int] = {}  # slot -> committed blocks
+            any_reject = False
+            self._lengths_dev = None             # frontiers advance below
+            for slot in np.flatnonzero(live):
+                req = self._slots[slot]
+                tv = int(t_valid[slot])
+                was_prefill = self._prompt_pos[slot] < feed_len[slot]
+                if slot not in drafts:
+                    # identical to the never-drafted tail (_sample_and_finish),
+                    # except finishes are deferred until after rollback so EOS
+                    # frees append to a free list rollback already restored
+                    self._lengths[slot] += tv
+                    self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
+                                                 feed_len[slot])
+                    if self.prefix_sharing and (was_prefill
+                                                or self.decode_sharing):
+                        with prof.phase("register"):
+                            self._register_blocks(slot, req)
+                    if not samples[slot]:
+                        continue                 # still mid-prompt
+                    tok = int(toks[slot, 0])
+                    req.out_tokens.append(tok)
+                    if self.telemetry.enabled and len(req.out_tokens) == 1:
+                        self.telemetry.metrics.on_first_token(req.uid)
+                    self._last[slot] = tok
+                    if (len(req.out_tokens) >= req.max_new_tokens or
+                            (self.eos_id is not None and tok == self.eos_id) or
+                            (not was_prefill and
+                             self._lengths[slot] >= self.max_len - 1)):
+                        finished_slots.append(slot)
+                    continue
+                # drafting decode slot: longest matching prefix wins
+                d = drafts[slot]
+                k = len(d)
+                t_row = [int(toks[slot, i]) for i in range(1 + k)]
+                j = 0
+                while j < k and d[j] == t_row[j]:
+                    j += 1
+                # emit t_row[0..j] under never-drafted finish semantics: stop
+                # at the first token that would have ended the request (budget,
+                # EOS, cache-full) — later accepted tokens must not leak out
+                L0 = int(self._lengths[slot])
+                emitted: list[int] = []
+                fin = False
+                for i in range(j + 1):
+                    tok = t_row[i]
+                    emitted.append(tok)
+                    if (len(req.out_tokens) + len(emitted)
+                            >= req.max_new_tokens or
+                            (self.eos_id is not None and
+                             tok == self.eos_id) or
+                            L0 + 1 + i >= self.max_len - 1):
+                        fin = True
+                        break
+                m = len(emitted)
+                self.spec_steps += 1
+                self.drafted_tokens += k
+                self.accepted_tokens += m - 1
+                self.rejected_tokens += k - (m - 1)
+                if m < tv:
+                    any_reject = True
+                # exactly the rows a never-drafted engine would have written:
+                # lanes 0..m-1 (the final emitted token's own KV lands on its
+                # NEXT step, or never — same as one-token-per-step decode)
+                replay[off[slot]:off[slot] + m] = True
+                keep_blocks[slot] = -(-(L0 + m) // bs)
+                self._lengths[slot] += m
+                req.out_tokens.extend(emitted)
+                if self.decode_sharing:
                     with prof.phase("register"):
                         self._register_blocks(slot, req)
-                if not samples[slot]:
-                    continue                 # still mid-prompt
-                tok = int(toks[slot, 0])
-                req.out_tokens.append(tok)
-                if self.telemetry.enabled and len(req.out_tokens) == 1:
-                    self.telemetry.metrics.on_first_token(req.uid)
-                self._last[slot] = tok
-                if (len(req.out_tokens) >= req.max_new_tokens or
-                        (self.eos_id is not None and tok == self.eos_id) or
-                        (not was_prefill and
-                         self._lengths[slot] >= self.max_len - 1)):
+                self._last[slot] = emitted[-1]
+                if fin:
                     finished_slots.append(slot)
-                continue
-            # drafting decode slot: longest matching prefix wins
-            d = drafts[slot]
-            k = len(d)
-            t_row = [int(toks[slot, i]) for i in range(1 + k)]
-            j = 0
-            while j < k and d[j] == t_row[j]:
-                j += 1
-            # emit t_row[0..j] under never-drafted finish semantics: stop
-            # at the first token that would have ended the request (budget,
-            # EOS, cache-full) — later accepted tokens must not leak out
-            L0 = int(self._lengths[slot])
-            emitted: list[int] = []
-            fin = False
-            for i in range(j + 1):
-                tok = t_row[i]
-                emitted.append(tok)
-                if (len(req.out_tokens) + len(emitted)
-                        >= req.max_new_tokens or
-                        (self.eos_id is not None and
-                         tok == self.eos_id) or
-                        L0 + 1 + i >= self.max_len - 1):
-                    fin = True
-                    break
-            m = len(emitted)
-            self.spec_steps += 1
-            self.drafted_tokens += k
-            self.accepted_tokens += m - 1
-            self.rejected_tokens += k - (m - 1)
-            if m < tv:
-                any_reject = True
-            # exactly the rows a never-drafted engine would have written:
-            # lanes 0..m-1 (the final emitted token's own KV lands on its
-            # NEXT step, or never — same as one-token-per-step decode)
-            replay[off[slot]:off[slot] + m] = True
-            keep_blocks[slot] = -(-(L0 + m) // bs)
-            self._lengths[slot] += m
-            req.out_tokens.extend(emitted)
-            if self.decode_sharing:
-                with prof.phase("register"):
-                    self._register_blocks(slot, req)
-            self._last[slot] = emitted[-1]
-            if fin:
-                finished_slots.append(slot)
         with prof.phase("rollback"):
             for slot, jdx, blk, resv_dec in reversed(draft_allocs):
                 if jdx < keep_blocks[slot]:

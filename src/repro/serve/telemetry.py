@@ -19,45 +19,49 @@ Three layers, composable and individually cheap:
   reported as p50/p95/p99/mean over finished requests (percentile math is
   numpy-equivalent linear interpolation, pinned by tests/test_telemetry.py).
 
-* **StepProfiler** — wraps each engine step and attributes wall time to the
-  named PHASES of the step body. The phase taxonomy (paged engine; the other
-  engines use the applicable subset):
+* **StepProfiler** — wraps each engine step and the named PHASES of its
+  body in profiler spans: the step in a
+  ``jax.profiler.StepTraceAnnotation("serve.step", step_num=n)``, each
+  phase in a ``jax.profiler.TraceAnnotation("serve.<phase>")``. The spans
+  are emitted whether or not telemetry is enabled; JAX records them only
+  while a profiler session is open (``jax.profiler.trace``,
+  ``launch/serve.py --trace-out DIR``), so they land in the same trace as
+  every device op, on its clock. The phase taxonomy (paged engine; the
+  other engines use the applicable subset):
 
       admit      queue -> slot admission: prefix match, reservation gate,
                  table fork (continuous: includes the admission prefill)
       schedule   host-side step scheduling + token packing (t_valid, slot
-                 ids, steering arrays)
+                 ids, steering arrays) and their upload
       alloc_cow  block-pool bookkeeping: alloc-on-frontier-crossing growth
                  plus copy-on-write copies of shared blocks
-      device     the jitted model step. The profiler fences this phase with
-                 ``jax.block_until_ready`` so JAX async dispatch cannot
-                 smear device time into later host phases — ONLY when
-                 profiling is enabled, so unprofiled runs keep async
-                 dispatch overlap.
-      sample     logits -> next-token sampling (argmax/categorical + host
-                 transfer)
-      register   prefix-trie registration of newly filled blocks
+      device     the ENQUEUE of the jitted model step: JAX dispatches
+                 asynchronously, so this span ends when XLA accepts the
+                 program, not when the device finishes it
+      sample     the step tail: the wait for the step's result, the
+                 argmax/categorical sampling, the host transfer and the
+                 finish loop (frontier advance, EOS/budget checks). In the
+                 async loop, the dispatch-time frontier advance and the
+                 one-step-late commit
+      register   prefix-trie registration of newly filled blocks (nested
+                 inside sample)
+      rollback   speculative decoding: freeing rejected draft blocks and
+                 the int8 snapshot restore
 
-  Phases are FLAT within a step (no nesting), re-enterable (a phase opened
-  twice in one step accumulates), and exportable two ways: ``summary()``
-  (per-phase totals, share-of-step, and ``coverage`` = attributed/step wall
-  time — the acceptance gate keeps this >= 0.9) and a Chrome-trace JSONL
-  (``write_chrome_trace``; one complete event per line, loadable in
-  Perfetto / chrome://tracing). A sample trace, one event per line:
-
-      {"name": "step", "cat": "step", "ph": "X", "ts": 120, "dur": 5200,
-       "pid": 0, "tid": 0, "args": {"step": 0}}
-      {"name": "admit", "cat": "phase", "ph": "X", "ts": 130, "dur": 310, ...}
-      {"name": "schedule", "cat": "phase", "ph": "X", "ts": 450, "dur": 180, ...}
-      {"name": "device", "cat": "phase", "ph": "X", "ts": 700, "dur": 4100, ...}
-      {"name": "sample", "cat": "phase", "ph": "X", "ts": 4810, "dur": 350, ...}
+  With telemetry enabled the profiler also attributes wall time on the
+  serving clock: ``summary()`` gives per-phase self time, share-of-step and
+  ``coverage`` = attributed/step wall time (the acceptance gate keeps this
+  >= 0.9). That accounting fences the ``device`` phase with
+  ``jax.block_until_ready`` so device time is not smeared into later host
+  phases; with telemetry disabled nothing is fenced, timed or allocated
+  beyond the span objects, and async dispatch keeps its overlap.
 
 * **Telemetry** — the per-engine facade bundling one registry + one
   profiler behind a single ``enabled`` flag. Engines hold a Telemetry
-  instance unconditionally; when disabled every hook is a no-op flag check
-  (``phase()`` returns a shared null context manager, lifecycle hooks
-  return immediately), so telemetry-off serving pays one attribute test per
-  hook and nothing else — greedy outputs are asserted token-identical with
+  instance unconditionally; when disabled the lifecycle hooks return
+  immediately and ``phase()``/``step()`` return the bare profiler span, so
+  telemetry-off serving pays one attribute test per hook plus the spans —
+  greedy outputs are asserted token-identical with
   telemetry on vs off for all three engines.
 
 ``make_snapshot`` merges the lifecycle/phase metrics with the engines'
@@ -74,19 +78,19 @@ batch-drain submission).
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import json
 import math
 import time
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # v2 added the `robustness` section (admission/preemption/deadline counters
 # from serve/admission.py's RobustnessCounters; None for engines without the
 # opt-in layer) and RequestTrace.dropped / MetricsRegistry.on_drop for
-# requests ending in failure (shed, cancelled, deadline-expired)
-SNAPSHOT_SCHEMA_VERSION = 2
+# requests ending in failure (shed, cancelled, deadline-expired); v3 added
+# the `padding` section's ctx_positions / sampled_lanes work counters
+SNAPSHOT_SCHEMA_VERSION = 3
 
 # admission-wait histogram bucket edges (milliseconds, log-spaced); the last
 # bucket is open-ended
@@ -101,8 +105,6 @@ QUEUE_WAIT_EDGES_MS = (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
 # passing `clock=` to Telemetry (the engines resolve deadlines off the same
 # instance unless AdmissionConfig.clock is explicitly overridden).
 SERVING_CLOCK = time.perf_counter
-
-_NULL = contextlib.nullcontext()
 
 
 def percentile(values, q: float):
@@ -262,80 +264,103 @@ class MetricsRegistry:
 
 
 class _Span:
-    """Reusable timing context for StepProfiler (one per live nesting level;
-    allocated per __enter__ so re-entrant phases in one step are safe)."""
+    """One open step or phase: enters its profiler annotation and, when the
+    profiler is enabled, times itself on the serving clock. Allocated per
+    __enter__ site so re-entrant and nested phases are safe."""
 
-    def __init__(self, prof, name: str, is_step: bool):
+    def __init__(self, prof, name: str, annotation, is_step: bool):
         self.prof = prof
         self.name = name
+        self.annotation = annotation
         self.is_step = is_step
 
     def __enter__(self):
         prof = self.prof
+        self.annotation.__enter__()
         if self.is_step:
             prof._step_depth += 1
-            self.idx = prof.step_count
+        else:
+            self.child = 0.0
+            prof._open.append(self)
         self.t0 = prof.clock()
         return self
 
     def __exit__(self, *exc):
         prof = self.prof
-        t1 = prof.clock()
-        dur = t1 - self.t0
-        ev = dict(name=self.name, cat="step" if self.is_step else "phase",
-                  ph="X", ts=round((self.t0 - prof.epoch) * 1e6, 1),
-                  dur=round(dur * 1e6, 1), pid=0, tid=0)
+        dur = prof.clock() - self.t0
         if self.is_step:
             prof._step_depth -= 1
             prof.step_total += dur
             prof.step_count += 1
-            ev["args"] = {"step": self.idx}
         else:
+            prof._open.pop()
+            # phases hold their SELF time: a nested phase (register inside
+            # sample) is charged to itself, not twice
             prof.phase_seconds[self.name] = (
-                prof.phase_seconds.get(self.name, 0.0) + dur)
+                prof.phase_seconds.get(self.name, 0.0) + dur - self.child)
             prof.phase_counts[self.name] = (
                 prof.phase_counts.get(self.name, 0) + 1)
-            if prof._step_depth > 0:
+            if prof._open:
+                prof._open[-1].child += dur
+            elif prof._step_depth > 0:
                 prof.in_step_seconds += dur
-        prof.events.append(ev)
+        self.annotation.__exit__(*exc)
         return False
 
 
 class StepProfiler:
-    """Wall-time attribution of engine steps to named phases.
+    """Engine steps and their named phases, as profiler spans and (when
+    enabled) as host-clock wall-time attribution.
 
-    ``step(name)`` wraps one engine step; ``phase(name)`` wraps a region of
-    its body (flat — phases never nest inside each other; a phase may be
-    opened several times per step and accumulates). ``coverage`` is the
-    fraction of step wall time attributed to phases — the observability
-    acceptance gate keeps it >= 0.9, so a new chunk of per-step host work
-    can't silently hide outside the breakdown. When disabled both return a
-    shared null context: one attribute check, zero allocation."""
+    ``step(name)`` wraps one engine step in a
+    ``jax.profiler.StepTraceAnnotation("serve.<name>", step_num=n)``;
+    ``phase(name)`` wraps a region of its body in a
+    ``jax.profiler.TraceAnnotation("serve.<name>")``. The annotations are
+    entered whether or not the profiler is enabled: JAX records them only
+    while a profiler session is open, so outside one they cost an object and
+    a flag test, and inside one they share the device trace's clock.
+
+    A phase may be opened several times per step (it accumulates) and may
+    nest inside another (``register`` inside ``sample``); ``phase_seconds``
+    holds each phase's self time. ``coverage`` is the fraction of step wall
+    time attributed to phases — the observability gate keeps it >= 0.9, so a
+    new chunk of per-step host work can't silently hide outside the
+    breakdown. The host-clock accounting runs only when enabled."""
 
     def __init__(self, enabled: bool = True, clock=None):
         self.enabled = enabled
         self.clock = clock if clock is not None else SERVING_CLOCK
+        self._names: dict[str, str] = {}
+        self.step_num = 0             # numbers the step annotations
         self.reset()
 
     def reset(self):
-        self.epoch = self.clock()
-        self.events: list[dict] = []
         self.phase_seconds: dict[str, float] = {}
         self.phase_counts: dict[str, int] = {}
         self.in_step_seconds = 0.0
         self.step_total = 0.0
         self.step_count = 0
         self._step_depth = 0
+        self._open: list[_Span] = []
+
+    def _name(self, name: str) -> str:
+        full = self._names.get(name)
+        if full is None:
+            full = self._names[name] = "serve." + name
+        return full
 
     def step(self, name: str = "step"):
+        ann = StepTraceAnnotation(self._name(name), step_num=self.step_num)
+        self.step_num += 1
         if not self.enabled:
-            return _NULL
-        return _Span(self, name, is_step=True)
+            return ann
+        return _Span(self, name, ann, is_step=True)
 
     def phase(self, name: str):
+        ann = TraceAnnotation(self._name(name))
         if not self.enabled:
-            return _NULL
-        return _Span(self, name, is_step=False)
+            return ann
+        return _Span(self, name, ann, is_step=False)
 
     @property
     def coverage(self):
@@ -354,16 +379,6 @@ class StepProfiler:
                                share_of_step=(secs / self.step_total
                                               if self.step_total else None))
                     for name, secs in sorted(self.phase_seconds.items())})
-
-    def write_chrome_trace(self, path: str) -> int:
-        """Chrome-trace JSONL: one complete ('ph': 'X') event per line, ts /
-        dur in microseconds since the profiler epoch. Loadable in Perfetto
-        and chrome://tracing (both accept newline-delimited event objects);
-        line-parseable by anything else. Returns the event count."""
-        with open(path, "w") as f:
-            for ev in sorted(self.events, key=lambda e: e["ts"]):
-                f.write(json.dumps(ev) + "\n")
-        return len(self.events)
 
 
 class Telemetry:

@@ -1,9 +1,14 @@
 """Serving telemetry: percentile math vs numpy, request-lifecycle ordering
-invariants, Chrome-trace JSONL validity, telemetry on/off greedy parity on
-all three engines, snapshot schema stability, phase coverage, and the
-open-loop arrival driver."""
+invariants, the step profiler's spans and self-time accounting, the
+launcher's profiler trace, no device fence with telemetry off, telemetry
+on/off greedy parity on all three engines, snapshot schema stability, phase
+coverage, and the open-loop arrival driver."""
 import copy
 import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -26,6 +31,10 @@ LATENCY_KEYS = {"requests", "ttft", "tpot", "e2e", "queue_wait",
                 "queue_wait_hist", "queue_depth_peak", "queue_depth_mean"}
 DIST_KEYS = {"count", "mean", "p50", "p95", "p99"}
 PHASES_KEYS = {"steps", "step_seconds", "coverage", "phases"}
+# v3 added the ctx_positions / sampled_lanes work counters
+PADDING_KEYS = {"lanes_valid", "lanes_total", "efficiency",
+                "pad_lanes_skipped", "ctx_positions", "sampled_lanes"}
+REPO = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture
@@ -158,48 +167,177 @@ def test_engine_trace_ordering_invariants(served, rng, name):
             next(r for r in done if r.uid == t.uid).out_tokens)
 
 
-# ----------------------------------------------------------- chrome trace --
+# -------------------------------------------------------- profiler spans --
 
 
-def test_chrome_trace_jsonl_validity(served, rng, tmp_path):
-    """Every line is a complete JSON event with the Chrome-trace keys;
-    phase events fall inside [min step ts, max step end]; step events carry
-    their step index."""
-    cfg, params = served
-    tel = Telemetry(enabled=True)
-    eng = _engines(params, cfg, tel)["paged"]
-    for r in _requests(rng, 4):
-        eng.submit(r)
-    eng.run()
-    path = tmp_path / "trace.jsonl"
-    n = tel.profiler.write_chrome_trace(str(path))
-    lines = path.read_text().splitlines()
-    assert n == len(lines) > 0
-    events = [json.loads(ln) for ln in lines]
-    for ev in events:
-        assert {"name", "cat", "ph", "ts", "dur", "pid", "tid"} <= set(ev)
-        assert ev["ph"] == "X" and ev["cat"] in ("step", "phase")
-        assert ev["ts"] >= 0 and ev["dur"] >= 0
-    assert [ev["ts"] for ev in events] == sorted(ev["ts"] for ev in events)
-    steps = [ev for ev in events if ev["cat"] == "step"]
-    phases = [ev for ev in events if ev["cat"] == "phase"]
-    assert steps and phases
-    assert [ev["args"]["step"] for ev in steps] == list(range(len(steps)))
-    lo = min(ev["ts"] for ev in steps)
-    hi = max(ev["ts"] + ev["dur"] for ev in steps)
-    # tolerate the timestamp rounding (0.1 us) at the boundaries
-    assert all(lo - 1 <= ev["ts"] and ev["ts"] + ev["dur"] <= hi + 1
-               for ev in phases)
-    assert {ev["name"] for ev in phases} >= {"admit", "device", "sample"}
+class _FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def test_nested_phase_is_charged_its_self_time():
+    """register inside sample: each phase holds its self time, and the step
+    is attributed once (outermost phases only), so coverage stays <= 1."""
+    clock = _FakeClock()
+    prof = StepProfiler(enabled=True, clock=clock)
+    with prof.step():
+        with prof.phase("schedule"):
+            clock.t += 2.0
+        with prof.phase("sample"):
+            clock.t += 1.0
+            with prof.phase("register"):
+                clock.t += 3.0
+            clock.t += 1.0
+        clock.t += 1.0                       # unattributed
+    s = prof.summary()
+    assert s["steps"] == 1 and s["step_seconds"] == 8.0
+    assert {n: p["seconds"] for n, p in s["phases"].items()} == {
+        "schedule": 2.0, "sample": 2.0, "register": 3.0}
+    assert s["coverage"] == 7.0 / 8.0
 
 
 def test_disabled_profiler_records_nothing():
+    """Disabled: step()/phase() hand back the bare profiler span (no timing
+    wrapper, nothing accumulated), with the step spans still numbered."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
     prof = StepProfiler(enabled=False)
+    step0 = prof.step()
+    assert type(step0) is StepTraceAnnotation
+    assert type(prof.phase("device")) is TraceAnnotation
     with prof.step():
         with prof.phase("device"):
             pass
-    assert prof.events == [] and prof.step_count == 0
+    assert prof.step_num == 2
+    assert prof.step_count == 0 and prof.phase_seconds == {}
     assert prof.coverage is None
+
+
+def test_trace_out_writes_profiler_trace(tmp_path):
+    """`launch/serve.py --trace-out DIR` wraps the serving loop in a
+    profiler session: DIR holds an .xplane.pb whose host spans include the
+    engine's serve.step and serve.<phase> spans, with telemetry off."""
+    from jax.profiler import ProfileData
+    out = tmp_path / "trace"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               PYTHONPATH=str(REPO / "src"))
+    p = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch",
+         "granite-3-2b", "--reduced", "--requests", "2", "--prompt-len", "8",
+         "--new-tokens", "2", "--scheduler", "paged", "--max-batch", "2",
+         "--block-size", "8",
+         "--trace-out", str(out)],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert "telemetry snapshot" not in p.stdout      # no host accounting
+    found = sorted(out.rglob("*.xplane.pb"))
+    assert len(found) == 1
+    names = {e.name for plane in ProfileData.from_file(str(found[0])).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")}
+    assert {"serve.step", "serve.admit", "serve.schedule", "serve.device",
+            "serve.sample"} <= names
+    assert f"profiler trace in {out}" in p.stdout
+
+
+@pytest.mark.parametrize("name", ["wave", "continuous", "paged",
+                                  "paged_async"])
+@pytest.mark.parametrize("enabled", [False, True])
+def test_device_fence_only_with_telemetry(served, rng, monkeypatch, name,
+                                          enabled):
+    """The device phase's block_until_ready fence belongs to the host-clock
+    accounting: with telemetry disabled no step calls it, so async dispatch
+    keeps its overlap; enabled, every engine fences."""
+    cfg, params = served
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(1)
+        return real(x)
+    tel = Telemetry(enabled=enabled)
+    engines = _engines(params, cfg, tel)
+    if name == "paged_async":
+        eng = PagedEngine(params, cfg.replace(cache_layout="paged"),
+                          max_batch=4, max_len=64, block_size=8, packed=True,
+                          async_loop=True, telemetry=tel)
+    else:
+        eng = engines[name]
+    for r in _requests(rng, 3):
+        eng.submit(r)
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    done = eng.run()
+    assert len(done) == 3
+    assert bool(calls) == enabled
+
+
+def _count_step_inputs(eng):
+    """Wrap every step function of `eng`; returns the running sums of the
+    valid lanes and their causal contexts the steps were handed."""
+    seen = {"lanes": 0, "ctx": 0}
+
+    def wrap(attr, idx):
+        fn = getattr(eng, attr)
+
+        def counted(*a):
+            ex = a[idx]
+            if "slot_ids" in ex:             # packed: per-token frontiers
+                valid = np.asarray(ex["slot_ids"]) >= 0
+                seen["lanes"] += int(valid.sum())
+                seen["ctx"] += int(np.asarray(ex["kv_len"])[valid].sum())
+            else:                            # lockstep: per-slot frontiers
+                tv = np.asarray(a[5]).astype(np.int64)
+                kv = np.asarray(ex["kv_len"]).astype(np.int64)
+                seen["lanes"] += int(tv.sum())
+                seen["ctx"] += int((tv * kv - tv * (tv - 1) // 2).sum())
+            return fn(*a)
+        setattr(eng, attr, counted)
+    for attr, idx in (("_packed_fn", 5), ("_packed_spec_fn", 5),
+                      ("_packed_async_fn", 7), ("_step_fn", 4)):
+        wrap(attr, idx)
+    return seen
+
+
+@pytest.mark.parametrize("layout", ["packed", "async", "speculative",
+                                    "lockstep"])
+def test_work_counters_match_step_inputs(served, rng, layout):
+    """padding_stats()' ctx_positions is the sum of every valid lane's
+    causal context the step functions are handed and sampled_lanes the
+    lanes sampled, on every step path."""
+    cfg, params = served
+    kw = dict(max_batch=4, max_len=128, block_size=8)
+    if layout == "speculative":
+        kw.update(prefix_sharing=True, decode_sharing=True, speculative=True,
+                  draft_len=4)
+    eng = PagedEngine(params, cfg.replace(cache_layout="paged"),
+                      packed=layout != "lockstep",
+                      async_loop=layout == "async", **kw)
+    seen = _count_step_inputs(eng)
+    done = []
+    if layout == "speculative":
+        # repeated turns over one history give the trie drafts to verify
+        base = rng.integers(0, 256, 12).astype(np.int32)
+        for turn in range(3):
+            for s in range(2):
+                eng.submit(Request(uid=2 * turn + s, prompt=base,
+                                   max_new_tokens=8), session=f"s{s}")
+            done.extend(eng.run())
+        assert eng.drafted_tokens > 0
+    else:
+        for r in _requests(rng, 5):
+            eng.submit(r)
+        done = eng.run()
+    pad = eng.padding_stats()
+    assert pad["ctx_positions"] == seen["ctx"] > 0
+    assert pad["lanes_valid"] == seen["lanes"]
+    tokens = sum(len(r.out_tokens) for r in done)
+    if layout == "speculative":
+        assert pad["sampled_lanes"] >= tokens
+    else:
+        assert pad["sampled_lanes"] == tokens
 
 
 # ------------------------------------------------------- parity & schema --
@@ -234,7 +372,7 @@ def test_snapshot_schema_stability(served, rng, enabled):
     for name, eng in engines.items():
         snap = eng.snapshot()
         assert set(snap) == SNAPSHOT_KEYS
-        assert snap["schema_version"] == 2
+        assert snap["schema_version"] == 3
         assert snap["engine"] == name
         assert snap["robustness"] is None    # none of these are robust
         assert set(snap["kv_cache"]) == {"cache_bytes_logical",
@@ -245,7 +383,8 @@ def test_snapshot_schema_stability(served, rng, enabled):
         else:
             assert snap["latency"] is None and snap["phases"] is None
         if name == "paged":
-            assert snap["prefix"] is not None and snap["padding"] is not None
+            assert snap["prefix"] is not None
+            assert set(snap["padding"]) == PADDING_KEYS
         else:
             assert snap["prefix"] is None and snap["padding"] is None
         assert json.dumps(snap)           # JSON-serializable as-is
