@@ -94,6 +94,15 @@ def decode_kernel_blockers(cfg) -> list:
     return blockers
 
 
+def xla_reads_kv(cfg) -> bool:
+    """True when attention reads the cache through XLA, not a fused kernel:
+    no kernel is asked for, or the config blocks it (decode_kernel_blockers).
+    The one gate for the kernel dispatch (which also needs hccs params), the
+    lane padding below and the paged pool's geometry (serve/paged.py
+    init_paged_cache)."""
+    return cfg.decode_kernel == "none" or bool(decode_kernel_blockers(cfg))
+
+
 def kv_store_geometry(cfg, max_len: int) -> tuple[int, int]:
     """Storage shape (head_dim, seq) for cache K/V buffers.
 
@@ -105,7 +114,7 @@ def kv_store_geometry(cfg, max_len: int) -> tuple[int, int]:
     XLA readers slice back to [..., :head_dim]; padded lanes stay zero and
     padded rows sit beyond every slot's length mask.
     """
-    if cfg.decode_kernel == "none" or decode_kernel_blockers(cfg):
+    if xla_reads_kv(cfg):
         return cfg.head_dim, max_len
     hd = max(-(-cfg.head_dim // 128) * 128, 128)
     return hd, -(-max_len // 128) * 128
@@ -145,22 +154,32 @@ def _slot_scatter(cache_kv, new_kv, lengths):
 # (paged_quant_scatter) — after verification the engine restores a pre-step
 # block snapshot and re-folds exactly the committed rows from the staged
 # copies (the scale fold cannot be un-folded in place).
+# `layer` only rides on steps over a token-major pool: the pool leaves are
+# then the whole (L, ...) stack, carried through the layer scan, and each
+# layer writes and reads its own slice by this index (model.forward).
 _PAGED_TRANSIENT = ("block_table", "write_pos", "kv_len", "slot_ids",
                     "q_pos_grid", "grid_pos", "kv_len_slot", "fresh_blocks",
-                    "stage_rows", "draft_rows")
+                    "stage_rows", "draft_rows", "layer")
 
 
-def _paged_scatter(pool, new_kv, write_pos):
+def _paged_scatter(pool, new_kv, write_pos, layer=None):
     """Write t new KV vectors per slot into the global paged block pool.
 
-    pool: (N, Hkv, block_size, hd_c); new_kv: (B, Hkv, t, hd); write_pos:
-    (B, t) int32 flat pool positions (block_id * block_size + offset),
-    host-computed by the engine — tokens past a slot's valid count point at
-    the reserved trash block 0, so the scatter keeps a static shape without
-    polluting any live block."""
-    n, hkv, bs, hd_c = pool.shape
+    pool: one layer's head-major (N, Hkv, block_size, hd_c) pool or, with
+    `layer`, the token-major stack (L, N, block_size, Hkv*hd), written in
+    place at [layer, block, offset] one (Hkv*hd) row per token; new_kv:
+    (B, Hkv, t, hd); write_pos: (B, t) int32 flat pool positions
+    (block_id * block_size + offset), host-computed by the engine — tokens
+    past a slot's valid count point at the reserved trash block 0, so the
+    scatter keeps a static shape without polluting any live block."""
     pos = write_pos.reshape(-1)
-    upd = new_kv.transpose(0, 2, 1, 3).reshape(-1, hkv, new_kv.shape[-1])
+    upd = new_kv.transpose(0, 2, 1, 3)                    # (B, t, Hkv, hd)
+    if layer is not None:
+        bs = pool.shape[2]
+        rows = upd.reshape(pos.shape[0], -1).astype(pool.dtype)
+        return pool.at[layer, pos // bs, pos % bs].set(rows)
+    n, hkv, bs, hd_c = pool.shape
+    upd = upd.reshape(-1, hkv, new_kv.shape[-1])
     return pool.at[pos // bs, :, pos % bs, :upd.shape[-1]].set(
         upd.astype(pool.dtype))
 
@@ -176,14 +195,18 @@ KV_QUANT_EPS = 1e-6
 KV_QUANT_INV_QMAX = jnp.float32(1.0 / 127.0)
 
 
-def paged_quant_scatter(pool, scales, new_kv, write_pos, draft_rows=None):
+def paged_quant_scatter(pool, scales, new_kv, write_pos, draft_rows=None,
+                        layer=None):
     """Quantizing write into an int8 paged pool with per-block scales.
 
     pool: (N, Hkv, block_size, hd_c) int8; scales: (N, Hkv) float32 — one
     symmetric scale per (block, kv-head); new_kv: (B, Hkv, t, hd) float;
     write_pos: (B, t) flat positions exactly as in _paged_scatter.
     draft_rows: optional (B, t) bool — rows that fold with a CLAMPED scale
-    (speculative verify steps; see below).
+    (speculative verify steps; see below). With `layer`, pool is the
+    token-major stack (L, N, block_size, Hkv*hd) and scales (L, N, Hkv):
+    the fold runs on a (block_size, Hkv, hd) view of [layer, block], with
+    the same arithmetic, so the bytes are those of the head-major fold.
 
     Rows are folded IN POSITION ORDER, one at a time (lax.fori_loop):
 
@@ -213,7 +236,9 @@ def paged_quant_scatter(pool, scales, new_kv, write_pos, draft_rows=None):
     lanes read something meaningful. Draft folds are scratch either way:
     the engine restores the pre-step snapshot and re-folds the committed
     rows (without the flag) after every verify step."""
-    n, hkv, bs, hd_c = pool.shape
+    token_major = layer is not None
+    hkv = scales.shape[-1]
+    bs = pool.shape[2] if token_major else pool.shape[-2]
     pos = write_pos.reshape(-1)
     upd = new_kv.transpose(0, 2, 1, 3).reshape(-1, hkv, new_kv.shape[-1])
     upd = upd.astype(jnp.float32)
@@ -224,36 +249,52 @@ def paged_quant_scatter(pool, scales, new_kv, write_pos, draft_rows=None):
     def write_row(i, carry):
         pool, scales = carry
         blk, row = pos[i] // bs, pos[i] % bs
+        at = (layer, blk) if token_major else (blk,)
         x = upd[i]                                         # (Hkv, hd)
-        s_old = scales[blk]                                # (Hkv,)
+        s_old = scales[at]                                 # (Hkv,)
         amax = jnp.abs(x).max(-1)
         s_new = jnp.maximum(s_old, jnp.maximum(amax, KV_QUANT_EPS)
                             * KV_QUANT_INV_QMAX)
         if draft is not None:
             s_new = jnp.where(draft[i] & (s_old > 0), s_old, s_new)
         ratio = s_old / s_new                              # s_new >= eps/127
-        payload = pool[blk].astype(jnp.float32) * ratio[:, None, None]
-        payload = jnp.clip(round_to_int(payload), -128, 127)
         q = jnp.clip(round_to_int(x / s_new[:, None]), -128, 127)
-        payload = payload.at[:, row, :hd].set(q)
-        return (pool.at[blk].set(payload.astype(pool.dtype)),
-                scales.at[blk].set(s_new))
+        if token_major:
+            payload = (pool[at].reshape(bs, hkv, hd).astype(jnp.float32)
+                       * ratio[None, :, None])
+            payload = jnp.clip(round_to_int(payload), -128, 127)
+            payload = payload.at[row].set(q).reshape(bs, hkv * hd)
+        else:
+            payload = pool[at].astype(jnp.float32) * ratio[:, None, None]
+            payload = jnp.clip(round_to_int(payload), -128, 127)
+            payload = payload.at[:, row, :hd].set(q)
+        return (pool.at[at].set(payload.astype(pool.dtype)),
+                scales.at[at].set(s_new))
 
     return jax.lax.fori_loop(0, pos.shape[0], write_row, (pool, scales))
 
 
-def _paged_gather(pool, block_table, hd, scales=None):
+def _paged_gather(pool, block_table, hd, scales=None, layer=None):
     """Contiguous (B, Hkv, nblk*block_size, hd) view of each slot's blocks —
     the XLA attention path over a paged cache (the Pallas kernel instead
     gathers block-by-block in its BlockSpec index_map, see kernels/decode.py).
+    pool: one layer's head-major (N, Hkv, bs, hd_c) pool or, with `layer`,
+    the token-major stack (L, N, bs, Hkv*hd), read at pool[layer, table].
     Sentinel (-1) entries gather the trash block; they only occur at or past
     the slot's frontier, so the kv_len mask hides them. With `scales`
-    (N, Hkv; kv_quant="int8") the int8 payload is dequantized per block
-    elementwise BEFORE attention — the same values the fused kernel's tile
-    dequant produces, keeping XLA/kernel bit-parity."""
+    (N, Hkv, or (L, N, Hkv) with `layer`; kv_quant="int8") the int8 payload
+    is dequantized per block elementwise BEFORE attention — the same values
+    the fused kernel's tile dequant produces, keeping XLA/kernel bit-parity."""
     b, nblk = block_table.shape
-    n, hkv, bs, hd_c = pool.shape
     tbl = jnp.maximum(block_table, 0)
+    if layer is not None:
+        bs = pool.shape[2]
+        g = pool[layer, tbl].reshape(b, nblk, bs, -1, hd)  # (.., Hkv, hd)
+        if scales is not None:
+            g = (g.astype(jnp.float32)
+                 * scales[layer, tbl][:, :, None, :, None])
+        return g.reshape(b, nblk * bs, -1, hd).transpose(0, 2, 1, 3)
+    n, hkv, bs, hd_c = pool.shape
     g = pool[tbl]                                  # (B, nblk, Hkv, bs, hd_c)
     if scales is not None:
         g = g.astype(jnp.float32) * scales[tbl][..., None, None]
@@ -483,8 +524,7 @@ def _packed_attention(q, k_pool, v_pool, cache, cfg, hccs, hd,
     b, h, t, _ = q.shape
     sid = cache["slot_ids"]
     qt = q[0].transpose(1, 0, 2)                          # (T, H, hd)
-    if (cfg.decode_kernel != "none" and not decode_kernel_blockers(cfg)
-            and hccs is not None):
+    if not xla_reads_kv(cfg) and hccs is not None:
         from repro.kernels.ops import hccs_packed_prefill
         theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
         with jax.named_scope("attn.core"):
@@ -503,10 +543,10 @@ def _packed_attention(q, k_pool, v_pool, cache, cfg, hccs, hd,
         qg = jnp.zeros((bs_ * wb + 1, h, qt.shape[-1]),
                        qt.dtype).at[gp].set(qt)
         qg = qg[:bs_ * wb].reshape(bs_, wb, h, -1).transpose(0, 2, 1, 3)
-        kg = _paged_gather(k_pool, cache["block_table"], hd,
-                           scales=k_scales)               # (B, Hkv, L, hd)
-        vg = _paged_gather(v_pool, cache["block_table"], hd,
-                           scales=v_scales)
+        li = cache.get("layer")
+        kg = _paged_gather(k_pool, cache["block_table"], hd, k_scales,
+                           li)                            # (B, Hkv, L, hd)
+        vg = _paged_gather(v_pool, cache["block_table"], hd, v_scales, li)
     tk = kg.shape[2]
     use_blockwise = (cfg.attention_impl == "blockwise" or
                      (cfg.attention_impl == "auto" and wb > 1 and
@@ -532,11 +572,14 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
     With cfg.hot_buffer > 0 the cache also carries (hot_k, hot_v, hot_len):
     decode appends there (replicated, static-shard-safe) and attention merges
     the main + hot segments against a shared max.
-    PAGED layout (serve/paged.py): k/v are instead global block pools
-    (N, Hkv, block_size, hd) and the cache carries `block_table` (B, nblk),
-    `write_pos` (B, T) flat scatter targets, and `kv_len` (B,) per-slot
-    valid counts — the dispatch keys off `block_table`'s presence, the paged
-    analogue of `length` going scalar-vs-vector for the slot arena.
+    PAGED layout (serve/paged.py): k/v are instead global block pools —
+    one layer's head-major (N, Hkv, block_size, hd_c) pool for the fused
+    kernels, or the token-major (L, N, block_size, Hkv*hd) stack with this
+    layer's `layer` index for the XLA path — and the cache carries
+    `block_table` (B, nblk), `write_pos` (B, T) flat scatter targets, and
+    `kv_len` (B,) per-slot valid counts — the dispatch keys off
+    `block_table`'s presence, the paged analogue of `length` going
+    scalar-vs-vector for the slot arena.
     PACKED paged steps additionally carry `slot_ids` (T,): x is then a
     (1, T) ragged token batch (rows are tokens, not slots), positions are
     per-token, and `kv_len` is per-TOKEN — see _packed_attention.
@@ -615,7 +658,10 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
     if paged:
         # paged arena: K/V live in a global block pool addressed through
         # per-slot block tables; the write targets (incl. trash routing for
-        # tokens past each slot's valid count) were resolved on the host
+        # tokens past each slot's valid count) were resolved on the host.
+        # A token-major pool arrives as the whole (L, ...) stack with this
+        # layer's index; a head-major pool as this layer's own slice.
+        li = cache.get("layer")
         with jax.named_scope("attn.kv_write"):
             quant = "k_scale" in cache
             if quant:
@@ -627,17 +673,18 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
                 ks, vs = cache["k_scale"], cache["v_scale"]
                 fresh = cache.get("fresh_blocks")
                 if fresh is not None:
-                    ks = ks.at[fresh].set(0.0)
-                    vs = vs.at[fresh].set(0.0)
+                    at = (fresh,) if li is None else (li, fresh)
+                    ks = ks.at[at].set(0.0)
+                    vs = vs.at[at].set(0.0)
                 dr = cache.get("draft_rows")
                 kc, ks = paged_quant_scatter(cache["k"], ks, k,
-                                             cache["write_pos"], draft_rows=dr)
+                                             cache["write_pos"], dr, li)
                 vc, vs = paged_quant_scatter(cache["v"], vs, v,
-                                             cache["write_pos"], draft_rows=dr)
+                                             cache["write_pos"], dr, li)
             else:
                 ks = vs = None
-                kc = _paged_scatter(cache["k"], k, cache["write_pos"])
-                vc = _paged_scatter(cache["v"], v, cache["write_pos"])
+                kc = _paged_scatter(cache["k"], k, cache["write_pos"], li)
+                vc = _paged_scatter(cache["v"], v, cache["write_pos"], li)
         new_cache = {kk: vv for kk, vv in cache.items()
                      if kk not in _PAGED_TRANSIENT}
         new_cache.update(k=kc, v=vc, length=cache["length"] + t)
@@ -662,8 +709,7 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
             out = _packed_attention(q, kc, vc, cache, cfg, hccs, hd,
                                     k_scales=ks, v_scales=vs)
             return _project_out(out, p, b, t), new_cache
-        if (t == 1 and cfg.decode_kernel != "none"
-                and not decode_kernel_blockers(cfg) and hccs is not None):
+        if t == 1 and not xla_reads_kv(cfg) and hccs is not None:
             # block-sparse fused decode: the kernel walks the block table
             from repro.kernels.ops import hccs_paged_decode
             theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
@@ -677,8 +723,8 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
             out = o.astype(q.dtype).reshape(b, 1, h * hd)
             return _project_out(out, p, b, 1), new_cache
         with jax.named_scope("attn.gather"):
-            k = _paged_gather(kc, cache["block_table"], hd, scales=ks)
-            v = _paged_gather(vc, cache["block_table"], hd, scales=vs)
+            k = _paged_gather(kc, cache["block_table"], hd, ks, li)
+            v = _paged_gather(vc, cache["block_table"], hd, vs, li)
     elif cache is not None:
         with jax.named_scope("attn.kv_write"):
             if per_slot:
@@ -708,9 +754,8 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
 
     # ---- fused decode kernel: single new token against the cache ring
     # buffer, per-slot length masking (kernels/decode.py) ----
-    if (cache is not None and t == 1 and cfg.decode_kernel != "none"
-            and not decode_kernel_blockers(cfg) and hccs is not None
-            and "hot_k" not in cache):
+    if (cache is not None and t == 1 and not xla_reads_kv(cfg)
+            and hccs is not None and "hot_k" not in cache):
         from repro.kernels.ops import hccs_decode
         theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
         with jax.named_scope("attn.core"):

@@ -113,10 +113,21 @@ def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
 
     hccs_xs = hccs if hccs else None
     cache_xs = cache["layers"] if cache is not None else None
+    L = cfg.num_layers
+    # A token-major paged pool (L, N, bs, Hkv*hd), the one the XLA attention
+    # path reads, rides the scan's CARRY: each layer scatters into and
+    # gathers from its own slice by layer index, in place. As xs/ys the
+    # scan would slice every layer's pool out and write it back each step.
+    # Head-major pools (L, N, Hkv, bs, hd_c), read by the fused kernels,
+    # stay xs/ys. Per-layer outputs (staged_k/staged_v) stay ys either way.
+    pools = {}
+    if paged_extras is not None and cache_xs["k"].ndim == 4:
+        pools = {kk: cache_xs[kk] for kk in ("k", "v", "k_scale", "v_scale")
+                 if kk in cache_xs}
+        cache_xs = jnp.arange(L)
     xs = (weights["layers"], hccs_xs, cache_xs)
     # lax.scan requires every xs leaf to have leading dim L; None legs are
     # replaced by dummy per-layer zeros.
-    L = cfg.num_layers
     if hccs_xs is None:
         xs = (xs[0], jnp.zeros((L,)), xs[2])
     if cache_xs is None:
@@ -124,22 +135,29 @@ def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
 
     def scan_body(carry, xs_):
         lp, hc, lc = xs_
+        x, aux, pools_ = carry
         hc = hc if isinstance(hc, dict) else None
+        if pools_:
+            lc = dict(pools_, layer=lc)
         lc = lc if isinstance(lc, dict) else None
         if lc is not None and hot_len is not None:
             lc = dict(lc, hot_len=hot_len)
         if lc is not None and paged_extras is not None:
             lc = dict(lc, **paged_extras)
-        x, aux = carry
         x, new_lc, aux_l = call(lp, x, hc, lc, length, positions,
                                 mrope_positions)
         if new_lc and "hot_len" in new_lc:
             new_lc = {k_: v_ for k_, v_ in new_lc.items() if k_ != "hot_len"}
-        return (x, aux + aux_l), (new_lc if new_lc else jnp.zeros(()))
+        if pools_:
+            return ((x, aux + aux_l, {k_: new_lc[k_] for k_ in pools_}),
+                    {k_: v_ for k_, v_ in new_lc.items() if k_ not in pools_})
+        return (x, aux + aux_l, pools_), (new_lc if new_lc else jnp.zeros(()))
 
-    (x, aux), new_layer_caches = jax.lax.scan(
-        scan_body, (x, jnp.zeros((), jnp.float32)), xs,
+    (x, aux, pools), new_layer_caches = jax.lax.scan(
+        scan_body, (x, jnp.zeros((), jnp.float32), pools), xs,
         unroll=min(cfg.scan_unroll, L))
+    if pools:
+        new_layer_caches = dict(new_layer_caches, **pools)
 
     x = apply_norm(weights["final_norm"], x, cfg)
     new_cache = None

@@ -81,6 +81,9 @@ def kv_cache_byte_stats(cache, cfg, max_len: int | None = None) -> dict:
         leaf = cache["layers"].get(name)
         if leaf is None:
             continue
+        if leaf.ndim == 4:       # token-major paged pool (L, N, bs, Hkv*hd):
+            logical += leaf.size * leaf.dtype.itemsize    # never padded
+            continue
         rows_c, hd_c = leaf.shape[-2], leaf.shape[-1]
         rows = rows_c
         if name in ("k", "v") and max_len is not None:

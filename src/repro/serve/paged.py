@@ -17,8 +17,19 @@ returns a block to the free list when its last reference drops:
     │ blk 5  ████  slot1 COW  ref 1 │   free list: [2, 4, ...]
     │ blk 7  ████  shared     ref 3 │   prefix trie: (root, chunk 0) ─▶ 3
     └───────────────────────────────┘                 (blk 3, chunk 1) ─▶ 7
-    pool k/v: (num_blocks, Hkv, block_size, hd); logical position p of slot b
-    lives at pool block table[b, p // block_size], row p % block_size.
+    pool k/v, stacked over layers, in one of two geometries chosen by the
+    pool's reader (init_paged_cache):
+
+        XLA attention path    (L, num_blocks, block_size, Hkv*hd)
+                              token-major: a token's heads are one row;
+                              carried through the layer scan and updated
+                              in place by layer index (model.forward)
+        fused Pallas kernels  (L, num_blocks, Hkv, block_size, hd_c)
+                              head-major, head_dim lane-padded to 128 for
+                              the kernels' BlockSpec tiles; scanned as xs/ys
+
+    Either way logical position p of slot b lives at pool block
+    table[b, p // block_size], row p % block_size.
     With cfg.kv_quant="int8" the pools are int8 and each layer adds
     k_scale/v_scale (num_blocks, Hkv) f32 — one symmetric per-(block,
     kv-head) dequant scale alongside the payload:
@@ -248,8 +259,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
-from repro.models.attention import (decode_kernel_blockers,
-                                    kv_store_geometry, paged_quant_scatter)
+from repro.models.attention import (kv_store_geometry, paged_quant_scatter,
+                                    xla_reads_kv)
 from repro.serve.admission import (AdmissionQueue, QueueFull,
                                    RobustnessCounters, as_admission,
                                    choose_victim)
@@ -653,9 +664,16 @@ def _restore_and_replay(layers, snap, blocks, fresh_mask, staged_k,
         sc = out[name + "_scale"].at[:, blocks].set(
             jnp.where(fresh_mask[None, :, None], 0.0,
                       snap[name + "_scale"]))
-        pool, sc = jax.vmap(paged_quant_scatter,
-                            in_axes=(0, 0, 0, None))(pool, sc, staged,
-                                                     replay_pos)
+        if pool.ndim == 4:          # token-major stack: fold layer by layer
+            pool, sc = jax.lax.fori_loop(
+                0, pool.shape[0],
+                lambda li, c: paged_quant_scatter(
+                    c[0], c[1], staged[li], replay_pos, None, li),
+                (pool, sc))
+        else:
+            pool, sc = jax.vmap(paged_quant_scatter,
+                                in_axes=(0, 0, 0, None))(pool, sc, staged,
+                                                         replay_pos)
         out[name], out[name + "_scale"] = pool, sc
     return out
 
@@ -718,19 +736,28 @@ def _copy_block_kv(layers, src, dst):
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, max_batch: int,
                      cache_dtype=None):
-    """Model cache in the paged layout: per-layer (N, Hkv, bs, hd) pools plus
-    the (B,) per-slot length frontier. head_dim is lane-padded exactly when
-    the dense arena would be (kv_store_geometry), so the paged/dense byte
-    comparison is apples-to-apples and the paged kernel's zero-copy branch
-    runs whenever the dense kernel's would.
+    """Model cache in the paged layout: per-layer block pools plus the (B,)
+    per-slot length frontier. The pool's geometry follows its reader
+    (attention.xla_reads_kv):
+
+        XLA attention path:  k/v (L, N, bs, Hkv*hd)    token-major
+        fused Pallas kernels: k/v (L, N, Hkv, bs, hd_c) head-major
+
+    Token-major puts one token's heads in one (Hkv*hd)-lane row, so the
+    pool carries no lane padding, and model.forward carries it through the
+    layer scan and updates it in place by layer index. Head-major gives the
+    kernels' BlockSpecs their per-head tiles; head_dim is lane-padded
+    exactly when the dense arena would be (kv_store_geometry), so the
+    paged kernel's zero-copy branch runs whenever the dense kernel's would.
 
     cache_dtype=None resolves to cfg.cache_dtype (the single-sourced default
     shared with init_cache and every engine). With cfg.kv_quant="int8" the
-    pools are int8 regardless of cache_dtype and each layer additionally
-    carries `k_scale`/`v_scale` (num_blocks, Hkv) float32 — one symmetric
-    dequant scale per (block, kv-head), zero meaning "never written":
+    pools are int8 regardless of cache_dtype, in the same geometry, and each
+    layer additionally carries `k_scale`/`v_scale` (num_blocks, Hkv) float32
+    — one symmetric dequant scale per (block, kv-head), zero meaning "never
+    written":
 
-        k/v:        (L, N, Hkv, bs, hd_c)  int8 payload
+        k/v:             int8 payload in either geometry above
         k_scale/v_scale: (L, N, Hkv)       f32, row value = q * scale
 
     Scales are state, not steering: they ride the carried cache through the
@@ -739,9 +766,12 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, max_batch: int,
     if cache_dtype is None:
         cache_dtype = jnp.dtype(cfg.cache_dtype)
     hkv = cfg.num_kv_heads
-    hd_c = kv_store_geometry(cfg, block_size)[0]
     L = cfg.num_layers
-    shape = (L, num_blocks, hkv, block_size, hd_c)
+    if xla_reads_kv(cfg):
+        shape = (L, num_blocks, block_size, hkv * cfg.head_dim)
+    else:
+        hd_c = kv_store_geometry(cfg, block_size)[0]
+        shape = (L, num_blocks, hkv, block_size, hd_c)
     quant = cfg.kv_quant == "int8"
     pool_dtype = jnp.int8 if quant else cache_dtype
     layers = {"k": jnp.zeros(shape, pool_dtype),
@@ -930,9 +960,7 @@ class PagedEngine:
         # with the fused packed kernel active, attention never reads the
         # grid-steering arrays — omit them so the step traces once per chunk
         # width, not once per (chunk width, grid width) pair
-        self._use_grid = not (cfg.decode_kernel != "none"
-                              and not decode_kernel_blockers(cfg)
-                              and bool(params["hccs"]))
+        self._use_grid = xla_reads_kv(cfg) or not params["hccs"]
         # pipelined async loop (module docstring, "Pipelined async loop"):
         # dispatch step N+1 while step N's tokens are still in flight, with
         # host commit running one step behind. Opt-in; packed-only (the

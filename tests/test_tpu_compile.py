@@ -5,13 +5,16 @@ it: a tile that breaks the (8, 128) layout, or an operand that outgrows SMEM
 or VMEM, only fails when the kernel is compiled for the chip. These tests
 compile with `interpret=False` against a described `v5e:2x2` topology, one
 device, at granite-3-2b head geometry (H 32, Hkv 8, head_dim 64 lane-padded
-to 128) and at pool sizes a real deployment holds.
+to 128) and at pool sizes a real deployment holds. The XLA attention
+path's packed step is compiled the same way, to check what it does with
+its KV pool.
 
 The TPU library may be loaded by one process at a time, so the topology is
 described inside a module fixture and never at import: every xdist worker
 collects the same tests, and only the worker given this file loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +175,73 @@ def test_full_width_int8_packed_step_compiles(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < 16e9, used        # v5e: 16 GB of HBM
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "f32": 4, "s32": 4, "u32": 4}
+_MOVES = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) "
+                    r"(copy|copy-start|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _pool_moves(hlo_text, n_blocks, at_least):
+    """(name, op, bytes) of every copy, slice or update-slice in the
+    compiled text with a result (or an element of a tuple result) that can
+    hold a whole layer's pool: at least `at_least` bytes, with an axis of
+    the pool's `n_blocks`. The attention core's gathered per-slot K/V,
+    which may be as large but has no block axis, is not a pool move."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _MOVES.match(line)
+        if not m:
+            continue
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(2)):
+            dims = [int(d) for d in dims.split(",") if d]
+            size = _BYTES.get(dtype, 4)
+            for d in dims:
+                size *= d
+            if size >= at_least and n_blocks in dims:
+                out.append((m.group(1), m.group(3), size))
+    return out
+
+
+def test_xla_packed_step_moves_no_pool(one_chip):
+    """The engine's packed step on the XLA attention path at the serving
+    benchmark's rag-4k shapes: granite-3-2b, a bf16 pool of 1792 blocks of
+    32, 16 slots, 512 lanes, a 128-wide attention grid. Its token-major pool
+    rides the layer scan's carry and is written in place, so no op copies,
+    slices or writes back a layer's pool, and the step's temp stays far
+    below one pool (4.7 GB; with the pool as scan xs/ys it read 5.86 GB)."""
+    from repro.models import model as M
+    from repro.serve import PagedEngine
+    from repro.serve.paged import init_paged_cache
+
+    cfg = get_config("granite-3-2b").replace(
+        cache_layout="paged", cache_dtype="bfloat16")
+    b, bs, max_len, n, width, wb = 16, 32, 4096, 1792, 512, 128
+    params = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    eng = PagedEngine(params, cfg, max_batch=b, max_len=max_len,
+                      block_size=bs, num_blocks=max_len // bs + 2)
+    assert eng._use_grid
+    s = _spec(one_chip)
+    place = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    cache = place(jax.eval_shape(lambda: init_paged_cache(cfg, n, bs, b)))
+    layer_pool = n * bs * HKV * HD * 2
+    assert cache["layers"]["k"].shape == (cfg.num_layers, n, bs, HKV * HD)
+    extras = {"length": s((b,), jnp.int32),
+              "block_table": s((b, max_len // bs), jnp.int32),
+              "write_pos": s((1, width), jnp.int32),
+              "kv_len": s((width,), jnp.int32),
+              "slot_ids": s((width,), jnp.int32),
+              "q_pos_grid": s((b, wb), jnp.int32),
+              "grid_pos": s((width,), jnp.int32),
+              "kv_len_slot": s((b,), jnp.int32)}
+    compiled = eng._packed_fn.lower(
+        place(params["weights"]), place(params["hccs"]),
+        s((1, width), jnp.int32), s((1, width), jnp.int32), cache, extras,
+        s((b,), jnp.int32)).compile()
+    assert _pool_moves(compiled.as_text(), n, layer_pool) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1e9, temp
 
 
 @pytest.mark.parametrize("backend,interp", [("cpu", True), ("tpu", False)])
