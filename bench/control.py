@@ -5,8 +5,9 @@ control's, over several seeds in one process.
     python3 bench/control.py --workload <cell> --seconds <s> --seeds <n> ...
 
 For each seed it runs the cell as bench/run.py does (set-up, lead-in, a
-window of --seconds at the cell's own load, the reference on the sampled
-finished requests) and prints one JSON line: the program's `correct` and
+window of --seconds at the cell's own load, the reference on as many
+finished requests as the check file's `requests`) and prints one JSON
+line: the program's `correct` and
 widest logit gap (logit_gap_max), and the control's, decided by the same
 comparison with the control's tokens in place of the served ones: at each
 served position, the token that the reference computed with float8

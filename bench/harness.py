@@ -2,7 +2,8 @@
 window, read the metrics, check the served tokens against the reference.
 
 Set-up makes the weights on the device from the seed in one jitted call,
-builds the cell's `PagedEngine`, compiles (or loads from the persistent
+as the configuration's architecture module (archs/<arch>.py) lays them
+out, builds the cell's `PagedEngine`, compiles (or loads from the persistent
 cache) every packed-step shape the cell's traffic reaches, fills the
 prefix cache where the mix asks for it, then runs the lead-in traffic.
 `setup_s` runs from the start of the process to the window's opening.
@@ -37,7 +38,6 @@ from bench.traffic import generator as G
 TRACE_AT_S = 1.0         # traced sub-window: opens this long into the window
 TRACE_S = 4.0            # and lasts this long (less in a shorter window)
 DRAIN_S = 60.0           # most seconds stepped after the window closes
-SAMPLE_REQUESTS = 8      # finished requests the reference checks
 
 
 class NoAccelerator(RuntimeError):
@@ -142,9 +142,9 @@ def mix_max_len(mix: dict) -> int:
     return ctx + mix["prompt"]["max"] + mix["output"]["max"]
 
 
-def sample(records, seed: int, n: int = SAMPLE_REQUESTS):
-    """Finished requests for the reference: the longest, and the rest drawn
-    from the seed."""
+def sample(records, seed: int, n: int):
+    """n finished requests for the reference (the cell's check file's
+    `requests`): the longest, and the rest drawn from the seed."""
     done = [r for r in records if r.finished and r.req.out_tokens]
     if not done:
         return []
@@ -164,7 +164,7 @@ def _seqs(recs):
 def logit_gaps(c: dict, seed: int, recs, tokens=None, **shape):
     """Per served token (or per token of `tokens`, one array a request, in
     its place), how far its logit lies below the reference's best at that
-    position."""
+    position. `shape` is logit_stats's t, k, n and root."""
     from bench import reference as R
     stats = R.logit_stats(c, seed, _seqs(recs), tokens=tokens, **shape)
     return np.concatenate([mx - at for mx, at, _ in stats])
@@ -207,6 +207,7 @@ def setup(cell_name: str, seed: int, *, root: Path = registry.ROOT,
     bench = bench if bench is not None else registry.load_benchmark()
     w = registry.cell(bench, cell_name)
     c = registry.config(w["config"], root)
+    arch = registry.arch(c["arch"], root)
     peaks = json.loads((Path(root) / "peaks.json").read_text())
 
     import jax
@@ -222,8 +223,8 @@ def setup(cell_name: str, seed: int, *, root: Path = registry.ROOT,
     parts = {"import_s": time.perf_counter() - t_start}
 
     t = time.perf_counter()
-    cfg = BM.model_config(c)
-    params = BM.make_params(c, seed, cfg)
+    cfg = arch.model_config(c)
+    params = BM.make_params(c, seed, cfg, arch)
     jax.block_until_ready(params)
     parts["weights_s"] = time.perf_counter() - t
 
@@ -244,7 +245,7 @@ def setup(cell_name: str, seed: int, *, root: Path = registry.ROOT,
     parts["live_gb"] = stats.get("bytes_in_use", 0) / 1e9
     parts["limit_gb"] = stats.get("bytes_limit", 0) / 1e9
     return types.SimpleNamespace(
-        t_start=t_start, bench=bench, cell=w, config=c,
+        t_start=t_start, bench=bench, cell=w, config=c, arch=arch,
         mix=registry.mix(w["traffic"], root),
         check=registry.check(cell_name, root), peaks=peaks, devs=devs,
         dev=dev, cfg=cfg, params=params, eng=eng, parts=parts,
@@ -260,7 +261,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     of the served ones (bench/control.py)."""
     su = setup(cell_name, seed, root=root, bench=bench,
                require_tpu=require_tpu, t_start=t_start)
-    c, mix, chk, peaks = su.config, su.mix, su.check, su.peaks
+    c, arch, mix, chk, peaks = su.config, su.arch, su.mix, su.check, su.peaks
     dev, devs, eng, parts, compiles = (su.dev, su.devs, su.eng, su.parts,
                                        su.compiles)
     sv = c["serving"]
@@ -338,8 +339,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if contexts:
         n_tok = sum(n for s, e, n in res.steps
                     if tr_state["t0"] <= s and e <= tr_state["t1"])
-        flops = sum(BM.step_flops(c, kv, 0) for kv in contexts) \
-            + n_tok * BM.head_flops(c)
+        flops = sum(arch.step_flops(c, kv, 0) for kv in contexts) \
+            + n_tok * arch.head_flops(c)
     del steps_seen
     ctx = readings.Context(
         run=res, config=c, mix=mix, setup_s=setup_s, end=end, trace=tr,
@@ -378,7 +379,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     # the check: the served tokens of finished requests against the
     # reference, once the program's state is freed
-    recs = sample(res.records, seed)
+    recs = sample(res.records, seed, chk["requests"])
     del eng, su, res, ctx, traffic
     gc.collect()
     t = time.perf_counter()
@@ -387,7 +388,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     tt = 256
     while tt < min(mix_max_len(mix), sv["max_len"]):
         tt *= 2
-    shape = dict(t=tt, k=mix["output"]["max"], n=SAMPLE_REQUESTS)
+    shape = dict(t=tt, k=mix["output"]["max"], n=chk["requests"],
+                 root=root)
     if recs:
         gap = float(logit_gaps(c, seed, recs, **shape).max())
     log(f"[check] requests={len(recs)} tokens="

@@ -2,13 +2,15 @@
 jax.numpy, float32 at the highest matmul precision, with no cache, no
 paging and no batching of requests.
 
-It imports nothing of the program. Weights come from the seed through
-`bench.model.leaf_value`, one layer at a time inside a scan, so the
-reference holds one layer's weights (in float32) at a time. Attention is
-HCCS as the configuration states it: float logits q.k / sqrt(head_dim)
-quantised to int8 by the logit scale (round half to even, clip to
-[-128, 127]), the row max over the causal context, s = B - S * min(m - q,
-D) on that context and 0 elsewhere, p = s / sum(s) ("wide"
+It imports nothing of the program. The forward up to the final norm is
+the architecture module's `hidden` (archs/<arch>.py), built from the
+pieces here; this module runs it and reads the logits. Weights come from
+the seed through `bench.model.leaf_value`, one layer at a time inside a
+scan, so the reference holds one layer's weights (in float32) at a time.
+Attention is HCCS as the configuration states it: float logits q.k /
+sqrt(head_dim) quantised to int8 by the logit scale (round half to even,
+clip to [-128, 127]), the row max over the causal context, s = B - S *
+min(m - q, D) on that context and 0 elsewhere, p = s / sum(s) ("wide"
 normalisation). RoPE rotates the two halves of each head. Norms are RMS
 or layer norm with the configuration's eps.
 
@@ -19,12 +21,14 @@ the nearest precision below the configuration's bfloat16.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench import model as BM
+from bench import registry
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0
@@ -86,71 +90,22 @@ def _hccs_attention(q, k, v, hc, mm, q_block):
     return out.transpose(1, 0, 2, 3).reshape(h, t, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("c_json", "precision"))
-def _hidden(key, tokens, gather, *, c_json, precision):
-    """tokens (n, T), gather (n, K) -> final-normed hidden (n, K, d)."""
-    import json
+@functools.partial(jax.jit, static_argnames=("c_json", "precision", "root"))
+def _hidden(key, tokens, gather, *, c_json, precision, root):
+    """tokens (n, T), gather (n, K) -> final-normed hidden (n, K, d), by
+    the configuration's architecture module."""
     c = json.loads(c_json)
-    m, hc_cfg = c["model"], c["hccs"]
-    L, d = m["num_layers"], m["d_model"]
-    H, Hkv, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    eps, norm = m["norm_eps"], m["norm"]
-    mm = _mm(precision)
-    table = BM.leaf_table(c)
-    f32 = jnp.float32
-    hc = {k: jnp.float32(hc_cfg[k]) for k in ("B", "S", "D", "scale")}
-    t = tokens.shape[1]
-    q_block = min(256, t)
-
-    def leaf(name, layer=None):
-        shape, _, rule = table[name]
-        return BM.leaf_value(key, name, shape, rule, layer, dtype=f32)
-
-    embed = leaf("embed/table")
-    x = embed[tokens]                                      # (n, T, d)
-
-    def layer(x, l):
-        p = {name[len("layers/"):]: leaf(name, l)
-             for name in table if name.startswith("layers/")}
-        n1 = {"scale": p["norm1/scale"], "bias": p.get("norm1/bias")}
-        n2 = {"scale": p["norm2/scale"], "bias": p.get("norm2/bias")}
-
-        def one(xs):                                       # (T, d)
-            hn = _norm(xs, n1, norm, eps)
-            q = mm(hn, p["attn/wq"]).reshape(t, H, hd).transpose(1, 0, 2)
-            k = mm(hn, p["attn/wk"]).reshape(t, Hkv, hd).transpose(1, 0, 2)
-            v = mm(hn, p["attn/wv"]).reshape(t, Hkv, hd).transpose(1, 0, 2)
-            q, k = _rope(q, m["rope_theta"]), _rope(k, m["rope_theta"])
-            a = _hccs_attention(q, k, v, hc, mm, q_block)
-            xs = xs + mm(a.transpose(1, 0, 2).reshape(t, H * hd),
-                         p["attn/wo"])
-            hn = _norm(xs, n2, norm, eps)
-            u = mm(hn, p["mlp/w_in"])
-            if m["activation"] == "swiglu":
-                u = jax.nn.silu(mm(hn, p["mlp/w_gate"])) * u
-            elif m["activation"] == "gelu":
-                u = jax.nn.gelu(u, approximate=True)
-            else:
-                raise ValueError(m["activation"])
-            return xs + mm(u, p["mlp/w_out"])
-
-        return jax.lax.map(one, x), None
-
-    x, _ = jax.lax.scan(layer, x, jnp.arange(L))
-    fin = {"scale": leaf("final_norm/scale"),
-           "bias": leaf("final_norm/bias") if "final_norm/bias" in table
-           else None}
-    h = jnp.take_along_axis(x, gather[..., None], axis=1)  # (n, K, d)
-    return _norm(h, fin, norm, eps)
+    return registry.arch(c["arch"], root).hidden(key, tokens, gather, c,
+                                                 _mm(precision))
 
 
-@functools.partial(jax.jit, static_argnames=("c_json", "precision"))
-def _stats(key, h, toks, *, c_json, precision):
+@functools.partial(jax.jit, static_argnames=("c_json", "precision", "root"))
+def _stats(key, h, toks, *, c_json, precision, root):
     """h (n, K, d), toks (n, K) -> the logits' max, the logit of each token
-    in toks, and the argmax, each (n, K)."""
-    import json
+    in toks, and the argmax, each (n, K); the head is the tied embedding."""
     c = json.loads(c_json)
-    shape, _, rule = BM.leaf_table(c)["embed/table"]
+    table = registry.arch(c["arch"], root).leaf_table(c)
+    shape, _, rule = table["embed/table"]
     embed = BM.leaf_value(key, "embed/table", shape, rule, dtype=jnp.float32)
     logits = _mm(precision)(h, embed.T)                    # (n, K, V)
     at = jnp.take_along_axis(logits, toks[..., None], axis=-1)[..., 0]
@@ -158,15 +113,16 @@ def _stats(key, h, toks, *, c_json, precision):
 
 
 def logit_stats(c: dict, seed: int, sequences, *, t: int, k: int, n=None,
-                tokens=None, precision: str = "f32"):
+                tokens=None, precision: str = "f32", root=registry.ROOT):
     """sequences: list of (prompt, served tokens). The model is fed
     prompt + served[:-1] from position 0; at every position whose output
     was served it returns, per sequence, numpy arrays of the logits' max,
     the logit of the token given there (by default the served one; else
     tokens[i]) and the argmax. Feeds are padded to t positions, served
     tokens to k and the batch to n sequences (repeating the first), all
-    fixed per cell, so the reference compiles once per cell."""
-    import json
+    fixed per cell, so the reference compiles once per cell. The forward is
+    the one of the configuration's architecture module, found under
+    `root`."""
     c_json = json.dumps(c, sort_keys=True)
     key = BM.seed_key(seed)
     feeds = [np.concatenate([np.asarray(p), np.asarray(o[:-1])]).astype(
@@ -186,9 +142,9 @@ def logit_stats(c: dict, seed: int, sequences, *, t: int, k: int, n=None,
         fed[i, :len(f)] = f
         gather[i, :len(o)] = len(p) - 1 + np.arange(len(o))
         toks[i, :len(o)] = o if tokens is None else tokens[i]
-    h = _hidden(key, jnp.asarray(fed), jnp.asarray(gather),
-                c_json=c_json, precision=precision)
+    static = dict(c_json=c_json, precision=precision, root=str(root))
+    h = _hidden(key, jnp.asarray(fed), jnp.asarray(gather), **static)
     mx, at, am = (np.asarray(a) for a in _stats(
-        key, h, jnp.asarray(toks), c_json=c_json, precision=precision))
+        key, h, jnp.asarray(toks), **static))
     return [(mx[i, :len(o)], at[i, :len(o)], am[i, :len(o)])
             for i, (_, o) in enumerate(sequences[:real])]

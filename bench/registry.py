@@ -1,10 +1,12 @@
 """Everything the benchmark runs is found by name: a cell in
 BENCHMARK.json names its configuration (`configs/<config>.json`), its
 traffic mix (`traffic/<traffic>.json`) and its correctness limits
-(`checks/<cell>.json`); each metric BENCHMARK.json lists is read by
-`metrics/<metric>.py`, whose `read(ctx)` returns a number or None.
-Adding a cell, a configuration, a mix or a metric adds files and entries,
-and edits none."""
+(`checks/<cell>.json`); a configuration names its architecture
+(`archs/<arch>.py`: the program's ModelConfig, the weights' leaves, the
+FLOPs a step requires and the reference forward); each metric
+BENCHMARK.json lists is read by `metrics/<metric>.py`, whose `read(ctx)`
+returns a number or None. Adding a cell, a configuration, an
+architecture, a mix or a metric adds files and entries, and edits none."""
 from __future__ import annotations
 
 import importlib.util
@@ -26,7 +28,11 @@ def _json(root, sub, name) -> dict:
 
 
 def config(name, root=ROOT) -> dict:
-    return _json(root, "configs", name)
+    c = _json(root, "configs", name)
+    if "arch" not in c:
+        raise KeyError(f"configuration {name!r} names no \"arch\": add "
+                       f"\"arch\": \"<name>\" for archs/<name>.py")
+    return c
 
 
 def mix(name, root=ROOT) -> dict:
@@ -34,19 +40,35 @@ def mix(name, root=ROOT) -> dict:
 
 
 def check(cell, root=ROOT) -> dict:
-    return _json(root, "checks", cell)
+    """The cell's limits, and `requests`: how many finished requests the
+    reference checks."""
+    chk = _json(root, "checks", cell)
+    if "requests" not in chk:
+        raise KeyError(f"check file of {cell!r} names no \"requests\": the "
+                       f"finished requests the reference checks")
+    return chk
+
+
+def _module(root, sub, name, what):
+    """The module <sub>/<name>.py (the file name may hold dots)."""
+    path = Path(root) / sub / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {what} {name!r}: {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{sub}_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric, root=ROOT):
-    """`read` of metrics/<metric>.py (the file name may hold dots)."""
-    path = Path(root) / "metrics" / f"{metric}.py"
-    if not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {metric!r}: {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    """`read` of metrics/<metric>.py."""
+    return _module(root, "metrics", metric, "reader for metric").read
+
+
+def arch(name, root=ROOT):
+    """The architecture module archs/<name>.py."""
+    return _module(root, "archs", name, "architecture")
 
 
 def cell(bench: dict, name: str) -> dict:

@@ -1,11 +1,13 @@
-"""Model FLOPs from the configuration's shapes, pinned against hand
-counts, and the table of peaks."""
+"""Model FLOPs from the configuration's shapes (the dense architecture
+module's functions), pinned against hand counts, and the table of
+peaks."""
 import json
 
 import numpy as np
 
-from bench import model as BM
 from bench import registry
+
+dense = registry.arch("dense")
 
 
 def starcoder2():
@@ -19,30 +21,43 @@ def starcoder2():
     return c
 
 
+def test_starcoder2_file_holds_the_published_shape():
+    c = registry.config("starcoder2-3b")
+    assert c["arch"] == "dense"
+    assert c["model"] == dict(starcoder2()["model"], rope_theta=c["model"][
+        "rope_theta"])
+    pub = c["published"]
+    assert (pub["num_hidden_layers"], pub["hidden_size"],
+            pub["num_attention_heads"], pub["num_key_value_heads"],
+            pub["intermediate_size"], pub["vocab_size"]) == (
+        30, 3072, 24, 2, 12288, 49152)
+    assert c["model"]["rope_theta"] == pub["rope_theta"]
+
+
 def test_granite_per_token_flops():
     c = registry.config("granite-3-2b")
     # per layer: q 2048x2048, k and v 2048x512 each, o 2048x2048, SwiGLU
     # 3 x 2048x8192 = 60,817,408 weights; 2 FLOPs each, 40 layers
-    assert BM.matmul_flops_per_token(c) == 2 * 60_817_408 * 40
+    assert dense.matmul_flops_per_token(c) == 2 * 60_817_408 * 40
     # QK and PV: 2 x 2 x 32 heads x 64 per key, 40 layers
-    assert BM.attention_flops(c, 1000) == 2 * 2 * 32 * 64 * 40 * 1000
-    assert BM.head_flops(c) == 2 * 2048 * 49155
+    assert dense.attention_flops(c, 1000) == 2 * 2 * 32 * 64 * 40 * 1000
+    assert dense.head_flops(c) == 2 * 2048 * 49155
 
 
 def test_starcoder2_per_token_flops():
     c = starcoder2()
     # q 3072x3072, k and v 3072x256 each, o 3072x3072, GELU MLP
     # 2 x 3072x12288 = 95,944,704 weights a layer, 30 layers
-    assert BM.matmul_flops_per_token(c) == 2 * 95_944_704 * 30
-    assert BM.attention_flops(c, [10, 20]) == 2 * 2 * 24 * 128 * 30 * 30
-    assert BM.head_flops(c) == 2 * 3072 * 49152
+    assert dense.matmul_flops_per_token(c) == 2 * 95_944_704 * 30
+    assert dense.attention_flops(c, [10, 20]) == 2 * 2 * 24 * 128 * 30 * 30
+    assert dense.head_flops(c) == 2 * 3072 * 49152
 
 
 def test_step_flops_counts_valid_tokens_and_samples():
     c = registry.config("granite-3-2b")
-    f = BM.step_flops(c, [1, 2, 3], 2)
-    assert f == (3 * BM.matmul_flops_per_token(c) + BM.attention_flops(c, 6)
-                 + 2 * BM.head_flops(c))
+    f = dense.step_flops(c, [1, 2, 3], 2)
+    assert f == (3 * dense.matmul_flops_per_token(c)
+                 + dense.attention_flops(c, 6) + 2 * dense.head_flops(c))
 
 
 def test_step_contexts_read_the_recorded_inputs():
@@ -72,9 +87,9 @@ def test_params_are_whole_models():
     for c, n in ((registry.config("granite-3-2b"), granite),
                  (starcoder2(), starcoder)):
         total = 0
-        for path, (shape, stacked, _) in BM.leaf_table(c).items():
+        for path, (shape, layers, _) in dense.leaf_table(c).items():
             k = 1
             for s in shape:
                 k *= s
-            total += k * (c["model"]["num_layers"] if stacked else 1)
+            total += k * max(layers, 1)
         assert total == n

@@ -17,6 +17,7 @@ from bench import harness, registry
 # control 0.16 to 0.25 (seeds 1-4)
 TINY_LIMIT = 0.05
 SEED = 2 ** 31 + 11
+REQUESTS = {"closed": 8, "open": 5}    # each check file's `requests`
 
 
 @pytest.fixture(scope="module")
@@ -24,8 +25,9 @@ def tiny(tmp_path_factory):
     """A benchmark root with a tiny configuration (granite's block at
     d_model 64, 2 layers, vocab 256) and two small mixes."""
     root = tmp_path_factory.mktemp("bench")
-    shutil.copytree(registry.ROOT / "metrics", root / "metrics",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for d in ("metrics", "archs"):
+        shutil.copytree(registry.ROOT / d, root / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(registry.ROOT / "peaks.json", root)
     for d in ("configs", "traffic", "checks"):
         (root / d).mkdir()
@@ -49,11 +51,16 @@ def tiny(tmp_path_factory):
     bench["workloads"] = [
         {"name": f"tiny.{m}", "config": "tiny", "traffic": m, "chips": 1,
          "why": "test"} for m in ("closed", "open")]
+    bench["per_layer"].append({        # a reader no listed cell reads yet
+        "name": "sched.prefix_skip_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "scheduler",
+        "moves": "ttft_p50_ms"})
     for m in bench["per_layer"] + bench["end_to_end"]:
         m.pop("workloads", None)
-    for w in bench["workloads"]:
-        (root / "checks" / f"{w['name']}.json").write_text(json.dumps(
-            {"logit_gap_max": {"limit": TINY_LIMIT}}))
+    for m in REQUESTS:
+        (root / "checks" / f"tiny.{m}.json").write_text(json.dumps(
+            {"requests": REQUESTS[m],
+             "logit_gap_max": {"limit": TINY_LIMIT}}))
     return root, bench
 
 
@@ -106,6 +113,45 @@ def test_traced_run_reads_the_layer_metrics(tiny):
     assert "output_tokens_per_s" not in m
     assert out["device"]["window_s"] > 0
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_open_loop_prefix_sharing_passes_and_the_control_fails(
+        tiny, monkeypatch):
+    """Shared contexts filled at set-up, arrivals on a schedule: the trie
+    skips part of every prompt, the served tokens pass, the control's fail,
+    and the reference checks the check file's number of requests."""
+    from bench import reference as R
+    real, shapes = R.logit_stats, []
+
+    def spy(c, seed, sequences, **kw):
+        shapes.append((len(sequences), kw["n"]))
+        return real(c, seed, sequences, **kw)
+    monkeypatch.setattr(R, "logit_stats", spy)
+    out = _run(tiny, cell="tiny.open", trace=True, control=True)
+    assert out["correct"], out["check"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert not out["control"]["correct"]
+    assert out["control"]["check"]["logit_gap_max"]["value"] > TINY_LIMIT
+    assert 0 < out["metrics"]["sched.prefix_skip_share"]["value"] < 100
+    # the program's check, then the control's tokens and their gaps
+    assert shapes == [(REQUESTS["open"], REQUESTS["open"])] * 3
+
+
+def test_sample_takes_the_longest_and_n_from_the_seed():
+    import types
+    recs = [types.SimpleNamespace(
+        finished=i != 3, req=types.SimpleNamespace(
+            prompt=[0] * (10 + i), out_tokens=[1] * (i % 4)))
+        for i in range(12)]
+    done = [r for r in recs if r.finished and r.req.out_tokens]
+    for n in (1, 4, 24):
+        got = harness.sample(recs, SEED, n)
+        assert len(got) == min(n, len(done))
+        assert got[0] is recs[11]                   # the longest first
+        assert all(r in done for r in got)
+        assert len({id(r) for r in got}) == len(got)
+        assert got == harness.sample(recs, SEED, n)
+    assert harness.sample(recs, SEED, 4) != harness.sample(recs, SEED + 1, 4)
 
 
 def test_without_a_tpu_the_command_exits_with_no_result():

@@ -255,8 +255,9 @@ def _tiny_config(**serving):
 
 def _engine(c, seed=SEED):
     from repro.serve import PagedEngine
-    cfg = BM.model_config(c)
-    params = BM.make_params(c, seed, cfg)
+    arch = registry.arch(c["arch"])
+    cfg = arch.model_config(c)
+    params = BM.make_params(c, seed, cfg, arch)
     sv = c["serving"]
     return PagedEngine(params, cfg, max_batch=sv["max_batch"],
                        max_len=sv["max_len"], block_size=sv["block_size"],
@@ -370,7 +371,7 @@ def test_work_counters_match_the_step_inputs_on_rag_4k():
     is handed, lanes_valid their count, sampled_lanes the tokens returned:
     the model FLOPs from the counters equal the harness's count from the
     step inputs. The rag-4k mix's first requests at tiny width."""
-    from bench import harness
+    from bench import harness, traced
     c = _tiny_config()                         # rag-4k's serving shape
     eng = _engine(c)
     seen, _ = _recording(eng)
@@ -388,12 +389,13 @@ def test_work_counters_match_the_step_inputs_on_rag_4k():
     assert d["lanes_valid"] == sum(len(kv) for kv in contexts)
     tokens = sum(len(r.out_tokens) for r in done)
     assert d["sampled_lanes"] == tokens == 6
-    wrapper = (sum(BM.step_flops(c, kv, 0) for kv in contexts)
-               + tokens * BM.head_flops(c))
-    counted = (d["lanes_valid"] * BM.matmul_flops_per_token(c)
-               + BM.attention_flops(c, d["ctx_positions"])
-               + d["sampled_lanes"] * BM.head_flops(c))
-    assert counted == wrapper
+    arch = registry.arch(c["arch"])
+    wrapper = (sum(arch.step_flops(c, kv, 0) for kv in contexts)
+               + tokens * arch.head_flops(c))
+    counted = (d["lanes_valid"] * arch.matmul_flops_per_token(c)
+               + arch.attention_flops(c, d["ctx_positions"])
+               + d["sampled_lanes"] * arch.head_flops(c))
+    assert counted == wrapper == traced.counter_flops(arch, c, d)
 
 
 def test_traced_windows_on_the_cpu(tmp_path, monkeypatch):
@@ -405,8 +407,10 @@ def test_traced_windows_on_the_cpu(tmp_path, monkeypatch):
     for d in ("configs", "traffic", "checks"):
         (root / d).mkdir(parents=True)
     shutil.copy(registry.ROOT / "peaks.json", root)
+    shutil.copytree(registry.ROOT / "archs", root / "archs",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (root / "checks" / "tiny.closed.json").write_text(json.dumps(
-        {"logit_gap_max": {"limit": 0.05}}))
+        {"requests": 8, "logit_gap_max": {"limit": 0.05}}))
     c = _tiny_config(max_batch=4, max_len=128, block_size=8, num_blocks=80)
     (root / "configs" / "tiny.json").write_text(json.dumps(c))
     (root / "traffic" / "closed.json").write_text(json.dumps(dict(

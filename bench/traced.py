@@ -42,6 +42,8 @@ import tempfile  # noqa: E402
 from collections import Counter, defaultdict  # noqa: E402
 from pathlib import Path  # noqa: E402
 
+import numpy as np  # noqa: E402
+
 CHECKOUT = Path(__file__).resolve().parents[1]
 LONG_STEP_S = 0.5   # a stall: a rag-4k step takes 0.33 s on a TPU v5e
 
@@ -141,12 +143,25 @@ def by_scope(tr, programs) -> dict:
                                    key=lambda kv: -kv[1])[:10]}
 
 
-def read_window(c, tdir, calls, fn, counters, tokens) -> dict:
+def counter_flops(arch, c, d) -> float | None:
+    """The model FLOPs of the counters' deltas `d`: `lanes_valid` tokens
+    whose causal contexts sum to `ctx_positions`, and `sampled_lanes`
+    positions through the head. The counters keep only the sum, so one
+    lane carries it: right where a token's FLOPs grow linearly with its
+    context."""
+    if "ctx_positions" not in d:
+        return None
+    contexts = np.zeros(d["lanes_valid"], np.int64)
+    contexts[:1] = d["ctx_positions"]
+    return arch.step_flops(c, contexts, d["sampled_lanes"])
+
+
+def read_window(c, arch, tdir, calls, fn, counters, tokens) -> dict:
     """Everything the module docstring lists, from the trace in tdir, the
     step inputs `calls` handed to `fn`, the engine counters at the window's
-    two ends and the tokens its steps returned."""
+    two ends and the tokens its steps returned; `arch` is the
+    configuration's architecture module."""
     from bench import harness
-    from bench import model as BM
     from bench import spans as S
     from bench import trace as T
     tr = T.load(tdir)
@@ -161,12 +176,9 @@ def read_window(c, tdir, calls, fn, counters, tokens) -> dict:
     split = S.gap_split_ns(tr, sp, _is_step)
     d = {k: counters[1][k] - counters[0][k] for k in counters[0]}
     contexts = harness.step_contexts([a[5] for a in calls])
-    wrapper = (sum(BM.step_flops(c, kv, 0) for kv in contexts)
-               + tokens * BM.head_flops(c)) if contexts else None
-    from_counters = (d["lanes_valid"] * BM.matmul_flops_per_token(c)
-                     + BM.attention_flops(c, d["ctx_positions"])
-                     + d["sampled_lanes"] * BM.head_flops(c)
-                     if "ctx_positions" in d else None)
+    wrapper = (sum(arch.step_flops(c, kv, 0) for kv in contexts)
+               + tokens * arch.head_flops(c)) if contexts else None
+    from_counters = counter_flops(arch, c, d)
     return {
         "window_s": (hi - lo) / 1e9, "busy_s": T.busy_ns(tr) / 1e9,
         "idle_share": T.idle_share(tr), "steps": len(calls),
@@ -234,7 +246,8 @@ def run(cell: str, seed: int, seconds: float, *, window_s: float = 4.0,
                 marks=[(at, start), (at + window_s, stop)], **kw)
     eng._packed_fn = fn
     tokens = sum(n for s, e, n in res.steps if w["t0"] <= s and e <= w["t1"])
-    out = read_window(c, tdir, w["calls"], fn, (w["c0"], w["c1"]), tokens)
+    out = read_window(c, su.arch, tdir, w["calls"], fn, (w["c0"], w["c1"]),
+                      tokens)
     shutil.rmtree(tdir, ignore_errors=True)
     return out
 
