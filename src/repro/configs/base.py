@@ -5,6 +5,9 @@ import dataclasses
 from typing import Literal
 
 
+LAYER_KINDS = ("attention", "mamba")
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -20,6 +23,16 @@ class ModelConfig:
     norm: str = "rmsnorm"            # rmsnorm | layernorm
     rope: str = "rope"               # rope | mrope | none | learned
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    # Granite's scalars, each neutral at its default: the embedding is
+    # multiplied by embedding_multiplier, each mixer's and MLP's output by
+    # residual_multiplier before it joins the residual stream, attention
+    # logits by attention_multiplier (0: 1/sqrt(head_dim)), and the logits
+    # divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     mrope_sections: tuple[int, ...] = (16, 24, 24)
     causal: bool = True
     # --- HCCS (the paper's technique) ---
@@ -39,6 +52,12 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_groups: int = 1
     ssm_chunk: int = 128
+    ssm_conv: int = 4                # depthwise causal conv width over xBC
+    # --- layer pattern ---
+    # one kind per layer ("attention" | "mamba"), as the published config's
+    # `layer_types`; empty means every layer is `family`'s block. Each kind's
+    # layers are stacked over one axis, in layer order (models/model.py)
+    layer_types: tuple[str, ...] = ()
     # --- hybrid (hymba) ---
     hybrid: bool = False             # parallel attn + SSM heads per block
     window: int = 0                  # sliding-window attention (0 = full)
@@ -134,6 +153,16 @@ class ModelConfig:
     def __post_init__(self):
         if self.num_heads and not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_layers is {self.num_layers}")
+            bad = set(self.layer_types) - set(LAYER_KINDS)
+            if bad:
+                raise ValueError(f"layer_types may hold {LAYER_KINDS}, "
+                                 f"got {sorted(bad)}")
         if self.decode_kernel not in ("none", "fused", "static_max"):
             raise ValueError(
                 f"decode_kernel must be 'none' | 'fused' | 'static_max', "
@@ -185,6 +214,22 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the SSM's causal conv: x, B and C."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def carries_state(self) -> bool:
+        """True when a sequence carries recurrent state between steps."""
+        if self.layer_types:
+            return "mamba" in self.layer_types
+        return self.family in ("ssm", "hybrid")
+
+    def layers_of(self, kind: str) -> int:
+        """How many layers of `kind` a layer pattern holds."""
+        return self.layer_types.count(kind)
 
     @property
     def is_moe(self) -> bool:

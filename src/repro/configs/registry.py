@@ -22,6 +22,7 @@ _ARCH_MODULES = {
     "mamba2-1.3b": "mamba2_1_3b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)
@@ -61,6 +62,8 @@ def reduced_config(arch: str) -> ModelConfig:
         kw.update(ssm_state=16, ssm_head_dim=32, ssm_chunk=16)
     if cfg.window:
         kw.update(window=16)
+    if cfg.layer_types:
+        kw.update(num_layers=4, layer_types=("mamba", "attention") * 2)
     return cfg.replace(**kw)
 
 

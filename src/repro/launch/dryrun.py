@@ -69,7 +69,7 @@ def _batch_shardings(batch_specs, mesh, batch_divisible):
 
 
 def _cache_shardings(cache_shapes, mesh, batch_divisible):
-    """(L,B,hkv,T,hd) attn caches / (L,B,nh,N,P) ssm states / length scalar."""
+    """(L,B,hkv,T,hd) attn caches / (L,B,nh,P,N) ssm states / length scalar."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
 
     def one(path, leaf):

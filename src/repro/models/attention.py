@@ -91,6 +91,9 @@ def decode_kernel_blockers(cfg) -> list:
         blockers.append(f"window={cfg.window}")
     if cfg.hot_buffer:
         blockers.append(f"hot_buffer={cfg.hot_buffer}")
+    if cfg.attention_multiplier:
+        # the kernels scale q.k by 1/sqrt(head_dim)
+        blockers.append(f"attention_multiplier={cfg.attention_multiplier}")
     return blockers
 
 
@@ -301,6 +304,14 @@ def _paged_gather(pool, block_table, hd, scales=None, layer=None):
     return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, nblk * bs, hd_c)[..., :hd]
 
 
+def _scale_logits(logits, cfg, hd):
+    """q.k times cfg.attention_multiplier, or over sqrt(head_dim) where
+    that is 0."""
+    if cfg.attention_multiplier:
+        return logits * jnp.float32(cfg.attention_multiplier)
+    return logits / jnp.sqrt(jnp.float32(hd))
+
+
 def _block_valid(cfg, q_pos, k_pos, k_len=None):
     """Validity mask (B, 1, Tq, Tk_blk) from positions, computed lazily.
 
@@ -325,7 +336,7 @@ def _dense_attention(q, k, v, valid, cfg, hccs):
     g = h // hkv
     qg = q.reshape(b, hkv, g, tq, hd)
     logits = jnp.einsum("bkgqd,bktd->bkgqt", qg, k).astype(jnp.float32)
-    logits = (logits / jnp.sqrt(jnp.float32(hd))).reshape(b, h, tq, tk)
+    logits = _scale_logits(logits, cfg, hd).reshape(b, h, tq, tk)
     if _CAPTURE is not None:
         _CAPTURE.append(logits)
     if cfg.attention_prob == "hccs" and hccs is not None:
@@ -362,7 +373,8 @@ def _blockwise_attention(q, k, v, q_pos, k_len, cfg, hccs):
     if k_len is None:
         k_len = jnp.full((b,), tk, jnp.int32)
     qg = q.reshape(b, hkv, g, tq, hd)
-    sm = 1.0 / jnp.sqrt(jnp.float32(hd))
+    sm = (jnp.float32(cfg.attention_multiplier) if cfg.attention_multiplier
+          else 1.0 / jnp.sqrt(jnp.float32(hd)))
 
     def logits_of(kblk):
         lg = jnp.einsum("bkgqd,bktd->bkgqt", qg, kblk).astype(jnp.float32) * sm
@@ -462,7 +474,7 @@ def _segment_partials(q, k, v, valid, m, cfg, hccs):
     g = h // hkv
     qg = q.reshape(b, hkv, g, tq, hd)
     logits = jnp.einsum("bkgqd,bktd->bkgqt", qg, k).astype(jnp.float32)
-    logits = (logits / jnp.sqrt(jnp.float32(hd))).reshape(b, h, tq, tk)
+    logits = _scale_logits(logits, cfg, hd).reshape(b, h, tq, tk)
     if cfg.attention_prob == "hccs" and hccs is not None:
         scale = hccs["scale"][:, None, None]
         B = hccs["B"][:, None, None].astype(jnp.float32)
@@ -486,7 +498,7 @@ def _segment_max(q, k, valid, cfg, hccs):
     g = h // hkv
     qg = q.reshape(b, hkv, g, tq, hd)
     logits = jnp.einsum("bkgqd,bktd->bkgqt", qg, k).astype(jnp.float32)
-    logits = (logits / jnp.sqrt(jnp.float32(hd))).reshape(b, h, tq, tk)
+    logits = _scale_logits(logits, cfg, hd).reshape(b, h, tq, tk)
     if cfg.attention_prob == "hccs" and hccs is not None:
         logits = jnp.round(jnp.clip(logits / hccs["scale"][:, None, None],
                                     -128., 127.))

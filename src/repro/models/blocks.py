@@ -6,8 +6,11 @@ layer params:
     apply_block(p, x, cfg, hccs, cache, positions, mrope_positions)
         -> (x, new_cache, aux)
 
-cache is a per-layer dict (may contain 'k','v' for attention and/or 'ssm'
-state); `length` is carried by the model, not per layer.
+cache is a per-layer dict (may contain 'k','v' for attention and/or the
+'ssm' and 'conv' states); `length` is carried by the model, not per layer.
+A configuration with `layer_types` builds each layer from its kind
+(`kind=`): "attention" is the dense block, "mamba" the same block with the
+Mamba-2 mixer in attention's place.
 """
 from __future__ import annotations
 
@@ -20,9 +23,25 @@ from repro.models import ssm as ssm_mod
 from repro.models.layers import apply_mlp, apply_norm, init_mlp, init_norm
 
 
-def init_block(rng, cfg):
+def _residual(x, y, cfg):
+    """x + y, y scaled by cfg.residual_multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
+def init_block(rng, cfg, kind=None):
     ks = jax.random.split(rng, 4)
     p: dict = {"norm1": init_norm(cfg)}
+    if kind is not None:
+        if kind == "attention":
+            p["attn"] = attn.init_attention(ks[0], cfg)
+        else:
+            p["ssm"] = ssm_mod.init_ssm(ks[1], cfg)
+        if cfg.d_ff > 0:
+            p["norm2"] = init_norm(cfg)
+            p["mlp"] = init_mlp(ks[3], cfg)
+        return p
     fam = cfg.family
     if fam in ("dense", "moe", "audio", "vlm", "encoder", "hybrid"):
         p["attn"] = attn.init_attention(ks[0], cfg)
@@ -39,7 +58,7 @@ def init_block(rng, cfg):
     return p
 
 
-def init_layer_cache(cfg, batch, max_len, cache_dtype=None):
+def init_layer_cache(cfg, batch, max_len, cache_dtype=None, kind=None):
     """Zero cache for ONE layer (the model stacks L of these).
 
     cache_dtype=None resolves to cfg.cache_dtype (the single-sourced default
@@ -52,7 +71,11 @@ def init_layer_cache(cfg, batch, max_len, cache_dtype=None):
     if cache_dtype is None:
         cache_dtype = jnp.dtype(cfg.cache_dtype)
     c: dict = {}
-    if cfg.family in ("dense", "moe", "audio", "vlm", "hybrid"):
+    if kind == "mamba":
+        c["ssm"], c["conv"] = ssm_mod.init_state(cfg, batch)
+        return c
+    if kind == "attention" or cfg.family in ("dense", "moe", "audio", "vlm",
+                                             "hybrid"):
         hkv = cfg.num_kv_heads
         hd_c, len_c = attn.kv_store_geometry(cfg, max_len)
         c["k"] = jnp.zeros((batch, hkv, len_c, hd_c), cache_dtype)
@@ -63,45 +86,49 @@ def init_layer_cache(cfg, batch, max_len, cache_dtype=None):
                                    cache_dtype)
             c["hot_v"] = jnp.zeros((batch, hkv, cfg.hot_buffer, hd_c),
                                    cache_dtype)
-    if cfg.family in ("ssm", "hybrid"):
-        c["ssm"] = jnp.zeros((batch, cfg.ssm_heads, cfg.ssm_state,
-                              cfg.ssm_head_dim), jnp.float32)
+    if kind is None and cfg.family in ("ssm", "hybrid"):
+        c["ssm"], c["conv"] = ssm_mod.init_state(cfg, batch)
     return c
 
 
+def _ssm_mixer(p, h, cfg, cache):
+    """The Mamba-2 mixer over h with the layer's carried states: none,
+    (B, T) rows, or the paged engine's packed step (slot_ids)."""
+    if cache is None:
+        y, _, _ = ssm_mod.apply_ssd(p, h, cfg)
+        return y, {}
+    if "slot_ids" in cache:
+        y, st, cs = ssm_mod.apply_ssd_packed(p, h, cfg, cache["ssm"],
+                                             cache["conv"], cache)
+    else:
+        y, st, cs = ssm_mod.apply_ssd(p, h, cfg, cache["ssm"], cache["conv"])
+    return y, {"ssm": st, "conv": cs}
+
+
 def apply_block(p, x, cfg, hccs=None, cache=None, length=None, positions=None,
-                mrope_positions=None, decode: bool = False):
+                mrope_positions=None, kind=None):
     """Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: dict = {}
     fam = cfg.family
     h = apply_norm(p["norm1"], x, cfg)
 
-    if fam == "ssm":
-        if decode:
-            y, st = ssm_mod.apply_ssd_step(p["ssm"], h, cfg, cache["ssm"])
-        else:
-            st0 = cache["ssm"] if cache is not None else None
-            y, st = ssm_mod.apply_ssd(p["ssm"], h, cfg, st0)
-        if cache is not None:
-            new_cache["ssm"] = st
-        x = x + y
-    elif fam == "hybrid":
+    if kind == "mamba" or (kind is None and fam == "ssm"):
+        y, new_cache = _ssm_mixer(p["ssm"], h, cfg, cache)
+        x = _residual(x, y, cfg)
+    elif kind is None and fam == "hybrid":
         ac = None
         if cache is not None:
-            ac = {k_: v_ for k_, v_ in cache.items() if k_ != "ssm"}
+            ac = {k_: v_ for k_, v_ in cache.items()
+                  if k_ not in ("ssm", "conv")}
             ac["length"] = length
         ya, nc = attn.apply_attention(p["attn"], h, cfg, hccs, positions, ac,
                                       mrope_positions)
-        if decode:
-            ys, st = ssm_mod.apply_ssd_step(p["ssm"], h, cfg, cache["ssm"])
-        else:
-            st0 = cache["ssm"] if cache is not None else None
-            ys, st = ssm_mod.apply_ssd(p["ssm"], h, cfg, st0)
+        ys, sc = _ssm_mixer(p["ssm"], h, cfg, cache)
         if cache is not None:
             new_cache.update({k_: v_ for k_, v_ in nc.items()
                               if k_ != "length"})
-            new_cache["ssm"] = st
+            new_cache.update(sc)
         x = x + 0.5 * (ya + ys)      # mean-fused parallel heads (Hymba-style)
     else:
         ac = None
@@ -113,15 +140,15 @@ def apply_block(p, x, cfg, hccs=None, cache=None, length=None, positions=None,
         if cache is not None:
             new_cache.update({k_: v_ for k_, v_ in nc.items()
                               if k_ != "length"})
-        x = x + y
+        x = _residual(x, y, cfg)
 
     if "moe" in p:
         y, aux = moe_mod.apply_moe(p["moe"], apply_norm(p["norm2"], x, cfg), cfg)
-        x = x + y
+        x = _residual(x, y, cfg)
     elif "mlp" in p:
         h = apply_norm(p["norm2"], x, cfg)
         with jax.named_scope("mlp"):
-            x = x + apply_mlp(p["mlp"], h, cfg)
+            x = _residual(x, apply_mlp(p["mlp"], h, cfg), cfg)
     # Megatron-style sequence parallelism on the residual stream: between
     # blocks the carry is sharded over ("batch", seq->model); with remat=full
     # this shrinks the saved per-layer carry by the TP degree. 'seq_act' maps

@@ -25,7 +25,8 @@ def init_norm(cfg, d=None):
     return p
 
 
-def apply_norm(p, x, cfg, eps=1e-6):
+def apply_norm(p, x, cfg):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = jnp.mean(xf, -1, keepdims=True)
@@ -125,17 +126,22 @@ def init_embed(rng, cfg):
 
 def embed_tokens(p, tokens, cfg):
     out = jnp.take(p["table"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.embedding_multiplier, out.dtype)
     return constrain(out, "batch", "seq_act", "embed")
 
 
 def lm_logits(embed_p, head_p, x, cfg):
-    """Logits over the padded vocab; pad lanes masked to -inf (Megatron-style
-    padded vocab keeps the table TP-divisible; semantics unchanged)."""
+    """Logits over the padded vocab, divided by cfg.logits_scaling; pad
+    lanes masked to -inf (Megatron-style padded vocab keeps the table
+    TP-divisible; semantics unchanged)."""
     if cfg.tie_embeddings:
         w = embed_p["table"].T
     else:
         w = head_p["lm_head"]
     logits = x @ w.astype(x.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     logits = constrain(logits, "batch", "attn_seq", "vocab")
     if cfg.padded_vocab != cfg.vocab_size:
         lane = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)

@@ -1,12 +1,18 @@
 """The LM: embedding -> lax.scan over stacked blocks -> norm -> logits.
 
 scan-over-layers keeps HLO size O(1) in depth (94-layer MoE compiles in the
-same HLO footprint as a 2-layer toy) and is the natural remat unit.
+same HLO footprint as a 2-layer toy) and is the natural remat unit. A
+configuration with `layer_types` scans the pattern's period instead, each
+kind's layers stacked over one axis of their own (layer_pattern).
 
 Params layout:
     {"weights": {"embed": ..., "pos_embed"?: ..., "layers": <stacked block
      pytree>, "final_norm": ..., "lm_head"?: ..., "cls_head"?: ...},
      "hccs": {"B","S","D","scale" : (L, H)} | {} }
+
+With `layer_types`, "layers" holds one stacked block pytree per kind,
+{"attention": ..., "mamba": ...}, over that kind's layers in layer order,
+and `hccs` is stacked over the attention layers alone.
 
 `hccs` holds the paper's frozen per-head calibration constants — they are
 deliberately OUTSIDE "weights" so the optimizer never touches them (the paper
@@ -28,8 +34,16 @@ from repro.parallel.sharding import constrain
 
 def init_params(rng, cfg, hccs_n_ref: int = 128):
     kE, kL, kH, kC = jax.random.split(rng, 4)
-    layer_keys = jax.random.split(kL, cfg.num_layers)
-    layers = jax.vmap(lambda k: blocks.init_block(k, cfg))(layer_keys)
+    if cfg.layer_types:
+        layers = {}
+        for i, kind in enumerate(sorted(set(cfg.layer_types))):
+            keys = jax.random.split(jax.random.fold_in(kL, i),
+                                    cfg.layers_of(kind))
+            layers[kind] = jax.vmap(
+                lambda k, kind=kind: blocks.init_block(k, cfg, kind))(keys)
+    else:
+        layer_keys = jax.random.split(kL, cfg.num_layers)
+        layers = jax.vmap(lambda k: blocks.init_block(k, cfg))(layer_keys)
     weights = {"embed": init_embed(kE, cfg), "layers": layers,
                "final_norm": init_norm(cfg)}
     if cfg.rope == "learned":
@@ -46,18 +60,20 @@ def init_params(rng, cfg, hccs_n_ref: int = 128):
                               jnp.dtype(cfg.dtype)) * cfg.d_model ** -0.5)
 
     hccs = {}
-    if cfg.attention_prob == "hccs" and cfg.num_heads > 0:
+    n_attn = (cfg.layers_of("attention") if cfg.layer_types
+              else cfg.num_layers)
+    if cfg.attention_prob == "hccs" and cfg.num_heads > 0 and n_attn:
         one = init_hccs_head_params(cfg, n_ref=hccs_n_ref)
         hccs = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.num_layers,) + a.shape), one)
+            lambda a: jnp.broadcast_to(a[None], (n_attn,) + a.shape), one)
         hccs = jax.tree.map(jnp.asarray, hccs)
     return {"weights": weights, "hccs": hccs}
 
 
-def _block_caller(cfg, decode):
+def _block_caller(cfg, kind=None):
     def call(lp, x, hc, cache, length, positions, mrope_positions):
         return blocks.apply_block(lp, x, cfg, hc, cache, length, positions,
-                                  mrope_positions, decode=decode)
+                                  mrope_positions, kind=kind)
 
     if cfg.remat == "full":
         return jax.checkpoint(call)
@@ -67,7 +83,7 @@ def _block_caller(cfg, decode):
     return call
 
 
-def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
+def forward(weights, hccs, batch, cfg, cache=None):
     """batch: {"tokens": (B,T)} or {"embeddings": (B,T,D)}, optional
     "positions" (B,T), "mrope_positions" (3,B,T).
 
@@ -109,7 +125,25 @@ def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
                         if kk in cache}
 
     hccs = jax.tree.map(jax.lax.stop_gradient, hccs)  # theta frozen (paper QAT)
-    call = _block_caller(cfg, decode)
+    scan = _scan_pattern if cfg.layer_types else _scan_stacked
+    x, aux, new_layer_caches = scan(
+        weights["layers"], hccs, x, cfg, cache, length, positions,
+        mrope_positions, hot_len, paged_extras)
+
+    x = apply_norm(weights["final_norm"], x, cfg)
+    new_cache = None
+    if cache is not None:
+        new_cache = {"layers": new_layer_caches, "length": length + t}
+        if "prompt_len" in cache:
+            new_cache["prompt_len"] = cache["prompt_len"]
+    return x, new_cache, aux
+
+
+def _scan_stacked(layers, hccs, x, cfg, cache, length, positions,
+                  mrope_positions, hot_len, paged_extras):
+    """Every layer one block of cfg.family, stacked over num_layers, in one
+    lax.scan. Returns (x, aux, new per-layer caches)."""
+    call = _block_caller(cfg)
 
     hccs_xs = hccs if hccs else None
     cache_xs = cache["layers"] if cache is not None else None
@@ -125,7 +159,7 @@ def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
         pools = {kk: cache_xs[kk] for kk in ("k", "v", "k_scale", "v_scale")
                  if kk in cache_xs}
         cache_xs = jnp.arange(L)
-    xs = (weights["layers"], hccs_xs, cache_xs)
+    xs = (layers, hccs_xs, cache_xs)
     # lax.scan requires every xs leaf to have leading dim L; None legs are
     # replaced by dummy per-layer zeros.
     if hccs_xs is None:
@@ -158,14 +192,96 @@ def forward(weights, hccs, batch, cfg, cache=None, decode: bool = False):
         unroll=min(cfg.scan_unroll, L))
     if pools:
         new_layer_caches = dict(new_layer_caches, **pools)
+    return x, aux, new_layer_caches
 
-    x = apply_norm(weights["final_norm"], x, cfg)
-    new_cache = None
-    if cache is not None:
-        new_cache = {"layers": new_layer_caches, "length": length + t}
-        if "prompt_len" in cache:
-            new_cache["prompt_len"] = cache["prompt_len"]
-    return x, new_cache, aux
+
+def layer_pattern(cfg):
+    """The period of cfg.layer_types as runs: the smallest P dividing
+    num_layers with layer_types[i] == layer_types[i % P], and the period's
+    runs of consecutive layers of one kind, each (kind, index of its first
+    layer among the period's layers of that kind, count)."""
+    types = cfg.layer_types
+    n = len(types)
+    period = next(p for p in range(1, n + 1) if n % p == 0 and all(
+        types[i] == types[i % p] for i in range(n)))
+    runs, seen, i = [], {}, 0
+    while i < period:
+        j = i
+        while j < period and types[j] == types[i]:
+            j += 1
+        runs.append((types[i], seen.get(types[i], 0), j - i))
+        seen[types[i]] = seen.get(types[i], 0) + j - i
+        i = j
+    return period, runs
+
+
+# the carried cache leaves each layer kind reads and writes at its index,
+# and the scope that reads and writes them
+_KIND_STATE = {"attention": ("k", "v"), "mamba": ("ssm", "conv")}
+_STATE_SCOPE = {"attention": "attn.kv_write", "mamba": "ssm.state"}
+
+
+def _scan_pattern(layers, hccs, x, cfg, cache, length, positions,
+                  mrope_positions, hot_len, paged_extras):
+    """The layer pattern: lax.scan over its periods, and inside a period
+    over each run of one kind's layers, so the HLO holds one body per run.
+
+    Each kind's cache leaves are the whole stack over that kind's layers
+    (attention K/V, the Mamba-2 SSM and conv states) and ride the scans'
+    carry: a layer reads its own slice by index and writes it back in
+    place, as the token-major paged pool does in _scan_stacked. A paged
+    pool is handed to attention whole, with the layer index. Returns
+    (x, aux, new cache leaves)."""
+    if hot_len is not None:
+        raise ValueError("hot buffers are not supported with layer_types")
+    period, runs = layer_pattern(cfg)
+    n_per = cfg.num_layers // period
+    per = {k: cfg.layers_of(k) // n_per for k in layers}
+    calls = {k: _block_caller(cfg, k) for k in layers}
+    paged = paged_extras is not None
+    state = dict(cache["layers"]) if cache is not None else {}
+
+    def one_layer(kind, st, li, x):
+        # weights are read at the layer's index in the whole stack: a
+        # period's slice as scan xs would be copied out of it every period
+        lp = jax.tree.map(lambda a: a[li], layers[kind])
+        hc = (jax.tree.map(lambda a: a[li], hccs)
+              if kind == "attention" and hccs else None)
+        names = _KIND_STATE[kind]
+        lc = None
+        if cache is not None:
+            if kind == "attention" and paged:
+                lc = dict({n: st[n] for n in names}, layer=li)
+            else:
+                with jax.named_scope(_STATE_SCOPE[kind]):
+                    lc = {n: st[n][li] for n in names}
+            if paged:
+                lc = dict(lc, length=length, **paged_extras)
+        x, new_lc, aux = calls[kind](lp, x, hc, lc, length, positions,
+                                     mrope_positions)
+        if cache is not None:
+            if kind == "attention" and paged:
+                st = dict(st, **{n: new_lc[n] for n in names})
+            else:
+                with jax.named_scope(_STATE_SCOPE[kind]):
+                    st = dict(st, **{n: st[n].at[li].set(new_lc[n])
+                                     for n in names})
+        return x, st, aux
+
+    def period_body(carry, pi):
+        for kind, start, n in runs:
+            def run_body(c, j, kind=kind, base=pi * per[kind] + start):
+                x, aux, st = c
+                x, st, a = one_layer(kind, st, base + j, x)
+                return (x, aux + a, st), None
+
+            carry, _ = jax.lax.scan(run_body, carry, jnp.arange(n))
+        return carry, None
+
+    (x, aux, state), _ = jax.lax.scan(
+        period_body, (x, jnp.zeros((), jnp.float32), state),
+        jnp.arange(n_per))
+    return x, aux, state
 
 
 def logits_from_hidden(weights, x, cfg):
@@ -219,10 +335,16 @@ def init_cache(cfg, batch_size: int, max_len: int, cache_dtype=None,
     never silently disagree between a direct init_cache call and an engine."""
     if cache_dtype is None:
         cache_dtype = jnp.dtype(cfg.cache_dtype)
-    one = blocks.init_layer_cache(cfg, batch_size, max_len, cache_dtype)
-    layers = jax.tree.map(
-        lambda a: jnp.broadcast_to(a[None], (cfg.num_layers,) + a.shape), one)
-    layers = jax.tree.map(jnp.asarray, layers)
+    stacks = ([(k, cfg.layers_of(k)) for k in sorted(set(cfg.layer_types))]
+              if cfg.layer_types else [(None, cfg.num_layers)])
+    layers = {}
+    for kind, n in stacks:
+        one = blocks.init_layer_cache(cfg, batch_size, max_len, cache_dtype,
+                                      kind)
+        layers.update(jax.tree.map(
+            lambda a, n=n: jnp.asarray(jnp.broadcast_to(a[None],
+                                                        (n,) + a.shape)),
+            one))
     shape = (batch_size,) if per_slot_lengths else ()
     c = {"layers": layers, "length": jnp.zeros(shape, jnp.int32)}
     if cfg.hot_buffer > 0:
@@ -252,6 +374,6 @@ def decode_step(weights, hccs, tokens, cache, cfg, embeddings=None):
     Returns (logits (B, vocab), new_cache)."""
     batch = ({"embeddings": embeddings} if embeddings is not None
              else {"tokens": tokens})
-    x, cache, _ = forward(weights, hccs, batch, cfg, cache=cache, decode=True)
+    x, cache, _ = forward(weights, hccs, batch, cfg, cache=cache)
     logits = logits_from_hidden(weights, x, cfg)
     return logits[:, 0], cache

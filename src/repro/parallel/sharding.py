@@ -124,7 +124,7 @@ _PARAM_RULES: list[tuple[str, tuple]] = [
     (r"(w_in|w_gate)$",         ("fsdp", "ffn")),
     (r"w_out$",                 ("ffn", "fsdp")),
     (r"router",                 ("fsdp", None)),
-    (r"ssm/in_proj",            ("fsdp", None)),   # proj dim not TP-divisible for hymba
+    (r"ssm/in_",                ("fsdp", None)),   # proj dim not TP-divisible for hymba
     (r"ssm/out_proj",           ("model", "fsdp")),
     (r"ssm/(A_log|dt_bias|D)",  (None,)),
     (r"(norm|scale|bias|ln)",   (None,)),

@@ -169,6 +169,28 @@ Accepted tokens amortize the per-step dispatch cost (the serving win the
 speculative benchmark section measures); greedy outputs are token-identical
 with speculation on or off, property-tested in tests/test_spec_decode.py.
 
+RECURRENT STATE (configs whose `layer_types` hold Mamba-2 layers): beside the
+paged KV pool, which then covers only the attention layers, each slot owns
+a fixed row of a per-slot STATE ARENA, allocated from the config at
+construction and riding the cache like the pool:
+
+    ssm   (L_mamba, max_batch, heads, head_dim, state)   float32
+    conv  (L_mamba, max_batch, ssm_conv - 1, conv_dim)   compute dtype
+
+A slot's rows are zeroed when a request is admitted into it (the
+`serve.state_reset` span), and every step reads and writes the rows of
+every slot in each Mamba layer: the packed step scatters each slot's tokens
+onto its row of the (B, Wb) grid the attention core already uses, runs the
+conv and the SSD from the slot's carried state, and writes the new state
+back in place (models/ssm.py apply_ssd_packed). A slot with no token in the
+step passes its state through unchanged. A slot's state is a function of
+its whole history, which a block table cannot share or roll back, so prefix
+and decode sharing, speculation, preemption, the async loop, the lockstep
+layout, int8 KV and the fused kernels are refused for such configs (state
+snapshots at block boundaries would lift that). padding_stats() counts the
+slot-states the steps moved, those of slots with a token, and the state
+bytes read and written.
+
 Attention dispatch (models/attention.py) keys off `block_table` in the cache:
 the XLA path gathers each slot's blocks into a contiguous view; with
 cfg.decode_kernel != "none" the t == 1 hot path runs the block-sparse Pallas
@@ -720,6 +742,15 @@ def packed_write_positions(t_valid, off, tables, lengths, block_size: int,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+def _zero_slot_state(layers, slot):
+    """Zero slot `slot`'s recurrent state in every Mamba layer, in place: an
+    admitted request starts from no history, whatever the slot's previous
+    owner left there."""
+    return dict(layers, **{name: layers[name].at[:, slot].set(0)
+                           for name in ("ssm", "conv")})
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_block_kv(layers, src, dst):
     """Copy-on-write: duplicate pool block `src` into `dst` across all layers
     for both k and v — and, on kv_quant="int8" pools, the per-block scales
@@ -762,11 +793,18 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, max_batch: int,
 
     Scales are state, not steering: they ride the carried cache through the
     step (attention's per-row fold grows them monotonically as rows land)
-    and are COW-copied with their block's payload (_copy_block_kv)."""
+    and are COW-copied with their block's payload (_copy_block_kv).
+
+    A config with `layer_types` pools K/V for its attention layers alone,
+    and gets the per-slot state arena of its Mamba layers (module
+    docstring, "Recurrent state"):
+
+        ssm:  (L_mamba, max_batch, heads, head_dim, state)   f32
+        conv: (L_mamba, max_batch, ssm_conv - 1, conv_dim)   cfg.dtype"""
     if cache_dtype is None:
         cache_dtype = jnp.dtype(cfg.cache_dtype)
     hkv = cfg.num_kv_heads
-    L = cfg.num_layers
+    L = cfg.layers_of("attention") if cfg.layer_types else cfg.num_layers
     if xla_reads_kv(cfg):
         shape = (L, num_blocks, block_size, hkv * cfg.head_dim)
     else:
@@ -779,6 +817,13 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, max_batch: int,
     if quant:
         layers["k_scale"] = jnp.zeros((L, num_blocks, hkv), jnp.float32)
         layers["v_scale"] = jnp.zeros((L, num_blocks, hkv), jnp.float32)
+    m = cfg.layers_of("mamba")
+    if m:
+        layers["ssm"] = jnp.zeros((m, max_batch, cfg.ssm_heads,
+                                   cfg.ssm_head_dim, cfg.ssm_state),
+                                  jnp.float32)
+        layers["conv"] = jnp.zeros((m, max_batch, cfg.ssm_conv - 1,
+                                    cfg.conv_dim), jnp.dtype(cfg.dtype))
     return {"layers": layers,
             "length": jnp.zeros((max_batch,), jnp.int32)}
 
@@ -800,10 +845,11 @@ class PagedEngine:
             raise ValueError(
                 "paged batching uses the block pool, not hot buffers "
                 f"(cfg.hot_buffer={cfg.hot_buffer})")
-        if cfg.family in ("ssm", "hybrid"):
+        if cfg.family in ("ssm", "hybrid") and not cfg.layer_types:
             raise ValueError(
-                f"paged KV needs attention-only blocks; {cfg.family} carries "
-                "per-slot SSM state that a block pool cannot page")
+                f"paged serving of a {cfg.family} model needs `layer_types`: "
+                "its SSM state lives in the per-slot state arena, whose "
+                "layers the pattern names")
         warn_decode_kernel_fallback(cfg)
         if cache_dtype is None:
             cache_dtype = jnp.dtype(cfg.cache_dtype)
@@ -996,6 +1042,11 @@ class PagedEngine:
         # FLOPs without reading its inputs
         self.ctx_positions = 0
         self.sampled_lanes = 0
+        # state-arena counters (module docstring, "Recurrent state"): each
+        # step reads and writes every slot's state in each Mamba layer
+        self.state_slots_moved = 0
+        self.state_slots_live = 0
+        self.state_bytes = 0
 
         # prefix sharing: exact-content trie over full-block chunks -> pool
         # block id (PrefixTrie above). The trie holds its own reference on
@@ -1021,6 +1072,25 @@ class PagedEngine:
         self.decode_tokens_skipped = 0
         self.cow_copies = 0
         self.prefix_evictions = 0
+        if cfg.carries_state:
+            refused = [name for name, on in (
+                ("prefix_sharing", self.prefix_sharing),
+                ("decode_sharing", self.decode_sharing),
+                ("speculative", self.speculative),
+                ("preemption", self._robust and self._adm.preemption),
+                ("async_loop", self.async_loop),
+                ("packed=False", not self.packed),
+                ('kv_quant="int8"', self.quantized),
+                (f"decode_kernel={cfg.decode_kernel!r}",
+                 cfg.decode_kernel != "none")) if on]
+            if refused:
+                raise ValueError(
+                    f"{cfg.name} carries recurrent state per slot, which "
+                    f"{', '.join(refused)} cannot keep: sharing, rollback "
+                    "and preemption need state snapshots at block "
+                    "boundaries, and the async loop, the lockstep layout, "
+                    "int8 KV and the fused kernels are not built for the "
+                    "state arena")
 
         # multi-turn sessions: submit(req, session=sid) prepends the stored
         # history (prompt + generated tokens of every prior turn) to the
@@ -1075,6 +1145,9 @@ class PagedEngine:
         self._submitted_ts = np.zeros(max_batch, float)
         self._cache = init_paged_cache(cfg, self.num_blocks, bs, max_batch,
                                        cache_dtype)
+        self._state_step_bytes = 2 * sum(
+            a.size * a.dtype.itemsize for name, a in
+            self._cache["layers"].items() if name in ("ssm", "conv"))
 
         cfg_ = cfg
 
@@ -1085,7 +1158,7 @@ class PagedEngine:
         @functools.partial(jax.jit, donate_argnums=(3,))
         def _step(w, hccs, tokens, cache, extras, t_valid):
             x, cache, _ = M.forward(w, hccs, {"tokens": tokens}, cfg_,
-                                    cache=dict(cache, **extras), decode=True)
+                                    cache=dict(cache, **extras))
             # each slot samples from its LAST VALID position (t_valid - 1):
             # chunk steps are ragged — riding decode slots have t_valid == 1,
             # mid-prompt slots discard their logits entirely
@@ -1108,7 +1181,7 @@ class PagedEngine:
         def _packed(w, hccs, tokens, positions, cache, extras, lane_idx):
             x, cache, _ = M.forward(
                 w, hccs, {"tokens": tokens, "positions": positions}, cfg_,
-                cache=dict(cache, **extras), decode=True)
+                cache=dict(cache, **extras))
             h_last = x[0, lane_idx][:, None]             # (B, 1, D)
             logits = M.logits_from_hidden(w, h_last, cfg_)
             return logits[:, 0], cache
@@ -1127,7 +1200,7 @@ class PagedEngine:
                          lane_grid):
             x, cache, _ = M.forward(
                 w, hccs, {"tokens": tokens, "positions": positions}, cfg_,
-                cache=dict(cache, **extras), decode=True)
+                cache=dict(cache, **extras))
             h = x[0][lane_grid]                          # (B, 1+K, D)
             logits = M.logits_from_hidden(w, h, cfg_)
             return logits, cache
@@ -1151,7 +1224,7 @@ class PagedEngine:
             fed = jnp.where(tok_src >= 0, prev_sampled[src], tokens[0])
             x, cache, _ = M.forward(
                 w, hccs, {"tokens": fed[None], "positions": positions},
-                cfg_, cache=dict(cache, **extras), decode=True)
+                cfg_, cache=dict(cache, **extras))
             h_last = x[0, lane_idx][:, None]             # (B, 1, D)
             logits = M.logits_from_hidden(w, h_last, cfg_)
             logits = logits[:, 0]
@@ -1412,6 +1485,10 @@ class PagedEngine:
             # them, threading the indexed chain tail as the parent
             self._reg_level[slot] = len(matched)
             self._reg_parent[slot] = matched[-1][1] if matched else -1
+            if self._state_step_bytes:
+                with self.telemetry.profiler.phase("state_reset"):
+                    self._cache = dict(self._cache, layers=_zero_slot_state(
+                        self._cache["layers"], np.int32(slot)))
 
     # ------------------------------------------------------------ prefix --
 
@@ -1580,13 +1657,20 @@ class PagedEngine:
     def padding_stats(self) -> dict:
         """Token-lane telemetry: efficiency = valid lanes / padded lanes over
         every step so far (the packing win the benchmark records), plus the
-        estimated lanes the lockstep layout would have burned extra."""
+        estimated lanes the lockstep layout would have burned extra, and the
+        state arena's traffic: slot-states read and written (every slot of
+        each state-carrying layer kind, per step), those of slots with a
+        token in the step, and the bytes of SSM and conv state read plus
+        written (all zero without recurrent state)."""
         return dict(lanes_valid=self.lanes_valid,
                     lanes_total=self.lanes_total,
                     efficiency=self.lanes_valid / max(self.lanes_total, 1),
                     pad_lanes_skipped=self.pad_lanes_skipped,
                     ctx_positions=self.ctx_positions,
-                    sampled_lanes=self.sampled_lanes)
+                    sampled_lanes=self.sampled_lanes,
+                    state_slots_moved=self.state_slots_moved,
+                    state_slots_live=self.state_slots_live,
+                    state_bytes=self.state_bytes)
 
     # ------------------------------------------------------------- slots --
 
@@ -1996,6 +2080,10 @@ class PagedEngine:
                                        + np.arange(tv))
             self.lanes_valid += int(t_valid.sum())
             self.lanes_total += width
+            if self._state_step_bytes:
+                self.state_slots_moved += self.max_batch
+                self.state_slots_live += int((t_valid > 0).sum())
+                self.state_bytes += self._state_step_bytes
             # lanes the lockstep layout would burn for the SAME scheduled
             # work: it caps each slot at block_size tokens per chunk step, so
             # this step's largest per-slot chunk takes ceil(max tv / bs)

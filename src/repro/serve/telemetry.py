@@ -89,8 +89,10 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 # from serve/admission.py's RobustnessCounters; None for engines without the
 # opt-in layer) and RequestTrace.dropped / MetricsRegistry.on_drop for
 # requests ending in failure (shed, cancelled, deadline-expired); v3 added
-# the `padding` section's ctx_positions / sampled_lanes work counters
-SNAPSHOT_SCHEMA_VERSION = 3
+# the `padding` section's ctx_positions / sampled_lanes work counters; v4
+# its state_slots_moved / state_slots_live / state_bytes state-arena
+# counters (zero without recurrent state)
+SNAPSHOT_SCHEMA_VERSION = 4
 
 # admission-wait histogram bucket edges (milliseconds, log-spaced); the last
 # bucket is open-ended

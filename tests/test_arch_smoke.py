@@ -55,7 +55,8 @@ def test_forward_and_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["granite-3-2b", "hymba-1.5b", "mamba2-1.3b",
-                                  "qwen3-moe-235b-a22b"])
+                                  "qwen3-moe-235b-a22b",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_forward(arch):
     """Prefill + single decode step == teacher-forced full forward."""
     cfg = reduced_config(arch)

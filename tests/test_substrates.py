@@ -147,24 +147,27 @@ class TestMoE:
 
 class TestSSD:
     def test_chunked_matches_recurrent(self):
-        """The chunked SSD (training path) == step-by-step recurrence."""
-        from repro.models.ssm import apply_ssd, apply_ssd_step, init_ssm
+        """The chunked SSD (training path) == step-by-step recurrence, the
+        conv state carried from step to step."""
+        from repro.models.ssm import apply_ssd, init_ssm, init_state
         cfg = tiny_cfg(family="ssm", num_heads=0, num_kv_heads=0, d_ff=0,
                        ssm_state=8, ssm_head_dim=16, ssm_chunk=4)
         p = init_ssm(jax.random.PRNGKey(0), cfg)
         x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (2, 12, 64)),
                         jnp.float32)
-        y_chunked, state_final = apply_ssd(p, x, cfg)
-        state = jnp.zeros((2, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim))
+        y_chunked, state_final, conv_final = apply_ssd(p, x, cfg)
+        state, conv = init_state(cfg, 2)
         ys = []
         for t in range(12):
-            y_t, state = apply_ssd_step(p, x[:, t:t + 1], cfg, state)
+            y_t, state, conv = apply_ssd(p, x[:, t:t + 1], cfg, state, conv)
             ys.append(y_t)
         y_rec = jnp.concatenate(ys, axis=1)
         np.testing.assert_allclose(np.asarray(y_chunked), np.asarray(y_rec),
                                    atol=2e-4)
         np.testing.assert_allclose(np.asarray(state_final), np.asarray(state),
                                    atol=2e-4)
+        np.testing.assert_array_equal(np.asarray(conv_final),
+                                      np.asarray(conv))
 
     @pytest.mark.slow
     def test_chunk_size_invariance(self):
@@ -175,8 +178,8 @@ class TestSSD:
         p = init_ssm(jax.random.PRNGKey(0), cfg4)
         x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (1, 24, 64)),
                         jnp.float32)
-        y4, s4 = apply_ssd(p, x, cfg4)
-        y6, s6 = apply_ssd(p, x, cfg6)
+        y4, s4, _ = apply_ssd(p, x, cfg4)
+        y6, s6, _ = apply_ssd(p, x, cfg6)
         np.testing.assert_allclose(np.asarray(y4), np.asarray(y6), atol=2e-4)
         np.testing.assert_allclose(np.asarray(s4), np.asarray(s6), atol=2e-4)
 

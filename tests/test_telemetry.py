@@ -33,7 +33,8 @@ DIST_KEYS = {"count", "mean", "p50", "p95", "p99"}
 PHASES_KEYS = {"steps", "step_seconds", "coverage", "phases"}
 # v3 added the ctx_positions / sampled_lanes work counters
 PADDING_KEYS = {"lanes_valid", "lanes_total", "efficiency",
-                "pad_lanes_skipped", "ctx_positions", "sampled_lanes"}
+                "pad_lanes_skipped", "ctx_positions", "sampled_lanes",
+                "state_slots_moved", "state_slots_live", "state_bytes"}
 REPO = Path(__file__).resolve().parents[1]
 
 
@@ -372,7 +373,7 @@ def test_snapshot_schema_stability(served, rng, enabled):
     for name, eng in engines.items():
         snap = eng.snapshot()
         assert set(snap) == SNAPSHOT_KEYS
-        assert snap["schema_version"] == 3
+        assert snap["schema_version"] == 4
         assert snap["engine"] == name
         assert snap["robustness"] is None    # none of these are robust
         assert set(snap["kv_cache"]) == {"cache_bytes_logical",

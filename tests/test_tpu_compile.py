@@ -244,6 +244,64 @@ def test_xla_packed_step_moves_no_pool(one_chip):
     assert temp < 1e9, temp
 
 
+def _whole_moves(hlo_text, dims, ops):
+    """Names of the ops among `ops` whose result has exactly `dims`."""
+    want = ",".join(str(d) for d in dims)
+    return [m.group(1) for m in map(_MOVES.match, hlo_text.splitlines())
+            if m and m.group(3) in ops
+            and re.match(rf"\w+\[{want}\]", m.group(2))]
+
+
+@pytest.mark.parametrize("width,wb", [(32, 1), (1024, 128)],
+                         ids=["decode", "chunk"])
+def test_hybrid_packed_step_moves_no_state_arena(one_chip, width, wb):
+    """granite-4.0-h-micro's packed step at the rag-4k-32 cell's shapes: 32
+    slots, a bf16 pool of 4096 blocks of 32 for the 4 attention layers and
+    the state arena of the 36 Mamba-2 layers (2.4 GB of f32 SSM state), for
+    a step where every slot decodes one token and for the widest chunk
+    step. The arena rides the layer scan's carry and each layer's rows are
+    written back in place, so no op copies or slices the whole arena, no op
+    moves a layer's pool, and the step's temp stays far below the arena."""
+    from repro.models import model as M
+    from repro.serve import PagedEngine
+    from repro.serve.paged import init_paged_cache
+
+    cfg = get_config("granite-4.0-h-micro").replace(
+        cache_layout="paged", cache_dtype="bfloat16")
+    b, bs, max_len, n = 32, 32, 4096, 4096
+    params = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
+    # one slot's arena is real; the step is compiled at b slots from shapes
+    eng = PagedEngine(params, cfg, max_batch=1, max_len=max_len,
+                      block_size=bs, num_blocks=max_len // bs + 2)
+    s = _spec(one_chip)
+    place = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    cache = place(jax.eval_shape(lambda: init_paged_cache(cfg, n, bs, b)))
+    arena = cache["layers"]["ssm"].shape
+    assert arena == (36, b, 64, 64, 128)
+    assert cache["layers"]["k"].shape == (4, n, bs, HKV * HD)
+    extras = {"length": s((b,), jnp.int32),
+              "block_table": s((b, max_len // bs), jnp.int32),
+              "write_pos": s((1, width), jnp.int32),
+              "kv_len": s((width,), jnp.int32),
+              "slot_ids": s((width,), jnp.int32),
+              "q_pos_grid": s((b, wb), jnp.int32),
+              "grid_pos": s((width,), jnp.int32),
+              "kv_len_slot": s((b,), jnp.int32)}
+    compiled = eng._packed_fn.lower(
+        place(params["weights"]), place(params["hccs"]),
+        s((1, width), jnp.int32), s((1, width), jnp.int32), cache, extras,
+        s((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _whole_moves(text, arena, ("copy", "copy-start",
+                                      "dynamic-slice")) == []
+    moves = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+    for pool in ((4, n, bs, HKV * HD), (1, n, bs, HKV * HD),
+                 (n, bs, HKV * HD)):
+        assert _whole_moves(text, pool, moves) == [], pool
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1e9, temp
+
+
 @pytest.mark.parametrize("backend,interp", [("cpu", True), ("tpu", False)])
 def test_interp_follows_backend(monkeypatch, backend, interp):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
