@@ -144,9 +144,11 @@ def _slot_scatter(cache_kv, new_kv, lengths):
 
 # transient per-step keys the paged engine attaches to the cache; they steer
 # the step and are not part of the carried cache state. `slot_ids`,
+# `attn_cell`, `dec_kv_len`, `chunk_slots`, `chunk_q_pos`, `chunk_kv_len`,
 # `q_pos_grid`, `grid_pos` and `kv_len_slot` only ride on packed token
-# steps: `slot_ids` selects the token-centric branch, the other three steer
-# the XLA path's per-slot attention grid (see _packed_attention).
+# steps: `slot_ids` selects the token-centric branch, the next five steer
+# the XLA path's two attention grids (see _packed_attention), and the last
+# three the SSD's (B, Wb) slot grid (models/ssm.py apply_ssd_packed).
 # `fresh_blocks` only rides on kv_quant="int8" steps: block ids allocated
 # since the last step, whose stale per-block scales must be reset to zero
 # before this step's quantized writes (padded with the trash block 0).
@@ -161,8 +163,9 @@ def _slot_scatter(cache_kv, new_kv, lengths):
 # then the whole (L, ...) stack, carried through the layer scan, and each
 # layer writes and reads its own slice by this index (model.forward).
 _PAGED_TRANSIENT = ("block_table", "write_pos", "kv_len", "slot_ids",
-                    "q_pos_grid", "grid_pos", "kv_len_slot", "fresh_blocks",
-                    "stage_rows", "draft_rows", "layer")
+                    "attn_cell", "dec_kv_len", "chunk_slots", "chunk_q_pos",
+                    "chunk_kv_len", "q_pos_grid", "grid_pos", "kv_len_slot",
+                    "fresh_blocks", "stage_rows", "draft_rows", "layer")
 
 
 def _paged_scatter(pool, new_kv, write_pos, layer=None):
@@ -521,17 +524,22 @@ def _packed_attention(q, k_pool, v_pool, cache, cfg, hccs, hd,
     BlockSpec index_map walks `block_table[slot_ids[token]]` (a gather-free
     DMA steer).
 
-    The XLA path instead rides the packed tokens through a compact PER-SLOT
-    GRID for the attention core only: `grid_pos` (T,) scatters each token to
-    cell (slot, position - frontier) of a (B, Wb) grid (Wb = this step's
-    bucketed max per-slot chunk, carried by `q_pos_grid`'s static shape; pad
-    lanes land in a spill row), the grid runs the SAME dense/blockwise
-    attention as the lockstep layout at width Wb — one per-slot KV gather,
-    NOT one per token, which is what makes the packed step cheaper rather
-    than gather-bound — and the outputs gather back to packed lanes. Every
-    other layer (projections, MLP, norms, logits) stays token-packed: that
-    is where the padding FLOPs go, while the attention core's work is
-    identical to lockstep's for the same tokens (bit-parity for free).
+    The XLA path instead rides the packed tokens through two compact
+    PER-SLOT GRIDS for the attention core only. `attn_cell` (T,) scatters
+    each lane to one cell: slots with one token this step (decode riders,
+    one-lane prefills) to their row of a (B, 1) DECODE grid, whose row b
+    ends at `dec_kv_len[b]` keys (0 where slot b has no single token);
+    slots with more than one token to a row of a (P, Wb) CHUNK grid, row r
+    being slot `chunk_slots[r]` at positions `chunk_q_pos[r]` against
+    `chunk_kv_len[r]` keys (Wb buckets this step's longest chunk, P rows
+    cover those slots; see serve/paged.py grid_steering); pad lanes to
+    the spill cell B + P*Wb. A step with no multi-token slot carries no
+    chunk grid. Each grid runs the SAME dense/blockwise attention as the
+    lockstep layout over its slots' gathered blocks — one per-slot KV
+    gather, NOT one per token — and the outputs gather back to packed
+    lanes. Every other layer (projections, MLP, norms, logits) stays
+    token-packed, while each row of the core does what lockstep's row for
+    the same token does (bit-parity for free).
     """
     b, h, t, _ = q.shape
     sid = cache["slot_ids"]
@@ -547,32 +555,49 @@ def _packed_attention(q, k_pool, v_pool, cache, cfg, hccs, hd,
                 static_max=(cfg.decode_kernel == "static_max"),
                 k_scales=k_scales, v_scales=v_scales)
         return o.astype(q.dtype).reshape(1, t, h * hd)
-    q_pos_grid = cache["q_pos_grid"]                      # (B, Wb)
-    gp = cache["grid_pos"]                                # (T,) spill = B*Wb
-    k_len = cache["kv_len_slot"]                          # (B,)
-    bs_, wb = q_pos_grid.shape
+    cell = cache["attn_cell"]                             # (T,) spill last
+    dec_len = cache["dec_kv_len"]                         # (B,)
+    nb = dec_len.shape[0]
+    chunk = "chunk_slots" in cache
+    p_, wb = cache["chunk_q_pos"].shape if chunk else (0, 1)
+    n_cells = nb + p_ * wb
     with jax.named_scope("attn.gather"):
-        qg = jnp.zeros((bs_ * wb + 1, h, qt.shape[-1]),
-                       qt.dtype).at[gp].set(qt)
-        qg = qg[:bs_ * wb].reshape(bs_, wb, h, -1).transpose(0, 2, 1, 3)
+        qc = jnp.zeros((n_cells + 1, h, qt.shape[-1]),
+                       qt.dtype).at[cell].set(qt)
         li = cache.get("layer")
         kg = _paged_gather(k_pool, cache["block_table"], hd, k_scales,
                            li)                            # (B, Hkv, L, hd)
         vg = _paged_gather(v_pool, cache["block_table"], hd, v_scales, li)
-    tk = kg.shape[2]
-    use_blockwise = (cfg.attention_impl == "blockwise" or
-                     (cfg.attention_impl == "auto" and wb > 1 and
-                      tk >= cfg.blockwise_threshold))
     with jax.named_scope("attn.core"):
-        if use_blockwise:
-            out = _blockwise_attention(qg, kg, vg, q_pos_grid, k_len, cfg,
-                                       hccs)
-        else:
-            valid = _block_valid(cfg, q_pos_grid, jnp.arange(tk), k_len)
-            out = _dense_attention(qg, kg, vg, valid, cfg, hccs)
+        out = _grid_attention(qc[:nb, :, None], kg, vg,
+                              (dec_len - 1)[:, None], dec_len, cfg, hccs)
+    outs = [out.reshape(nb, h * hd)]
+    if chunk:
+        cs = cache["chunk_slots"]
+        with jax.named_scope("attn.gather"):
+            qg = qc[nb:n_cells].reshape(p_, wb, h, -1).transpose(0, 2, 1, 3)
+            kc, vc = kg[cs], vg[cs]                       # (P, Hkv, L, hd)
+        with jax.named_scope("attn.core"):
+            out = _grid_attention(qg, kc, vc, cache["chunk_q_pos"],
+                                  cache["chunk_kv_len"], cfg, hccs)
+        outs.append(out.transpose(0, 2, 1, 3).reshape(p_ * wb, h * hd))
     with jax.named_scope("attn.gather"):
-        out = out.transpose(0, 2, 1, 3).reshape(bs_ * wb, h * hd)
-        return out[jnp.where(sid >= 0, gp, 0)][None]      # (1, T, H*hd)
+        out = jnp.concatenate(outs) if chunk else outs[0]
+        return out[jnp.where(sid >= 0, cell, 0)][None]    # (1, T, H*hd)
+
+
+def _grid_attention(q, k, v, q_pos, k_len, cfg, hccs):
+    """Attention of a per-slot grid q (S, H, W, hd) at positions q_pos
+    (S, W) over each row's gathered keys k/v (S, Hkv, L, hd), the first
+    k_len (S,) of them valid: blockwise for multi-token rows over long
+    tables, dense otherwise (the lockstep layout's rule)."""
+    tk = k.shape[2]
+    if (cfg.attention_impl == "blockwise" or
+            (cfg.attention_impl == "auto" and q.shape[2] > 1 and
+             tk >= cfg.blockwise_threshold)):
+        return _blockwise_attention(q, k, v, q_pos, k_len, cfg, hccs)
+    valid = _block_valid(cfg, q_pos, jnp.arange(tk), k_len)
+    return _dense_attention(q, k, v, valid, cfg, hccs)
 
 
 def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,

@@ -119,7 +119,9 @@ def forward(weights, hccs, batch, cfg, cache=None):
     if cache is not None and "block_table" in cache:
         paged_extras = {kk: cache[kk]
                         for kk in ("block_table", "write_pos", "kv_len",
-                                   "slot_ids", "q_pos_grid", "grid_pos",
+                                   "slot_ids", "attn_cell", "dec_kv_len",
+                                   "chunk_slots", "chunk_q_pos",
+                                   "chunk_kv_len", "q_pos_grid", "grid_pos",
                                    "kv_len_slot", "fresh_blocks",
                                    "stage_rows", "draft_rows")
                         if kk in cache}

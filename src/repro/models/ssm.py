@@ -231,8 +231,8 @@ def apply_ssd(p, x, cfg, state=None, conv_state=None):
 
 def apply_ssd_packed(p, x, cfg, state, conv_state, cache):
     """The packed paged step: x (1, T, D) holds ragged tokens of many slots.
-    `cache` carries the attention grid's steering (models/attention.py
-    _packed_attention): `grid_pos` (T,) puts each token at cell (slot, i) of
+    `cache` carries the slot grid's steering (serve/paged.py
+    grid_steering): `grid_pos` (T,) puts each token at cell (slot, i) of
     the (B, Wb) slot grid (pad lanes at the spill cell B*Wb), `q_pos_grid`
     gives Wb, and `kv_len_slot - length` is each slot's token count. Row b
     runs from slot b's carried states; a slot with no token keeps them.

@@ -88,9 +88,10 @@ size is BUDGET-driven, no longer hard-wired to block_size), and per-token
 attends within its own slot's blocks: the fused packed kernel
 (kernels/decode.py hccs_packed_prefill) walks `block_table[slot_ids[t]]` in
 its scalar-prefetched index_map (a gather-free DMA steer), while the XLA
-path scatters the tokens into a compact (B, Wb) per-slot grid for the
-attention core only — one per-slot KV gather, not one per token — and keeps
-every other layer token-packed (see models/attention.py
+path scatters the tokens into two compact per-slot grids for the attention
+core only — a (B, 1) grid of the slots with one token and a (P, Wb) grid of
+those with more (grid_steering) — with one per-slot KV gather, not one per
+token, and keeps every other layer token-packed (see models/attention.py
 _packed_attention). Each step runs at the
 smallest rung of a 4-entry chunk-width ladder (max_batch ... token_budget,
 default budget max_batch * block_size) that covers its pending work, so
@@ -180,7 +181,7 @@ construction and riding the cache like the pool:
 A slot's rows are zeroed when a request is admitted into it (the
 `serve.state_reset` span), and every step reads and writes the rows of
 every slot in each Mamba layer: the packed step scatters each slot's tokens
-onto its row of the (B, Wb) grid the attention core already uses, runs the
+onto its row of a (B, Wb) slot grid (grid_steering), runs the
 conv and the SSD from the slot's carried state, and writes the new state
 back in place (models/ssm.py apply_ssd_packed). A slot with no token in the
 step passes its state through unchanged. A slot's state is a function of
@@ -741,6 +742,92 @@ def packed_write_positions(t_valid, off, tables, lengths, block_size: int,
     return wp.astype(np.int32)
 
 
+def chunk_grid_rows(width: int, wb: int, n_multi: int, max_batch: int) -> int:
+    """Rows P of a packed step's chunk grid (grid_steering): the most
+    Wb-token chunks `width` lanes hold (the floor rung), doubled until it
+    covers the step's n_multi multi-token slots, at most max_batch. The
+    floor is the rung a step of fresh prompts filling its width lands on;
+    prefill chunks never exceed it (fit_chunk_grid), so the rungs above it
+    carry speculative verify rows."""
+    p = max(1, width // wb)
+    while p < n_multi:
+        p *= 2
+    return min(p, max_batch)
+
+
+def fit_chunk_grid(t_valid, prefill, width: int, widths, grid_widths):
+    """Per-slot token counts whose prefill chunks fit the floor rung of the
+    chunk grid, and the step width they need. Where a step's multi-token
+    prefilling slots outnumber max(1, width // Wb) (short prompt tails
+    beside long chunks), those past that count in slot order (the order
+    schedule_step_tokens deals lanes in) keep one lane, and the step takes
+    the smallest rung of `widths` that still holds its lanes, fitted
+    again at that width. A step without speculative verify rows then runs
+    the attention program of its (width, Wb) pair, the one the step that
+    first met the pair compiled. Verify rows are left whole; they take the
+    rungs above the floor (chunk_grid_rows). Returns (t_valid, width)."""
+    t_valid = np.array(t_valid)
+    while True:
+        wb = next(w for w in grid_widths if w >= max(int(t_valid.max()), 1))
+        chunks = np.flatnonzero(prefill & (t_valid > 1))
+        t_valid[chunks[max(1, width // wb):]] = 1
+        fitted = next(w for w in widths if w >= int(t_valid.sum()))
+        if fitted == width:
+            return t_valid, width
+        width = fitted
+
+
+def grid_steering(t_valid, off, sid, lengths, grid_widths, ssd=False):
+    """Host steering of the XLA packed step's attention grids
+    (models/attention.py _packed_attention), as int32 arrays by cache key.
+
+    Slots with one token this step take their row of the (B, 1) decode
+    grid: `attn_cell` sends the lane to cell b, `dec_kv_len[b]` = length
+    + 1 (0 for every other slot, masking its row). Slots with more lanes
+    take rows 0.. of a (P, Wb) chunk grid in slot order: Wb is the
+    smallest of `grid_widths` covering the longest chunk, P is
+    chunk_grid_rows, `chunk_slots` (pad rows: slot 0), `chunk_q_pos` and
+    `chunk_kv_len` (pad rows: 0 keys) give each row's slot, positions and
+    keys, and lane off[b] + i goes to cell B + r*Wb + i. Pad lanes go to
+    the spill cell B + P*Wb. A step without a multi-token slot has no
+    chunk grid (Wb = 1). With `ssd`, also the SSD's (B, Wb) slot grid
+    (models/ssm.py apply_ssd_packed): `grid_pos`, `q_pos_grid` and
+    `kv_len_slot`."""
+    t_valid = np.asarray(t_valid)
+    lengths = np.asarray(lengths)
+    b, width = t_valid.shape[0], sid.shape[0]
+    wb = next(w for w in grid_widths if w >= max(int(t_valid.max()), 1))
+    multi = np.flatnonzero(t_valid > 1)
+    p = chunk_grid_rows(width, wb, len(multi), b) if wb > 1 else 0
+    cell = np.full(width, b + p * wb, np.int32)
+    one = np.flatnonzero(t_valid == 1)
+    cell[off[one]] = one
+    out = {"attn_cell": cell,
+           "dec_kv_len": np.where(t_valid == 1, lengths + 1,
+                                  0).astype(np.int32)}
+    if p:
+        slots = np.zeros(p, np.int32)
+        slots[:len(multi)] = multi
+        kv = np.zeros(p, np.int32)
+        kv[:len(multi)] = lengths[multi] + t_valid[multi]
+        for r, slot in enumerate(multi):
+            o, tv = int(off[slot]), int(t_valid[slot])
+            cell[o:o + tv] = b + r * wb + np.arange(tv)
+        out.update(chunk_slots=slots, chunk_kv_len=kv,
+                   chunk_q_pos=(lengths[slots][:, None]
+                                + np.arange(wb)[None, :]).astype(np.int32))
+    if ssd:
+        grid_pos = np.full(width, b * wb, np.int32)
+        valid = sid >= 0
+        grid_pos[valid] = (sid[valid] * wb
+                           + (np.flatnonzero(valid) - off[sid[valid]]))
+        out.update(grid_pos=grid_pos,
+                   q_pos_grid=(lengths[:, None]
+                               + np.arange(wb)[None, :]).astype(np.int32),
+                   kv_len_slot=(lengths + t_valid).astype(np.int32))
+    return out
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _zero_slot_state(layers, slot):
     """Zero slot `slot`'s recurrent state in every Mamba layer, in place: an
@@ -1005,8 +1092,10 @@ class PagedEngine:
                                               self._chunk_cap)})
         # with the fused packed kernel active, attention never reads the
         # grid-steering arrays — omit them so the step traces once per chunk
-        # width, not once per (chunk width, grid width) pair
+        # width, not once per (chunk width, grid width, chunk rows) triple;
+        # the SSD's (B, Wb) slot grid rides only where a layer is Mamba-2
         self._use_grid = xla_reads_kv(cfg) or not params["hccs"]
+        self._ssd_grid = "mamba" in (cfg.layer_types or ())
         # pipelined async loop (module docstring, "Pipelined async loop"):
         # dispatch step N+1 while step N's tokens are still in flight, with
         # host commit running one step behind. Opt-in; packed-only (the
@@ -1036,6 +1125,10 @@ class PagedEngine:
         self.lanes_valid = 0
         self.lanes_total = 0
         self.pad_lanes_skipped = 0
+        # query rows of the XLA packed step's attention grids (B decode
+        # rows plus P*Wb chunk rows a step): lanes_valid / grid_rows is how
+        # much of the attention core's work serves a token
+        self.grid_rows = 0
         # work counters: ctx_positions sums each valid lane's causal context
         # (the keys it attends over), sampled_lanes the lanes whose logits
         # a step samples from — with lanes_valid they give a step's model
@@ -1171,12 +1264,13 @@ class PagedEngine:
 
         # packed token step: tokens ride the sequence axis of a batch-of-one
         # forward, steered by slot_ids / per-token positions / per-token
-        # kv_len. One traced shape per (chunk width, grid width) pair the
-        # traffic actually hits — both ladders are O(1)-sized, so the trace
-        # count is bounded (~a dozen worst case), but callers timing steps
-        # must warm every shape their workload reaches (see the double
-        # warm-up note in benchmarks/serving_throughput.py). lane_idx picks
-        # each slot's LAST packed lane for sampling.
+        # kv_len. One traced shape per (chunk width, grid width, chunk rows)
+        # triple the traffic actually hits — the ladders are O(1)-sized and
+        # the chunk rows' rungs O(log max_batch), so the trace count is
+        # bounded, but callers timing steps must warm every shape their
+        # workload reaches (see the double warm-up note in
+        # benchmarks/serving_throughput.py). lane_idx picks each slot's
+        # LAST packed lane for sampling.
         @functools.partial(jax.jit, donate_argnums=(4,))
         def _packed(w, hccs, tokens, positions, cache, extras, lane_idx):
             x, cache, _ = M.forward(
@@ -1661,11 +1755,14 @@ class PagedEngine:
         state arena's traffic: slot-states read and written (every slot of
         each state-carrying layer kind, per step), those of slots with a
         token in the step, and the bytes of SSM and conv state read plus
-        written (all zero without recurrent state)."""
+        written (all zero without recurrent state), and the query rows of
+        the XLA packed step's attention grids (zero on the fused kernels'
+        path and the lockstep layout)."""
         return dict(lanes_valid=self.lanes_valid,
                     lanes_total=self.lanes_total,
                     efficiency=self.lanes_valid / max(self.lanes_total, 1),
                     pad_lanes_skipped=self.pad_lanes_skipped,
+                    grid_rows=self.grid_rows,
                     ctx_positions=self.ctx_positions,
                     sampled_lanes=self.sampled_lanes,
                     state_slots_moved=self.state_slots_moved,
@@ -2054,6 +2151,10 @@ class PagedEngine:
             t_valid = schedule_step_tokens(
                 live, remaining, width, self._chunk_cap,
                 drafts=n_drafts if drafts else None)
+            if self._use_grid:
+                t_valid, width = fit_chunk_grid(
+                    t_valid, remaining > 0, width, self._widths,
+                    self._grid_widths)
             if drafts:
                 # the scheduler may truncate drafts to fit the budget
                 for slot in list(drafts):
@@ -2171,24 +2272,7 @@ class PagedEngine:
                                + int(t_valid[slot])] = True
                 extras["draft_rows"] = jnp.asarray(draft_rows[None])
             if self._use_grid:
-                # XLA attention-grid steering: cell (slot, i) of the (B, Wb)
-                # grid is the slot's i-th token this step; grid_pos maps
-                # packed lanes to flat cells (pad lanes -> the spill row
-                # B*Wb)
-                max_tv = max(int(t_valid.max()), 1)
-                wb = next(w for w in self._grid_widths if w >= max_tv)
-                q_pos_grid = (self._lengths[:, None]
-                              + np.arange(wb, dtype=np.int32)[None, :])
-                grid_pos = np.full(width, self.max_batch * wb, np.int32)
-                valid_lane = sid >= 0
-                grid_pos[valid_lane] = (sid[valid_lane] * wb
-                                        + (np.flatnonzero(valid_lane)
-                                           - off[sid[valid_lane]]))
-                extras.update(
-                    q_pos_grid=jnp.asarray(q_pos_grid.astype(np.int32)),
-                    grid_pos=jnp.asarray(grid_pos),
-                    kv_len_slot=jnp.asarray((self._lengths
-                                             + t_valid).astype(np.int32)))
+                extras.update(self._grid_extras(t_valid, off, sid))
         with prof.phase("device"):
             if snap_blocks is not None:
                 snap = _gather_block_state(self._cache["layers"],
@@ -2227,6 +2311,15 @@ class PagedEngine:
                                            snap_blocks, snap, staged,
                                            fresh_np)
         return self._sample_and_finish(live, t_valid, logits)
+
+    def _grid_extras(self, t_valid, off, sid) -> dict:
+        """This packed step's grid_steering arrays on the device, counting
+        the attention grids' query rows (padding_stats' grid_rows)."""
+        g = grid_steering(t_valid, off, sid, self._lengths,
+                          self._grid_widths, self._ssd_grid)
+        self.grid_rows += self.max_batch + (g["chunk_q_pos"].size
+                                            if "chunk_q_pos" in g else 0)
+        return {k: jnp.asarray(v) for k, v in g.items()}
 
     # ------------------------------------------- pipelined async loop --
 
@@ -2312,6 +2405,10 @@ class PagedEngine:
             width = next(w for w in self._widths if w >= needed)
             t_valid = schedule_step_tokens(live, remaining, width,
                                            self._chunk_cap)
+            if self._use_grid:
+                t_valid, width = fit_chunk_grid(
+                    t_valid, remaining > 0, width, self._widths,
+                    self._grid_widths)
             sid, off = pack_slot_ids(t_valid, width)
             toks = np.zeros(width, np.int32)
             # decode-lane token indirection: tok_src[lane] = slot id whose
@@ -2366,20 +2463,7 @@ class PagedEngine:
             if self.quantized:
                 extras["fresh_blocks"] = jnp.asarray(self._take_fresh())
             if self._use_grid:
-                max_tv = max(int(t_valid.max()), 1)
-                wb = next(w for w in self._grid_widths if w >= max_tv)
-                q_pos_grid = (self._lengths[:, None]
-                              + np.arange(wb, dtype=np.int32)[None, :])
-                grid_pos = np.full(width, self.max_batch * wb, np.int32)
-                valid_lane = sid >= 0
-                grid_pos[valid_lane] = (sid[valid_lane] * wb
-                                        + (np.flatnonzero(valid_lane)
-                                           - off[sid[valid_lane]]))
-                extras.update(
-                    q_pos_grid=jnp.asarray(q_pos_grid.astype(np.int32)),
-                    grid_pos=jnp.asarray(grid_pos),
-                    kv_len_slot=jnp.asarray((self._lengths
-                                             + t_valid).astype(np.int32)))
+                extras.update(self._grid_extras(t_valid, off, sid))
             prev = (p["sampled"] if p is not None
                     else self._no_pending_tokens)
         with prof.phase("device"):

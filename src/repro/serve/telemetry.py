@@ -91,8 +91,9 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 # requests ending in failure (shed, cancelled, deadline-expired); v3 added
 # the `padding` section's ctx_positions / sampled_lanes work counters; v4
 # its state_slots_moved / state_slots_live / state_bytes state-arena
-# counters (zero without recurrent state)
-SNAPSHOT_SCHEMA_VERSION = 4
+# counters (zero without recurrent state); v5 its grid_rows counter (query
+# rows of the XLA packed step's attention grids)
+SNAPSHOT_SCHEMA_VERSION = 5
 
 # admission-wait histogram bucket edges (milliseconds, log-spaced); the last
 # bucket is open-ended

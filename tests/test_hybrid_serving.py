@@ -68,8 +68,9 @@ def reference_logits(c, arch, prompt, out):
     return np.asarray(jnp.matmul(h, embed.T, precision=R.HIGHEST))
 
 
-def serve(setup, prompts, max_new, max_batch=3, **kw):
-    """Serve the prompts; returns the requests and, per request uid, the
+def serve(setup, prompts, max_new, max_batch=3, first=None, **kw):
+    """Serve the prompts, the first `first` of them (all by default) one
+    step before the rest; returns the requests and, per request uid, the
     logits the engine sampled each of its tokens from."""
     from repro.serve import PagedEngine, Request
     c, arch, cfg, params = setup
@@ -92,7 +93,11 @@ def serve(setup, prompts, max_new, max_batch=3, **kw):
     eng._sample_and_finish = record
     reqs = [Request(uid=i, prompt=p, max_new_tokens=n)
             for i, (p, n) in enumerate(zip(prompts, max_new))]
-    for r in reqs:
+    for r in reqs[:first]:
+        eng.submit(r)
+    if first is not None:
+        eng.step()
+    for r in reqs[len(reqs[:first]):]:
         eng.submit(r)
     eng.run()
     return eng, reqs, {u: np.stack(v) for u, v in seen.items()}
@@ -142,6 +147,30 @@ def test_pad_lanes_beside_a_decoding_slot(setup):
     # the short prompt decodes while the long one prefills: its grid row
     # holds one token and Wb - 1 pad cells in every chunk step
     eng, reqs, seen = serve(setup, prompts(3, 90, seed=3), [20, 3])
+    assert max(gaps(setup, reqs, seen)) < TOL
+
+
+@pytest.mark.parametrize("lengths,step", [
+    # the short prompt decodes while prompts of 7 and 8 tokens take two
+    # rows of the chunk grid (Wb 8, P 3 at width 24)
+    ((3, 7, 8), ([1, 7, 8], 24, (3, 8))),
+    # ...and while a 40-token prompt takes 22 (the chunk grid's one row at
+    # width 24 and Wb 24) and a 50-token prompt one lane (the decode grid)
+    ((3, 40, 50), ([1, 22, 1], 24, (1, 24)))], ids=["two-chunks", "fitted"])
+def test_a_decoding_slot_beside_two_prefilling_slots(setup, monkeypatch,
+                                                     lengths, step):
+    import repro.serve.paged as paged
+    real, steps = paged.grid_steering, []
+
+    def record(t_valid, *a, **kw):
+        g = real(t_valid, *a, **kw)
+        steps.append((np.asarray(t_valid).tolist(), g["attn_cell"].size,
+                      g["chunk_q_pos"].shape if "chunk_q_pos" in g else None))
+        return g
+    monkeypatch.setattr(paged, "grid_steering", record)
+    eng, reqs, seen = serve(setup, prompts(*lengths, seed=6), [10, 4, 4],
+                            first=1)
+    assert step in steps, steps
     assert max(gaps(setup, reqs, seen)) < TOL
 
 

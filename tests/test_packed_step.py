@@ -18,7 +18,10 @@ hypothesis = require_hypothesis()
 from hypothesis import given, settings          # noqa: E402
 from hypothesis import strategies as st         # noqa: E402
 
-from repro.serve.paged import (TRASH_BLOCK, pack_slot_ids,  # noqa: E402
+import pytest                                   # noqa: E402
+
+from repro.serve.paged import (TRASH_BLOCK, chunk_grid_rows,  # noqa: E402
+                               fit_chunk_grid, grid_steering, pack_slot_ids,
                                packed_write_positions, schedule_step_tokens)
 
 
@@ -178,3 +181,140 @@ def test_schedule_is_greedy_fifo(state):
     for a, b_ in zip(prefill, prefill[1:]):
         if t_valid[b_] > 1:
             assert t_valid[a] == min(remaining[a], max(cap, 1))
+
+
+GRID_WIDTHS = [1] + list(range(8, 65, 8))
+
+
+@settings(max_examples=150, deadline=None)
+@given(step_states())
+def test_grid_steering_cells(state):
+    """The XLA packed step's attention grids: every valid lane lands in a
+    cell of its own — a single-token slot in its row of the (B, 1) decode
+    grid, a multi-token slot's i-th lane at column i of its own row of the
+    (P, Wb) chunk grid — and pad lanes in the spill cell; each row carries
+    its slot's positions and keys, and P follows the rung rule."""
+    live, remaining, lengths, budget, chunk_cap = state
+    t_valid = schedule_step_tokens(live, remaining, budget, chunk_cap)
+    width = budget
+    sid, off = pack_slot_ids(t_valid, width)
+    g = grid_steering(t_valid, off, sid, lengths, GRID_WIDTHS)
+    b = len(t_valid)
+    cell = g["attn_cell"]
+    wb = min(w for w in GRID_WIDTHS if w >= max(int(t_valid.max()), 1))
+    multi = np.flatnonzero(t_valid > 1)
+    if wb == 1:
+        assert "chunk_slots" not in g and len(multi) == 0
+        p = 0
+    else:
+        p, wb_ = g["chunk_q_pos"].shape
+        assert wb_ == wb
+        floor = max(1, width // wb)
+        assert p == chunk_grid_rows(width, wb, len(multi), b)
+        # the smallest of floor, 2 floor, 4 floor, ... that holds every
+        # multi-token slot, at most B
+        k = 0
+        while floor * 2 ** k < len(multi):
+            k += 1
+        assert p == min(b, floor * 2 ** k)
+    spill = b + p * wb
+    valid = sid >= 0
+    assert (cell[~valid] == spill).all()
+    assert len(set(cell[valid].tolist())) == int(valid.sum())
+    assert (cell[valid] < spill).all()
+    for slot in range(b):
+        tv = int(t_valid[slot])
+        lanes = np.arange(int(off[slot]), int(off[slot]) + tv)
+        if tv == 1:
+            assert cell[lanes[0]] == slot
+            assert g["dec_kv_len"][slot] == lengths[slot] + 1
+            continue
+        assert g["dec_kv_len"][slot] == 0
+        if tv == 0:
+            continue
+        r = int(np.flatnonzero(multi == slot)[0])
+        assert g["chunk_slots"][r] == slot
+        assert (cell[lanes] == b + r * wb + np.arange(tv)).all()
+        assert (g["chunk_q_pos"][r] == lengths[slot] + np.arange(wb)).all()
+        assert g["chunk_kv_len"][r] == lengths[slot] + tv
+    if p:
+        assert (g["chunk_kv_len"][len(multi):] == 0).all()
+    # the SSD's (B, Wb) slot grid rides only where asked for
+    assert "grid_pos" not in g
+    ssd = grid_steering(t_valid, off, sid, lengths, GRID_WIDTHS, ssd=True)
+    assert ssd["q_pos_grid"].shape == (b, wb)
+    gp = ssd["grid_pos"]
+    assert (gp[~valid] == b * wb).all()
+    lane = np.flatnonzero(valid)
+    assert (gp[valid] == sid[valid] * wb + lane - off[sid[valid]]).all()
+    assert (ssd["kv_len_slot"] == lengths + t_valid).all()
+
+
+@settings(max_examples=150, deadline=None)
+@given(spec_step_states())
+def test_fitted_prefill_chunks_take_the_floor_rung(state_and_drafts):
+    """fit_chunk_grid leaves at most max(1, width // Wb) multi-token
+    prefilling slots at the width it returns, the first in slot order, and
+    cuts the others to one lane; that width is the smallest rung holding
+    the lanes left. Without verify rows the chunk grid then has its
+    floor's P rows. Decode slots and their verify rows keep their lanes."""
+    (live, remaining, lengths, budget, chunk_cap), drafts = state_and_drafts
+    t0 = schedule_step_tokens(live, remaining, budget, chunk_cap,
+                              drafts=drafts)
+    widths = sorted({max(budget // 4, 1), max(budget // 2, 1), budget})
+    prefill = remaining > 0
+    t_valid, width = fit_chunk_grid(t0, prefill, budget, widths,
+                                    GRID_WIDTHS)
+    assert width == min(w for w in widths if w >= t_valid.sum())
+    wb = min(w for w in GRID_WIDTHS if w >= max(int(t_valid.max()), 1))
+    kept = np.flatnonzero(prefill & (t_valid > 1))
+    assert len(kept) <= max(1, width // wb)
+    assert (t_valid[~prefill] == t0[~prefill]).all()
+    cut = t_valid != t0
+    assert (t_valid[cut] == 1).all() and (t0[cut] > 1).all()
+    if cut.any():                  # the first chunks in slot order stay
+        assert kept.max() < np.flatnonzero(cut).min()
+    if not (~prefill & (t_valid > 1)).any():
+        sid, off = pack_slot_ids(t_valid, width)
+        g = grid_steering(t_valid, off, sid, lengths, GRID_WIDTHS)
+        if "chunk_q_pos" in g:
+            p, wb = g["chunk_q_pos"].shape
+            assert p == min(len(t_valid), max(1, width // wb))
+
+
+@pytest.mark.parametrize("config", ["granite-3-2b", "granite-4.0-h-micro"])
+def test_warm_up_lands_on_the_floor_rung(tiny_cfg, config):
+    """bench/harness.py's warm-up sends, for each (step width, Wb) pair of
+    the engine's ladders, n fresh prompts of p tokens; at the benchmark
+    cells' max_batch and block_size each first step lands on that pair,
+    whole, with P = width // Wb rows (the floor rung), so warming up
+    compiles every attention program a step without verify rows meets."""
+    import jax
+    from bench import harness, registry
+    from repro.models import model as M
+    from repro.serve import PagedEngine
+
+    sv = registry.config(config)["serving"]
+    b, bs = sv["max_batch"], sv["block_size"]
+    cfg = tiny_cfg(attention_prob="hccs", hccs_mode="i16_div")
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    eng = PagedEngine(params, cfg, max_batch=b, max_len=8 * bs,
+                      block_size=bs, packed=True)
+    for w, wb, n, p in harness.ladder(sv):
+        live = np.arange(b) < n
+        remaining = np.where(live, p, 0)
+        needed = min(n * min(p, eng._chunk_cap), eng.token_budget)
+        width = min(x for x in eng._widths if x >= needed)
+        assert width == w
+        t_valid = schedule_step_tokens(live, remaining, width,
+                                       eng._chunk_cap)
+        fitted, fitted_width = fit_chunk_grid(t_valid, live, width,
+                                              eng._widths, eng._grid_widths)
+        assert (fitted == t_valid).all() and fitted_width == width
+        sid, off = pack_slot_ids(t_valid, width)
+        g = grid_steering(t_valid, off, sid, np.zeros(b, np.int64),
+                          eng._grid_widths)
+        if wb == 1:
+            assert "chunk_q_pos" not in g
+        else:
+            assert g["chunk_q_pos"].shape == (min(b, max(1, w // wb)), wb)

@@ -766,6 +766,64 @@ def test_packed_step_parity_with_lockstep(served, rng, sharing):
     assert pt["pad_lanes_skipped"] > 0 and pf["pad_lanes_skipped"] == 0
 
 
+@pytest.mark.parametrize("mode", ["sync", "async", "blockwise",
+                                  "speculative"])
+def test_packed_split_grids_parity_with_lockstep(served, rng, monkeypatch,
+                                                 mode):
+    """The XLA packed step's two attention grids give greedy outputs
+    token-identical to the lockstep layout. Request 0 decodes while three
+    prompts of 6, 7 and 1 tokens arrive: at width 16 and Wb 16 the chunk
+    grid's floor is one row, so the 6-token chunk takes it and the 7-token
+    prompt one lane, beside the one-token prefill and the decode rider.
+    Also in the async loop, with every row on the blockwise path (8-key
+    blocks), and with speculative verify rows (garbage drafts of 2, all
+    rejected), which take the chunk grid's rungs above the floor: a verify
+    row beside a 40-token prompt's chunk fills 2 rows at width 64 and Wb
+    48, whose floor is one."""
+    import repro.serve.paged as paged
+    cfg, params = served
+    if mode == "blockwise":
+        cfg = cfg.replace(attention_impl="blockwise", block_k=8)
+    lens = (5, 5, 5, 40) if mode == "speculative" else (5, 6, 7, 1)
+    prompts = [rng.integers(0, 256, n).astype(np.int32) for n in lens]
+    first = 3 if mode == "speculative" else 1
+    steps = []
+    real = paged.grid_steering
+
+    def record(t_valid, off, sid, lengths, grid_widths, ssd=False):
+        g = real(t_valid, off, sid, lengths, grid_widths, ssd)
+        steps.append((np.asarray(t_valid).tolist(), len(sid),
+                      g["chunk_q_pos"].shape if "chunk_q_pos" in g else None))
+        return g
+    monkeypatch.setattr(paged, "grid_steering", record)
+    outs = {}
+    for packed in (False, True):
+        kw = dict(speculative=True, draft_len=2) if (
+            packed and mode == "speculative") else {}
+        eng = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
+                          packed=packed, async_loop=packed and mode == "async",
+                          **kw)
+        if kw:
+            eng._propose_drafts = lambda live, remaining: {
+                int(s): [7, 7] for s in np.flatnonzero(
+                    np.asarray(live) & (np.asarray(remaining) == 0))}
+        reqs = [Request(uid=i, prompt=p, max_new_tokens=6)
+                for i, p in enumerate(prompts)]
+        for r in reqs[:first]:
+            eng.submit(r)
+        eng.step()                        # these decode from here on
+        for r in reqs[first:]:
+            eng.submit(r)
+        done = eng.run()
+        outs[packed] = {r.uid: r.out_tokens for r in done}
+    assert outs[False] == outs[True] and len(outs[True]) == 4
+    if mode == "speculative":
+        assert any(shape and shape[0] > max(1, w // shape[1])
+                   for _, w, shape in steps), steps
+    else:
+        assert ([1, 6, 1, 1], 16, (1, 16)) in steps, steps
+
+
 def test_packed_step_prefill_heavy_efficiency(served, rng):
     """The packing acceptance regime: long prompts chunk-prefilling while
     short-prompt long-output requests decode alongside (every lockstep chunk

@@ -31,10 +31,12 @@ LATENCY_KEYS = {"requests", "ttft", "tpot", "e2e", "queue_wait",
                 "queue_wait_hist", "queue_depth_peak", "queue_depth_mean"}
 DIST_KEYS = {"count", "mean", "p50", "p95", "p99"}
 PHASES_KEYS = {"steps", "step_seconds", "coverage", "phases"}
-# v3 added the ctx_positions / sampled_lanes work counters
+# v3 added the ctx_positions / sampled_lanes work counters, v4 the state
+# arena's, v5 grid_rows
 PADDING_KEYS = {"lanes_valid", "lanes_total", "efficiency",
                 "pad_lanes_skipped", "ctx_positions", "sampled_lanes",
-                "state_slots_moved", "state_slots_live", "state_bytes"}
+                "state_slots_moved", "state_slots_live", "state_bytes",
+                "grid_rows"}
 REPO = Path(__file__).resolve().parents[1]
 
 
@@ -277,8 +279,9 @@ def test_device_fence_only_with_telemetry(served, rng, monkeypatch, name,
 
 def _count_step_inputs(eng):
     """Wrap every step function of `eng`; returns the running sums of the
-    valid lanes and their causal contexts the steps were handed."""
-    seen = {"lanes": 0, "ctx": 0}
+    valid lanes and their causal contexts the steps were handed, and of
+    the query rows of the attention grids they were steered onto."""
+    seen = {"lanes": 0, "ctx": 0, "grid": 0}
 
     def wrap(attr, idx):
         fn = getattr(eng, attr)
@@ -289,6 +292,9 @@ def _count_step_inputs(eng):
                 valid = np.asarray(ex["slot_ids"]) >= 0
                 seen["lanes"] += int(valid.sum())
                 seen["ctx"] += int(np.asarray(ex["kv_len"])[valid].sum())
+                if "dec_kv_len" in ex:
+                    seen["grid"] += ex["dec_kv_len"].size + (
+                        ex["chunk_q_pos"].size if "chunk_q_pos" in ex else 0)
             else:                            # lockstep: per-slot frontiers
                 tv = np.asarray(a[5]).astype(np.int64)
                 kv = np.asarray(ex["kv_len"]).astype(np.int64)
@@ -306,8 +312,9 @@ def _count_step_inputs(eng):
                                     "lockstep"])
 def test_work_counters_match_step_inputs(served, rng, layout):
     """padding_stats()' ctx_positions is the sum of every valid lane's
-    causal context the step functions are handed and sampled_lanes the
-    lanes sampled, on every step path."""
+    causal context the step functions are handed, sampled_lanes the lanes
+    sampled and grid_rows the attention grids' query rows, on every step
+    path."""
     cfg, params = served
     kw = dict(max_batch=4, max_len=128, block_size=8)
     if layout == "speculative":
@@ -334,6 +341,8 @@ def test_work_counters_match_step_inputs(served, rng, layout):
     pad = eng.padding_stats()
     assert pad["ctx_positions"] == seen["ctx"] > 0
     assert pad["lanes_valid"] == seen["lanes"]
+    assert pad["grid_rows"] == seen["grid"]
+    assert (pad["grid_rows"] > 0) == (layout != "lockstep")
     tokens = sum(len(r.out_tokens) for r in done)
     if layout == "speculative":
         assert pad["sampled_lanes"] >= tokens
@@ -373,7 +382,7 @@ def test_snapshot_schema_stability(served, rng, enabled):
     for name, eng in engines.items():
         snap = eng.snapshot()
         assert set(snap) == SNAPSHOT_KEYS
-        assert snap["schema_version"] == 4
+        assert snap["schema_version"] == 5
         assert snap["engine"] == name
         assert snap["robustness"] is None    # none of these are robust
         assert set(snap["kv_cache"]) == {"cache_bytes_logical",

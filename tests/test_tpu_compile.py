@@ -13,6 +13,7 @@ The TPU library may be loaded by one process at a time, so the topology is
 described inside a module fixture and never at import: every xdist worker
 collects the same tests, and only the worker given this file loads it.
 """
+import contextlib
 import os
 import re
 
@@ -44,17 +45,25 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def _no_compile_cache():
+@contextlib.contextmanager
+def _cache_off():
     """A compile for a described chip cannot be read back without one, so
     keep it out of JAX's persistent cache."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     old = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", old)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old)
+        cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    with _cache_off():
+        yield
 
 
 def _spec(sharding):
@@ -204,42 +213,67 @@ def _pool_moves(hlo_text, n_blocks, at_least):
     return out
 
 
-def test_xla_packed_step_moves_no_pool(one_chip):
+@pytest.fixture(scope="module")
+def rag_4k_step(one_chip):
     """The engine's packed step on the XLA attention path at the serving
-    benchmark's rag-4k shapes: granite-3-2b, a bf16 pool of 1792 blocks of
-    32, 16 slots, 512 lanes, a 128-wide attention grid. Its token-major pool
-    rides the layer scan's carry and is written in place, so no op copies,
-    slices or writes back a layer's pool, and the step's temp stays far
-    below one pool (4.7 GB; with the pool as scan xs/ys it read 5.86 GB)."""
+    benchmark's rag-4k shapes, compiled: granite-3-2b, a bf16 pool of 1792
+    blocks of 32, 16 slots, 512 lanes, a chunk grid of 4 slots x 128 (the
+    512 rung's floor) beside the 16-slot decode grid. Returns the compiled
+    step, the pool's block count and one layer's pool bytes."""
     from repro.models import model as M
     from repro.serve import PagedEngine
     from repro.serve.paged import init_paged_cache
 
     cfg = get_config("granite-3-2b").replace(
         cache_layout="paged", cache_dtype="bfloat16")
-    b, bs, max_len, n, width, wb = 16, 32, 4096, 1792, 512, 128
+    b, bs, max_len, n, width, wb, p = 16, 32, 4096, 1792, 512, 128, 4
     params = jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg))
     eng = PagedEngine(params, cfg, max_batch=b, max_len=max_len,
                       block_size=bs, num_blocks=max_len // bs + 2)
-    assert eng._use_grid
+    assert eng._use_grid and not eng._ssd_grid
     s = _spec(one_chip)
     place = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
     cache = place(jax.eval_shape(lambda: init_paged_cache(cfg, n, bs, b)))
-    layer_pool = n * bs * HKV * HD * 2
     assert cache["layers"]["k"].shape == (cfg.num_layers, n, bs, HKV * HD)
     extras = {"length": s((b,), jnp.int32),
               "block_table": s((b, max_len // bs), jnp.int32),
               "write_pos": s((1, width), jnp.int32),
               "kv_len": s((width,), jnp.int32),
               "slot_ids": s((width,), jnp.int32),
-              "q_pos_grid": s((b, wb), jnp.int32),
-              "grid_pos": s((width,), jnp.int32),
-              "kv_len_slot": s((b,), jnp.int32)}
-    compiled = eng._packed_fn.lower(
-        place(params["weights"]), place(params["hccs"]),
-        s((1, width), jnp.int32), s((1, width), jnp.int32), cache, extras,
-        s((b,), jnp.int32)).compile()
+              "attn_cell": s((width,), jnp.int32),
+              "dec_kv_len": s((b,), jnp.int32),
+              "chunk_slots": s((p,), jnp.int32),
+              "chunk_q_pos": s((p, wb), jnp.int32),
+              "chunk_kv_len": s((p,), jnp.int32)}
+    with _cache_off():
+        compiled = eng._packed_fn.lower(
+            place(params["weights"]), place(params["hccs"]),
+            s((1, width), jnp.int32), s((1, width), jnp.int32), cache,
+            extras, s((b,), jnp.int32)).compile()
+    return compiled, n, n * bs * HKV * HD * 2
+
+
+def test_xla_packed_step_moves_no_pool(rag_4k_step):
+    """The rag-4k step's token-major pool rides the layer scan's carry and
+    is written in place, so no op copies, slices or writes back a layer's
+    pool, and the step's temp stays far below one pool (4.7 GB; with the
+    pool as scan xs/ys it read 5.86 GB)."""
+    compiled, n, layer_pool = rag_4k_step
     assert _pool_moves(compiled.as_text(), n, layer_pool) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1e9, temp
+
+
+def test_xla_packed_step_grids_only_the_chunk_slots(rag_4k_step):
+    """The rag-4k step's attention core runs a (4, 128) chunk grid and a
+    (16, 1) decode grid: no op works on the 16 slots x 128 rows grid
+    (`[16,8,4,128,...]`, slots x KV heads x heads per KV head x rows)
+    that gave every slot the longest chunk's rows, and temp stays under
+    1 GB."""
+    compiled, _, _ = rag_4k_step
+    text = compiled.as_text()
+    assert re.search(r"\[16,8,4,128[,\]]", text) is None
+    assert re.search(r"\[4,8,4,128[,\]]", text) is not None
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1e9, temp
 
@@ -284,9 +318,16 @@ def test_hybrid_packed_step_moves_no_state_arena(one_chip, width, wb):
               "write_pos": s((1, width), jnp.int32),
               "kv_len": s((width,), jnp.int32),
               "slot_ids": s((width,), jnp.int32),
+              "attn_cell": s((width,), jnp.int32),
+              "dec_kv_len": s((b,), jnp.int32),
               "q_pos_grid": s((b, wb), jnp.int32),
               "grid_pos": s((width,), jnp.int32),
               "kv_len_slot": s((b,), jnp.int32)}
+    if wb > 1:                 # the 1024 rung's floor: 8 chunk rows
+        p = width // wb
+        extras.update(chunk_slots=s((p,), jnp.int32),
+                      chunk_q_pos=s((p, wb), jnp.int32),
+                      chunk_kv_len=s((p,), jnp.int32))
     compiled = eng._packed_fn.lower(
         place(params["weights"]), place(params["hccs"]),
         s((1, width), jnp.int32), s((1, width), jnp.int32), cache, extras,
