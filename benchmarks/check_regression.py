@@ -18,9 +18,9 @@ Two metric classes, two tolerance regimes:
   rates, greedy exact-match) are deterministic given the code, so their
   bands are tight (10%). These are the real per-PR gate.
 
-Ratios the benchmark computes between its own rows (packed vs lockstep,
-sharing on vs off, int8 vs fp32 bytes) are gated in ratio form, so a
-globally slow box — which scales both sides — cancels out.
+Ratios the benchmark computes between its own rows (sharing on vs off,
+int8 vs fp32 bytes) are gated in ratio form, so a globally slow box —
+which scales both sides — cancels out.
 
     # gate a fresh fast run against the committed reference
     PYTHONPATH=src python -m benchmarks.check_regression
@@ -82,15 +82,13 @@ def metric_specs(ref: dict) -> list:
                           ("engines", ("scheduler", name),
                            "padding_efficiency"),
                           HIGHER, TOL_STRUCTURAL))
-    for layout in ("lockstep", "packed"):
-        specs.append((f"prefill_heavy[{layout}].tok_per_s",
-                      ("prefill_heavy", ("step_layout", layout),
-                       "tok_per_s"),
-                      HIGHER, TOL_THROUGHPUT))
-        specs.append((f"prefill_heavy[{layout}].padding_efficiency",
-                      ("prefill_heavy", ("step_layout", layout),
-                       "padding_efficiency"),
-                      HIGHER, TOL_STRUCTURAL))
+    specs.append(("prefill_heavy[packed].tok_per_s",
+                  ("prefill_heavy", ("step_layout", "packed"), "tok_per_s"),
+                  HIGHER, TOL_THROUGHPUT))
+    specs.append(("prefill_heavy[packed].padding_efficiency",
+                  ("prefill_heavy", ("step_layout", "packed"),
+                   "padding_efficiency"),
+                  HIGHER, TOL_STRUCTURAL))
     for variant in ("off", "on"):
         specs.append((f"prefix_sharing[{variant}].tok_per_s",
                       ("prefix_sharing", ("variant", variant), "tok_per_s"),

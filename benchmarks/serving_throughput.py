@@ -1,6 +1,6 @@
 """Serving throughput: wave lockstep vs slot-based continuous batching vs
-paged-KV chunked prefill (lockstep AND packed token steps), plus paged
-prompt-prefix sharing.
+paged-KV chunked prefill in packed token steps, plus paged prompt-prefix
+sharing.
 
 A mixed prompt/output-length workload (the online-serving regime): prompt
 lengths and output budgets drawn from skewed distributions, so the wave
@@ -11,12 +11,10 @@ clock, after a warm-up pass that covers every jit shape (prefill buckets or
 chunk widths + decode) for each engine, so compile time is excluded.
 
 Every row also records PADDING EFFICIENCY (valid token-lanes / padded
-token-lanes over the timed steps): the paged lockstep chunk step pads every
-decode-riding slot to (block_size,) lanes, and the packed token step
-(serve/paged.py packed mode) removes that structurally — the third,
-prefill-heavy workload (long prompts, short outputs, so decode-riding waste
-dominates chunk steps) runs paged lockstep vs packed head-to-head and is the
-acceptance gate for the packing win.
+token-lanes over the timed steps): the packed token step (serve/paged.py)
+gives a decode-riding slot one lane, not a padded chunk-wide row — the
+third, prefill-heavy workload (long prompts, short outputs, so chunk steps
+carry riders) gates the packed step's efficiency and tok/s.
 
 A shared-system-prompt workload (every request opens with the same 48-token
 prefix — the chatbot/few-shot regime) runs the paged engine with prefix
@@ -88,7 +86,7 @@ per engine — plus the prefix-sharing and prefill-heavy rows — so the perf
 trajectory is tracked across PRs.
 
     PYTHONPATH=src python -m benchmarks.serving_throughput \
-        --engine wave --engine paged --json out.json
+        --engine wave --engine paged+packed --json out.json
 """
 from __future__ import annotations
 
@@ -191,7 +189,7 @@ def _overload_workload(rng, n, classes=3):
 def _prefill_heavy_workload(rng, n):
     """Long prompts, short-to-moderate outputs: most steps are chunk steps
     where the decode-riding slots dominate the padded lanes — the regime the
-    packed token step targets (lockstep burns block_size lanes per rider)."""
+    packed token step targets (one lane per rider, not a chunk-wide row)."""
     reqs = []
     for i in range(n):
         plen = int(rng.choice([40, 56, 72, 88], p=[.35, .3, .2, .15]))
@@ -254,31 +252,24 @@ def _serve_multi_turn(make_engine, warm_traffic, traffic, passes: int = 3):
 
 def _engine_factories(cfg, params):
     mk = dict(max_batch=MAX_BATCH, max_len=MAX_LEN)
-    # "paged" is the lockstep (B, block_size)/(B, 1) baseline; "paged+packed"
-    # flattens each step to a ragged token batch (the library default)
+    # "paged+packed" flattens each step to a ragged token batch
     return {
         "wave": lambda: ServeEngine(params, cfg, **mk),
         "continuous": lambda: ContinuousEngine(params, cfg, **mk),
         "continuous+kernel": lambda: ContinuousEngine(
             params, cfg.replace(decode_kernel="fused"), **mk),
-        "paged": lambda: PagedEngine(params, cfg, block_size=BLOCK_SIZE,
-                                     packed=False, **mk),
         "paged+packed": lambda: PagedEngine(params, cfg,
-                                            block_size=BLOCK_SIZE,
-                                            packed=True, **mk),
-        "paged+kernel": lambda: PagedEngine(
-            params, cfg.replace(decode_kernel="fused"),
-            block_size=BLOCK_SIZE, packed=False, **mk),
+                                            block_size=BLOCK_SIZE, **mk),
         "paged+packed+kernel": lambda: PagedEngine(
             params, cfg.replace(decode_kernel="fused"),
-            block_size=BLOCK_SIZE, packed=True, **mk),
+            block_size=BLOCK_SIZE, **mk),
     }
 
 
 # interpret-mode kernel emulation is slow on CPU; the packed+kernel row is
 # opt-in via --engine so the default sweep stays fast
-DEFAULT_ENGINES = ["wave", "continuous", "continuous+kernel", "paged",
-                   "paged+packed", "paged+kernel"]
+DEFAULT_ENGINES = ["wave", "continuous", "continuous+kernel",
+                   "paged+packed"]
 
 
 def _cache_byte_stats(eng):
@@ -304,7 +295,7 @@ def _prefix_delta(eng, p0):
                    "prefill_tokens", "prefill_tokens_skipped",
                    "prompt_tokens_skipped", "decode_tokens_skipped",
                    "followup_prefill_tokens", "followup_tokens_skipped",
-                   "cow_copies", "evictions", "pad_lanes_skipped",
+                   "cow_copies", "evictions",
                    "spec_steps", "spec_rollbacks", "tokens_drafted",
                    "tokens_accepted", "tokens_rejected")}
     d["hit_rate"] = d["hits"] / max(d["lookups"], 1)
@@ -329,7 +320,6 @@ def _timed(eng, serve_fn):
     n0 = getattr(eng, "occupancy_steps", 0)
     lv0 = getattr(eng, "lanes_valid", 0)
     lt0 = getattr(eng, "lanes_total", 0)
-    ps0 = getattr(eng, "pad_lanes_skipped", 0)
     p0 = eng.prefix_stats() if getattr(eng, "prefix_sharing", False) else None
     t0 = time.perf_counter()
     done = serve_fn()
@@ -344,8 +334,6 @@ def _timed(eng, serve_fn):
     row = dict(tokens=sum(len(r.out_tokens) for r in done), seconds=dt,
                **_cache_byte_stats(eng), occupancy=occ,
                padding_efficiency=pad_eff,
-               pad_lanes_skipped=(getattr(eng, "pad_lanes_skipped", 0) - ps0
-                                  if lt else None),
                prefix=None if p0 is None else _prefix_delta(eng, p0),
                snapshot=eng.snapshot())
     return row, done
@@ -410,32 +398,27 @@ def run(fast: bool = True, engines: list | None = None,
         out.append(dict(scheduler=name, tok_per_s=tps,
                         vs_first=tps / base_tps, **row))
 
-    # prefill-heavy workload: paged lockstep vs packed token steps — the
-    # acceptance gate for the packing win (tok/s AND padding efficiency)
+    # prefill-heavy workload: the packed step's padding efficiency and tok/s
+    # where chunk steps carry decode riders
     packed_out = []
     if engines is None or any(e.startswith("paged") for e in names):
-        # 2x the request count: the packed-vs-lockstep delta is the
-        # acceptance gate, so the timed region gets extra length to keep
-        # scheduler noise well below the effect size
+        # 2x the request count keeps scheduler noise well below the gated
+        # rate
         hreqs = _prefill_heavy_workload(np.random.default_rng(3), 2 * n)
         hwarm = _prefill_heavy_workload(np.random.default_rng(3), 2 * n)
-        # full pool so packing, not admission gating, is what differs
+        # full pool so admission gating does not shape the steps
         nblk = MAX_BATCH * (MAX_LEN // BLOCK_SIZE) + 1
         print("\n# prefill-heavy (paged, long prompts): step_layout, tokens, "
-              "s, tok/s, pad_eff, pad_lanes_skipped")
-        for packed in (False, True):
-            row = _serve(
-                lambda: PagedEngine(params, cfg, block_size=BLOCK_SIZE,
-                                    max_batch=MAX_BATCH, max_len=MAX_LEN,
-                                    num_blocks=nblk, packed=packed),
-                hwarm, hreqs)
-            tps = row["tokens"] / row["seconds"]
-            print("prefill_heavy,%s,%d,%.2f,%.1f,%.2f,%d" % (
-                "packed" if packed else "lockstep", row["tokens"],
-                row["seconds"], tps, row["padding_efficiency"],
-                row["pad_lanes_skipped"]))
-            packed_out.append(dict(step_layout="packed" if packed
-                                   else "lockstep", tok_per_s=tps, **row))
+              "s, tok/s, pad_eff")
+        row = _serve(
+            lambda: PagedEngine(params, cfg, block_size=BLOCK_SIZE,
+                                max_batch=MAX_BATCH, max_len=MAX_LEN,
+                                num_blocks=nblk),
+            hwarm, hreqs)
+        tps = row["tokens"] / row["seconds"]
+        print("prefill_heavy,packed,%d,%.2f,%.1f,%.2f" % (
+            row["tokens"], row["seconds"], tps, row["padding_efficiency"]))
+        packed_out.append(dict(step_layout="packed", tok_per_s=tps, **row))
 
     # shared-system-prompt workload: paged engine, prefix sharing off vs on
     # (skipped when --engine filters to non-paged rows only)
@@ -522,7 +505,7 @@ def run(fast: bool = True, engines: list | None = None,
                 eng = PagedEngine(params, cfg, block_size=BLOCK_SIZE,
                                   max_batch=MAX_BATCH, max_len=SPEC_MAX_LEN,
                                   num_blocks=nblk, prefix_sharing=sharing,
-                                  decode_sharing=sharing, packed=True,
+                                  decode_sharing=sharing,
                                   speculative=spec)
                 _serve_turns(eng, swarm, f"w{int(spec)}-", reply=SPEC_REPLY)
                 for s in range(len(swarm)):
@@ -583,8 +566,7 @@ def run(fast: bool = True, engines: list | None = None,
             qcfg = cfg.replace(kv_quant=quant)
             row = _serve(
                 lambda: PagedEngine(params, qcfg, block_size=BLOCK_SIZE,
-                                    max_batch=MAX_BATCH, max_len=MAX_LEN,
-                                    packed=True),
+                                    max_batch=MAX_BATCH, max_len=MAX_LEN),
                 qwarm, qreqs, keep_outputs=True)
             outputs = row.pop("outputs")
             tps = row["tokens"] / row["seconds"]
@@ -628,7 +610,7 @@ def run(fast: bool = True, engines: list | None = None,
             tel = Telemetry(enabled=True)
             eng = PagedEngine(params, cfg, block_size=BLOCK_SIZE,
                               max_batch=MAX_BATCH, max_len=MAX_LEN,
-                              packed=True, async_loop=mode, telemetry=tel)
+                              async_loop=mode, telemetry=tel)
             for r in copy.deepcopy(awarm):
                 eng.submit(r)
             eng.run()
@@ -694,7 +676,7 @@ def run(fast: bool = True, engines: list | None = None,
         swarm = _prefix_workload(np.random.default_rng(23), n)
         eng = PagedEngine(params, cfg, block_size=BLOCK_SIZE,
                           max_batch=MAX_BATCH, max_len=MAX_LEN,
-                          prefix_sharing=True, packed=True, telemetry=tel)
+                          prefix_sharing=True, telemetry=tel)
         # two warm-up drains: cold-prefix-cache then all-hit chunk shapes
         # (same reasoning as the prefix-sharing section's warmup_passes=2)
         for _ in range(2):
@@ -743,7 +725,7 @@ def run(fast: bool = True, engines: list | None = None,
         eng = PagedEngine(
             params, cfg, block_size=BLOCK_SIZE, max_batch=MAX_BATCH,
             max_len=MAX_LEN, num_blocks=nblk, prefix_sharing=True,
-            packed=True, telemetry=tel,
+            telemetry=tel,
             admission=AdmissionConfig(max_queue=2 * MAX_BATCH,
                                       backpressure="shed-lowest-priority",
                                       preemption=True))
@@ -864,7 +846,7 @@ def run(fast: bool = True, engines: list | None = None,
         preqs = _prefix_workload(np.random.default_rng(53), n)
         ref_eng = PagedEngine(params, cfg, block_size=BLOCK_SIZE,
                               max_batch=MAX_BATCH, max_len=MAX_LEN,
-                              prefix_sharing=True, packed=True)
+                              prefix_sharing=True)
         for r in copy.deepcopy(preqs):
             ref_eng.submit(r)
         ref_out = {r.uid: [int(t) for t in r.out_tokens]
@@ -875,7 +857,7 @@ def run(fast: bool = True, engines: list | None = None,
         # parity gate over zero preemptions would be vacuous)
         peng = PagedEngine(params, cfg, block_size=BLOCK_SIZE,
                            max_batch=MAX_BATCH, max_len=MAX_LEN,
-                           num_blocks=14, prefix_sharing=True, packed=True,
+                           num_blocks=14, prefix_sharing=True,
                            admission=AdmissionConfig(preemption=True))
         work = copy.deepcopy(preqs)
         for r in work:
@@ -960,8 +942,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--engine", action="append",
                     choices=["wave", "continuous", "continuous+kernel",
-                             "paged", "paged+packed", "paged+kernel",
-                             "paged+packed+kernel"],
+                             "paged+packed", "paged+packed+kernel"],
                     help="engine row(s) to run (default: all but the "
                          "interpret-slow paged+packed+kernel)")
     ap.add_argument("--json", default=DEFAULT_JSON,

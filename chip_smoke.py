@@ -192,7 +192,7 @@ def phase_engine(name, params, cfg, num_blocks, seed, dev, clock,
     blockers = decode_kernel_blockers(cfg)
     if kernel and (cfg.decode_kernel == "none" or blockers):
         raise AssertionError(f"{name}: the kernel cannot run: {blockers}")
-    eng = PagedEngine(params, cfg, packed=True, max_batch=MAX_BATCH,
+    eng = PagedEngine(params, cfg, max_batch=MAX_BATCH,
                       max_len=MAX_LEN, block_size=BLOCK,
                       num_blocks=num_blocks, admission=None)
     if eng._use_grid == kernel:

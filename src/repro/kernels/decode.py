@@ -38,10 +38,12 @@ scalar-prefetched *block table* instead of a contiguous offset, so the block
 gather is free (it steers the DMA), and sentinel (-1) table entries reuse the
 dead-block `pl.when` skip path. HCCS linearity is what makes paging trivial
 here — partial sums over blocks are exact, so no per-block rescaling is ever
-needed regardless of the physical block order.
+needed regardless of the physical block order. No engine step calls it:
+every paged step is a packed step, which runs the fourth variant below; its
+tests and chip_smoke.py keep it checked.
 
-A fourth, `hccs_packed_prefill`, is the token-centric packed-step variant
-(serve/paged.py packed mode): rows are TOKENS, not slots. Each of the T
+A fourth, `hccs_packed_prefill`, is the token-centric variant for the packed
+step (serve/paged.py): rows are TOKENS, not slots. Each of the T
 packed tokens carries a slot id and a per-token frontier; the KV index_map
 walks `block_table[slot_ids[token]]` — one extra scalar indirection on top of
 the paged walk — so a ragged mixed prefill/decode batch runs as T independent

@@ -69,7 +69,8 @@ def hccs_packed_prefill(q, k_pool, v_pool, block_table, slot_ids, lengths,
                         scale, theta, mode: str = "wide",
                         static_max: bool = False, block_k: int = 128,
                         k_scales=None, v_scales=None) -> jax.Array:
-    """Token-centric packed-step HCCS attention (see kernels/decode.py).
+    """Token-centric HCCS attention of the packed step (see
+    kernels/decode.py).
     k_scales/v_scales (N, Hkv) f32 dequantize int8 (kv_quant) pools in-tile."""
     return _hccs_packed_prefill(q, k_pool, v_pool, block_table, slot_ids,
                                 lengths, scale, theta, mode=mode,

@@ -85,13 +85,6 @@ def main():
                          "of this many chat turns (every turn submits a "
                          "fresh --prompt-len user message on top of the "
                          "stored history; paged scheduler only)")
-    ap.add_argument("--step-layout", default=None,
-                    choices=["packed", "lockstep"],
-                    help="paged step layout (default packed): 'packed' "
-                         "flattens each step to a ragged token batch (rows "
-                         "are tokens, zero padded decode-riding lanes); "
-                         "'lockstep' keeps the (B, block_size)/(B, 1) "
-                         "baseline shapes")
     ap.add_argument("--kv-quant", default="none", choices=["none", "int8"],
                     help="store paged KV blocks as int8 with per-block "
                          "per-kv-head scales (quantize at write, dequantize "
@@ -101,7 +94,7 @@ def main():
                          "--draft-len tokens per decode step from the prefix "
                          "trie (n-gram prompt-lookup fallback) and verify "
                          "them all in ONE packed step; greedy outputs are "
-                         "token-identical (paged scheduler, packed layout)")
+                         "token-identical (paged scheduler only)")
     ap.add_argument("--draft-len", type=int, default=4,
                     help="max draft tokens per decode step (--speculative)")
     ap.add_argument("--async-loop", action="store_true",
@@ -109,11 +102,10 @@ def main():
                          "step N+1 while step N's sampled tokens are still "
                          "in flight, committing host bookkeeping one step "
                          "behind (greedy outputs stay token-identical; "
-                         "paged scheduler, packed layout)")
+                         "paged scheduler only)")
     ap.add_argument("--token-budget", type=int, default=0,
-                    help="packed-step token lanes per chunk step "
-                         "(0 = max_batch * block_size, one lockstep chunk "
-                         "step's lane count)")
+                    help="token lanes per packed step (0 = max_batch * "
+                         "block_size; paged scheduler only)")
     ap.add_argument("--priority-classes", type=int, default=1, metavar="N",
                     help="assign demo requests round-robin to N priority/SLA "
                          "classes (0 = lowest); admission serves the highest "
@@ -169,25 +161,18 @@ def main():
                          "session API; use --scheduler paged")
     if args.turns < 1:
         raise SystemExit(f"--turns must be >= 1, got {args.turns}")
-    if args.scheduler != "paged" and (args.step_layout is not None
-                                      or args.token_budget):
-        raise SystemExit("--step-layout/--token-budget configure the paged "
-                         "engine's packed token step; use --scheduler paged")
+    if args.scheduler != "paged" and args.token_budget:
+        raise SystemExit("--token-budget configures the paged engine's "
+                         "packed token step; use --scheduler paged")
     if args.kv_quant != "none" and args.scheduler != "paged":
         raise SystemExit("--kv-quant quantizes the paged block pool; use "
                          "--scheduler paged")
     if args.speculative and args.scheduler != "paged":
         raise SystemExit("--speculative drafts against the paged engine's "
                          "prefix trie; use --scheduler paged")
-    if args.speculative and args.step_layout == "lockstep":
-        raise SystemExit("--speculative verifies all drafts in one packed "
-                         "step; drop --step-layout lockstep")
     if args.async_loop and args.scheduler != "paged":
         raise SystemExit("--async-loop pipelines the paged engine's packed "
                          "token step; use --scheduler paged")
-    if args.async_loop and args.step_layout == "lockstep":
-        raise SystemExit("--async-loop pipelines the packed token step; "
-                         "drop --step-layout lockstep")
     if args.arrival_rate < 0:
         raise SystemExit(f"--arrival-rate must be >= 0, got "
                          f"{args.arrival_rate}")
@@ -259,7 +244,6 @@ def main():
                           max_len=max_len,
                           block_size=args.block_size or None,
                           num_blocks=args.num_blocks or None,
-                          packed=(args.step_layout != "lockstep"),
                           token_budget=args.token_budget or None,
                           speculative=args.speculative,
                           draft_len=args.draft_len,
@@ -429,8 +413,7 @@ def main():
     if args.scheduler == "paged":
         pad = eng.padding_stats()
         print(f"step padding: {pad['lanes_valid']}/{pad['lanes_total']} "
-              f"token-lanes valid ({100 * pad['efficiency']:.0f}%), "
-              f"{pad['pad_lanes_skipped']} lanes skipped by packing")
+              f"token-lanes valid ({100 * pad['efficiency']:.0f}%)")
     if args.speculative:
         s = eng.prefix_stats()
         rate = s["acceptance_rate"]
@@ -442,17 +425,15 @@ def main():
               f"{s['spec_rollbacks']} rollbacks")
     if args.prefix_sharing or args.decode_sharing:
         s = eng.prefix_stats()
-        # the two prefill savings side by side: prefix sharing skips real
-        # prompt tokens, packing skips padded token-lanes — with the skip
-        # split by matched-block origin (prompt-cached vs decode-cached)
+        # the prefill skip split by matched-block origin (prompt-cached vs
+        # decode-cached)
         print(f"prefix sharing: {s['hits']}/{s['lookups']} hits "
               f"({s['prompt_hits']} prompt-block, {s['decode_hits']} "
               f"decode-block), "
               f"{s['prefill_tokens_skipped']}/{s['prefill_tokens']} prefill "
               f"tokens skipped by prefix ({100 * s['skip_rate']:.0f}%: "
               f"{s['prompt_tokens_skipped']} prompt + "
-              f"{s['decode_tokens_skipped']} decode) vs "
-              f"{s['pad_lanes_skipped']} token-lanes skipped by packing, "
+              f"{s['decode_tokens_skipped']} decode), "
               f"{s['cow_copies']} COW copies, {s['evictions']} evictions, "
               f"{s['cached_blocks']} blocks cached "
               f"({s['cached_decode_blocks']} from decode)")

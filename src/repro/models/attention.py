@@ -143,12 +143,12 @@ def _slot_scatter(cache_kv, new_kv, lengths):
 
 
 # transient per-step keys the paged engine attaches to the cache; they steer
-# the step and are not part of the carried cache state. `slot_ids`,
-# `attn_cell`, `dec_kv_len`, `chunk_slots`, `chunk_q_pos`, `chunk_kv_len`,
-# `q_pos_grid`, `grid_pos` and `kv_len_slot` only ride on packed token
-# steps: `slot_ids` selects the token-centric branch, the next five steer
-# the XLA path's two attention grids (see _packed_attention), and the last
-# three the SSD's (B, Wb) slot grid (models/ssm.py apply_ssd_packed).
+# the step and are not part of the carried cache state. `slot_ids` gives
+# each lane of a packed token step its slot; `attn_cell`, `dec_kv_len`,
+# `chunk_slots`, `chunk_q_pos` and `chunk_kv_len` steer the XLA path's two
+# attention grids (see _packed_attention), and `q_pos_grid`, `grid_pos` and
+# `kv_len_slot` the SSD's (B, Wb) slot grid (models/ssm.py
+# apply_ssd_packed).
 # `fresh_blocks` only rides on kv_quant="int8" steps: block ids allocated
 # since the last step, whose stale per-block scales must be reset to zero
 # before this step's quantized writes (padded with the trash block 0).
@@ -222,13 +222,14 @@ def paged_quant_scatter(pool, scales, new_kv, write_pos, draft_rows=None,
 
     The per-ROW fold (rather than quantizing a step's rows against the
     step-final scale in one shot) is what keeps a block's bytes a pure
-    function of the row values and their order: lockstep and packed steps
-    partition the same rows into different step boundaries, but the fold
-    they apply is the identical composition either way — so packed/lockstep,
-    prefix-/decode-sharing and session re-feed parity all stay bit-exact
-    under quantization. The requant multiply is the identity when the scale
-    did not grow (ratio == 1.0 exactly), and zeroes stale bytes on a freshly
-    allocated block (scale reset to 0 by the engine => ratio == 0.0).
+    function of the row values and their order: steps of different token
+    budgets partition the same rows into different step boundaries, but the
+    fold they apply is the identical composition either way — so token
+    budget, prefix-/decode-sharing and session re-feed parity all stay
+    bit-exact under quantization. The requant multiply is the identity when
+    the scale did not grow (ratio == 1.0 exactly), and zeroes stale bytes on
+    a freshly allocated block (scale reset to 0 by the engine => ratio ==
+    0.0).
     Quantization rounds half-away-from-zero (quant/int8.py's documented
     hardware mode). Returns (pool, scales).
 
@@ -535,11 +536,10 @@ def _packed_attention(q, k_pool, v_pool, cache, cfg, hccs, hd,
     cover those slots; see serve/paged.py grid_steering); pad lanes to
     the spill cell B + P*Wb. A step with no multi-token slot carries no
     chunk grid. Each grid runs the SAME dense/blockwise attention as the
-    lockstep layout over its slots' gathered blocks — one per-slot KV
-    gather, NOT one per token — and the outputs gather back to packed
-    lanes. Every other layer (projections, MLP, norms, logits) stays
-    token-packed, while each row of the core does what lockstep's row for
-    the same token does (bit-parity for free).
+    slot arena's per-slot rows, over its slots' gathered blocks — one
+    per-slot KV gather, NOT one per token — and the outputs gather back to
+    packed lanes. Every other layer (projections, MLP, norms, logits)
+    stays token-packed.
     """
     b, h, t, _ = q.shape
     sid = cache["slot_ids"]
@@ -590,7 +590,7 @@ def _grid_attention(q, k, v, q_pos, k_len, cfg, hccs):
     """Attention of a per-slot grid q (S, H, W, hd) at positions q_pos
     (S, W) over each row's gathered keys k/v (S, Hkv, L, hd), the first
     k_len (S,) of them valid: blockwise for multi-token rows over long
-    tables, dense otherwise (the lockstep layout's rule)."""
+    tables, dense otherwise (apply_attention's rule for a dense cache)."""
     tk = k.shape[2]
     if (cfg.attention_impl == "blockwise" or
             (cfg.attention_impl == "auto" and q.shape[2] > 1 and
@@ -612,14 +612,13 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
     PAGED layout (serve/paged.py): k/v are instead global block pools —
     one layer's head-major (N, Hkv, block_size, hd_c) pool for the fused
     kernels, or the token-major (L, N, block_size, Hkv*hd) stack with this
-    layer's `layer` index for the XLA path — and the cache carries
-    `block_table` (B, nblk), `write_pos` (B, T) flat scatter targets, and
-    `kv_len` (B,) per-slot valid counts — the dispatch keys off
-    `block_table`'s presence, the paged analogue of `length` going
-    scalar-vs-vector for the slot arena.
-    PACKED paged steps additionally carry `slot_ids` (T,): x is then a
-    (1, T) ragged token batch (rows are tokens, not slots), positions are
-    per-token, and `kv_len` is per-TOKEN — see _packed_attention.
+    layer's `layer` index for the XLA path — and the step is a PACKED
+    token step: x is a (1, T) ragged token batch (rows are tokens, not
+    slots), positions are per-token, and the cache carries `block_table`
+    (B, nblk), `write_pos` (1, T) flat scatter targets, `slot_ids` (T,)
+    and per-TOKEN `kv_len` (T,) — see _packed_attention. The dispatch
+    keys off `block_table`'s presence, the paged analogue of `length`
+    going scalar-vs-vector for the slot arena.
     Prefix sharing changes nothing here: a slot admitted past a shared
     prefix arrives with cache["length"] already at the partial-prefill start
     offset (so the default `positions = length + arange(t)` resumes RoPE at
@@ -689,10 +688,7 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
 
     new_cache = None
     k_len = None
-    paged = cache is not None and "block_table" in cache
-    per_slot = (cache is not None and not paged
-                and jnp.ndim(cache["length"]) > 0)
-    if paged:
+    if cache is not None and "block_table" in cache:
         # paged arena: K/V live in a global block pool addressed through
         # per-slot block tables; the write targets (incl. trash routing for
         # tokens past each slot's valid count) were resolved on the host.
@@ -734,37 +730,17 @@ def apply_attention(p, x, cfg, hccs=None, positions=None, cache=None,
             # them out of the returned cache after the step.
             new_cache.update(staged_k=k.astype(jnp.float32),
                              staged_v=v.astype(jnp.float32))
-        # per-slot valid-KV counts for this step (length + per-slot t_valid;
-        # chunked prefill makes t_valid ragged, so `length + t` is wrong here)
-        k_len = cache["kv_len"]
-        if "slot_ids" in cache:
-            # PACKED token step (b == 1): lane i of the t axis is an
-            # independent single-query token owned by slot_ids[i], at global
-            # position positions[0, i], with causal frontier kv_len[i] —
-            # rows are tokens, so a ragged mixed prefill/decode batch runs
-            # with zero padded query lanes (see serve/paged.py packed mode)
-            out = _packed_attention(q, kc, vc, cache, cfg, hccs, hd,
-                                    k_scales=ks, v_scales=vs)
-            return _project_out(out, p, b, t), new_cache
-        if t == 1 and not xla_reads_kv(cfg) and hccs is not None:
-            # block-sparse fused decode: the kernel walks the block table
-            from repro.kernels.ops import hccs_paged_decode
-            theta = jnp.stack([hccs["B"], hccs["S"], hccs["D"]], axis=-1)
-            with jax.named_scope("attn.core"):
-                o = hccs_paged_decode(
-                    q[:, :, 0, :].astype(jnp.float32), kc, vc,
-                    cache["block_table"], k_len, hccs["scale"], theta,
-                    mode=cfg.hccs_mode,
-                    static_max=(cfg.decode_kernel == "static_max"),
-                    k_scales=ks, v_scales=vs)
-            out = o.astype(q.dtype).reshape(b, 1, h * hd)
-            return _project_out(out, p, b, 1), new_cache
-        with jax.named_scope("attn.gather"):
-            k = _paged_gather(kc, cache["block_table"], hd, ks, li)
-            v = _paged_gather(vc, cache["block_table"], hd, vs, li)
-    elif cache is not None:
+        # a paged step is a PACKED token step (b == 1): lane i of the t axis
+        # is an independent single-query token owned by slot_ids[i], at
+        # global position positions[0, i], with causal frontier kv_len[i] —
+        # rows are tokens, so a ragged mixed prefill/decode batch runs with
+        # zero padded query lanes (see serve/paged.py)
+        out = _packed_attention(q, kc, vc, cache, cfg, hccs, hd,
+                                k_scales=ks, v_scales=vs)
+        return _project_out(out, p, b, t), new_cache
+    if cache is not None:
         with jax.named_scope("attn.kv_write"):
-            if per_slot:
+            if jnp.ndim(cache["length"]) > 0:
                 # continuous batching: every slot writes at its own frontier
                 kc = _slot_scatter(cache["k"], k, cache["length"])
                 vc = _slot_scatter(cache["v"], v, cache["length"])

@@ -1,7 +1,7 @@
 # engine.py     — wave scheduler: same-length prompt batches, lockstep decode
 # continuous.py — slot arena: continuous batching with per-slot lengths
 # paged.py      — block pool + block tables: paged KV with chunked prefill
-#                 (packed token steps by default; lockstep via packed=False)
+#                 in packed token steps (rows are tokens, not slots)
 # admission.py  — opt-in overload robustness: priority classes, deadlines,
 #                 bounded queue + backpressure, preemption policy
 # chaos.py      — seeded fault injector + engine invariant checker

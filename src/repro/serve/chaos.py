@@ -156,8 +156,7 @@ class ChaosMonkey:
         self._pending_raise = 0
         self._pending_nan = False
         self._orig = {}
-        for name in ("_step_fn", "_packed_fn", "_packed_spec_fn",
-                     "_packed_async_fn"):
+        for name in ("_packed_fn", "_packed_spec_fn", "_packed_async_fn"):
             self._orig[name] = getattr(eng, name)
             setattr(eng, name, self._wrap(self._orig[name],
                                           allow_nan=name != "_packed_spec_fn"))

@@ -41,7 +41,7 @@ returns a block to the free list when its last reference drops:
     each landing row grows the block scale monotonically to cover its amax
     and requantizes the existing payload by the old/new ratio, so the block
     bytes are a pure function of (row values, write order) — independent of
-    how steps partition the rows. That is what keeps packed vs lockstep,
+    how steps partition the rows. That is what keeps any token budget,
     sharing on/off, and session re-feeds BIT-IDENTICAL under quantization;
     only int8-vs-fp drift needs a tolerance gate (tests/test_kv_quant.py).
     COW copies carry payload AND scales (same bytes, same dequant); trie
@@ -61,24 +61,20 @@ crossing) and dereferenced at EOS (free-at-EOS). Block 0 is reserved as the
 slot's valid count still scatter somewhere — they are steered into block 0,
 which no request ever owns and every mask hides.
 
-Admission uses CHUNKED PREFILL: a long prompt is fed `block_size` tokens at a
-time inside the regular batched step — decoding slots ride along with
-t_valid = 1 — instead of the continuous engine's separate bucket-padded
-prefill call. That kills the O(log max_len) prefill retrace buckets: the
-engine compiles exactly two step shapes, (B, block_size) and (B, 1).
+Admission uses CHUNKED PREFILL: a long prompt is fed a chunk at a time
+inside the regular batched step — decoding slots ride along with one token
+each — instead of the continuous engine's separate bucket-padded prefill
+call. That kills the O(log max_len) prefill retrace buckets.
 
-PACKED TOKEN STEPS (packed=True, the default): the lockstep chunk layout
-above still pads every decode-riding slot to a full (block_size,) row — a
-step with one prefilling prompt and seven decoders burns 8 x 16 = 128 token
-lanes for 23 useful tokens. The packed step flattens the step's work into a
-RAGGED TOKEN BATCH instead (vLLM-v2 style): rows are tokens, not slots.
+PACKED TOKEN STEPS: every step flattens its work into a RAGGED TOKEN BATCH
+(vLLM-v2 style): rows are tokens, not slots, so a decode-riding slot costs
+one lane, not a padded chunk-wide row.
 
-    lockstep chunk step (B=4, bs=4)        packed step (budget T=8)
-    slot 0  p4 p5 p6 p7   ← prefilling     lane     0  1  2  3  4  5  6  7
-    slot 1  d  ░  ░  ░    ← decode rides   token   p4 p5 p6 p7 d  d  d  ░
-    slot 2  d  ░  ░  ░      with 3 pad     slot_id  0  0  0  0  1  2  3 -1
-    slot 3  d  ░  ░  ░      lanes each     pos      4  5  6  7  9  12 5  0
-    12/16 lanes wasted                     7/8 lanes useful
+    slot 0 prefilling p4..p7, slots 1-3 decoding      (budget T=8)
+    lane     0  1  2  3  4  5  6  7
+    token   p4 p5 p6 p7 d  d  d  ░
+    slot_id  0  0  0  0  1  2  3 -1
+    pos      4  5  6  7  9  12 5  0                  7/8 lanes useful
 
 The host packer emits (token, slot_id, position) triples padded to a fixed
 token budget: each live decode slot contributes exactly one token, each
@@ -96,8 +92,11 @@ _packed_attention). Each step runs at the
 smallest rung of a 4-entry chunk-width ladder (max_batch ... token_budget,
 default budget max_batch * block_size) that covers its pending work, so
 prompt tails and rider-dominated steps don't pad to the full budget — at
-most 4 traced shapes, still O(1). The lockstep layout stays available
-(packed=False) as the parity/benchmark baseline.
+most 4 traced shapes, still O(1). One host path builds every step's inputs
+(PagedEngine._build_packed_step), whether the step is synchronous, verifies
+drafts or is pipelined; one loop commits the synchronous and the pipelined
+steps' tokens (_commit_tokens), and every step tail commits a slot's token
+through _commit_slot.
 
 PREFIX SHARING (cfg.prefix_sharing / --prefix-sharing): as a request's
 prefill fills a block entirely with prompt tokens, the engine registers it
@@ -138,7 +137,7 @@ tests); sharing only makes it cheap. prefix_stats() splits the reuse
 telemetry into prompt_hits/decode_hits (and the matching token counters) so
 prompt-prefix reuse and decode-block reuse are separately visible.
 
-SPECULATIVE DECODING (cfg.speculative / --speculative, packed steps only):
+SPECULATIVE DECODING (cfg.speculative / --speculative):
 each decode step proposes up to draft_len tokens per decoding slot and
 verifies them ALL in one packed step — the packed layout already runs
 multi-token slots with per-token causal frontiers, so a verify step is just
@@ -186,18 +185,16 @@ conv and the SSD from the slot's carried state, and writes the new state
 back in place (models/ssm.py apply_ssd_packed). A slot with no token in the
 step passes its state through unchanged. A slot's state is a function of
 its whole history, which a block table cannot share or roll back, so prefix
-and decode sharing, speculation, preemption, the async loop, the lockstep
-layout, int8 KV and the fused kernels are refused for such configs (state
-snapshots at block boundaries would lift that). padding_stats() counts the
-slot-states the steps moved, those of slots with a token, and the state
-bytes read and written.
+and decode sharing, speculation, preemption, the async loop, int8 KV and
+the fused kernels are refused for such configs (state snapshots at block
+boundaries would lift that). padding_stats() counts the slot-states the
+steps moved, those of slots with a token, and the state bytes read and
+written.
 
 Attention dispatch (models/attention.py) keys off `block_table` in the cache:
-the XLA path gathers each slot's blocks into a contiguous view; with
-cfg.decode_kernel != "none" the t == 1 hot path runs the block-sparse Pallas
-kernel `hccs_paged_decode` (kernels/decode.py), whose KV BlockSpec index_map
-walks the scalar-prefetched block table directly — the gather steers the DMA
-and sentinel entries reuse the dead-block skip.
+a paged cache means a packed step. The XLA path gathers each slot's blocks
+into a contiguous view for its attention grids; with cfg.decode_kernel !=
+"none" every step runs the fused packed kernel instead (above).
 
 Admission is deadlock-free by reservation: a request is admitted only when
 the unreserved free-block count covers its worst case
@@ -235,7 +232,7 @@ alloc/COW journal), and preempts instead of crashing. serve/chaos.py is
 the seeded fault injector + invariant checker exercising all of it.
 
 PIPELINED ASYNC LOOP (cfg.async_loop / PagedEngine(async_loop=True) —
-strictly opt-in; packed layout only): the synchronous loop serializes
+strictly opt-in): the synchronous loop serializes
 [dispatch N → fence → commit N → dispatch N+1], leaving the device idle
 while the host samples, detects EOS, registers prefixes and runs
 telemetry. The async loop dispatches step N+1 BEFORE committing step N,
@@ -267,14 +264,15 @@ device side, so N+1 never reads a half-written pool):
     zeroing). Hot sampling and speculative drafting need landed values —
     those steps degrade to commit-then-sync-step (async_sync_fallbacks).
     Greedy outputs are token-identical with the loop on or off
-    (tests/test_async_loop.py runs the packed x sharing x int8 x
-    speculative parity matrix).
+    (tests/test_async_loop.py runs the sharing x int8 x speculative parity
+    matrix).
 
 When to prefer which engine: see the module docstrings of engine.py (wave)
 and continuous.py (slot arena), and ROADMAP.md "Serving architecture".
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -717,14 +715,6 @@ def pack_slot_ids(t_valid, width: int):
     return sid, off
 
 
-def _slot_write_targets(table_row, start: int, tv: int, bs: int):
-    """Flat pool positions for one slot's next tv tokens: token i lands at
-    table_row[(start+i)//bs] * bs + (start+i) % bs. The single source of the
-    block-addressing rule, shared by the lockstep and packed layouts."""
-    gpos = start + np.arange(tv)
-    return np.asarray(table_row)[gpos // bs].astype(np.int64) * bs + gpos % bs
-
-
 def packed_write_positions(t_valid, off, tables, lengths, block_size: int,
                            width: int):
     """Flat pool scatter targets (width,): lane off[b] + i of slot b lands at
@@ -737,8 +727,9 @@ def packed_write_positions(t_valid, off, tables, lengths, block_size: int,
     for slot in np.flatnonzero(np.asarray(t_valid) > 0):
         tv = int(t_valid[slot])
         o = int(off[slot])
-        wp[o:o + tv] = _slot_write_targets(tables[slot], int(lengths[slot]),
-                                           tv, bs)
+        gpos = int(lengths[slot]) + np.arange(tv)
+        wp[o:o + tv] = (tables[slot, gpos // bs].astype(np.int64) * bs
+                        + gpos % bs)
     return wp.astype(np.int32)
 
 
@@ -826,6 +817,32 @@ def grid_steering(t_valid, off, sid, lengths, grid_widths, ssd=False):
                                + np.arange(wb)[None, :]).astype(np.int32),
                    kv_len_slot=(lengths + t_valid).astype(np.int32))
     return out
+
+
+@dataclasses.dataclass
+class PackedStep:
+    """One packed step's host-built inputs (PagedEngine._build_packed_step):
+    what the device call takes — `toks`, `positions`, `tok_src` (the
+    pipelined loop's token indirection, None elsewhere), `lane_idx` (each
+    slot's last lane, its sampling lane) and `extras` — and what the step's
+    tail reads: the scheduled slots `live`, each slot's feed tokens left to
+    prefill before the step (`remaining`, 0 for a decoding slot), its lane
+    count and first lane (`t_valid`, `off`), the lanes' flat pool write
+    targets `wp`, and a speculative step's `drafts` ({slot: draft
+    tokens}), its draft-only block allocations and its fresh-block list."""
+    live: np.ndarray
+    remaining: np.ndarray
+    t_valid: np.ndarray
+    off: np.ndarray
+    toks: np.ndarray
+    positions: np.ndarray
+    tok_src: np.ndarray | None
+    lane_idx: np.ndarray
+    wp: np.ndarray
+    extras: dict
+    drafts: dict
+    draft_allocs: list
+    fresh: np.ndarray | None
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -999,16 +1016,14 @@ class PagedEngine:
         self.occupancy_sum = 0.0
         self.occupancy_steps = 0
 
-        # packed token steps (the default): rows are tokens, not slots —
-        # chunk size is budget-driven, decode slots cost one lane each.
-        # packed=False keeps the lockstep (B, block_size)/(B, 1) layout as
-        # the parity/benchmark baseline. The default budget matches one
-        # lockstep chunk step's lane count (max_batch * block_size): any
-        # lockstep step's work fits in one packed step, so the packed step
-        # COUNT never exceeds lockstep's (per-step dispatch overhead is the
-        # other half of the padding tax) while ragged packing keeps the
-        # lanes that lockstep would pad doing useful prefill work instead.
-        self.packed = True if packed is None else bool(packed)
+        # every step is a packed token step: rows are tokens, not slots —
+        # chunk size is budget-driven, decode slots cost one lane each. The
+        # default budget, max_batch * block_size, holds a block of tokens
+        # per slot. `packed` is still accepted for callers that pass it.
+        if packed is not None and not packed:
+            raise ValueError(
+                "packed=False: the lockstep step layout was removed; the "
+                "packed token step serves every config")
         budget = (int(token_budget) if token_budget
                   else max_batch * bs)
         if budget < max_batch:
@@ -1038,14 +1053,12 @@ class PagedEngine:
         self._widths = sorted({max_batch, max(budget // 4, max_batch),
                                max(budget // 2, max_batch), budget})
         # attention-grid width ladder: the XLA packed path runs its attention
-        # core on a (B, Wb) per-slot grid (models/attention.py
+        # core on a (P, Wb) per-slot chunk grid (models/attention.py
         # _packed_attention) where Wb buckets this step's max per-slot chunk
-        # — 1 for pure decode (the lockstep decode shape), exact block_size
-        # multiples otherwise. Per-slot chunks are capped at 4 blocks so a
-        # long prompt can neither monopolize the step nor blow the grid up
-        # for every rider's row (grid rounding waste stays < one block/slot,
-        # same as lockstep's ragged final chunk) while chunk steps still
-        # prefill 4x the tokens a lockstep step can.
+        # — 1 for pure decode, exact block_size multiples otherwise.
+        # Per-slot chunks are capped at 4 blocks so a long prompt can
+        # neither monopolize the step nor blow the grid up for every other
+        # chunk's row (grid rounding waste stays < one block/slot).
         self._chunk_cap = min(4 * bs, budget)
         # trie-driven speculative decoding (module docstring): decode slots
         # draft up to draft_len tokens per step, verified in one packed step
@@ -1056,11 +1069,6 @@ class PagedEngine:
         if self.draft_len < 1:
             raise ValueError(
                 f"draft_len must be >= 1, got {self.draft_len}")
-        if self.speculative and not self.packed:
-            raise ValueError(
-                "speculative decoding verifies all drafts in one packed "
-                "step; it requires packed=True (the lockstep layout has no "
-                "multi-token decode lanes)")
         if self.speculative:
             # verify lanes ride on top of a pure-decode step's max_batch
             # lanes, so give the ladder a 2*max_batch rung: without it a
@@ -1098,16 +1106,9 @@ class PagedEngine:
         self._ssd_grid = "mamba" in (cfg.layer_types or ())
         # pipelined async loop (module docstring, "Pipelined async loop"):
         # dispatch step N+1 while step N's tokens are still in flight, with
-        # host commit running one step behind. Opt-in; packed-only (the
-        # lockstep layout is the parity baseline and stays strictly
-        # synchronous).
+        # host commit running one step behind. Opt-in.
         self.async_loop = bool(cfg.async_loop if async_loop is None
                                else async_loop)
-        if self.async_loop and not self.packed:
-            raise ValueError(
-                "async_loop pipelines the packed token step; it requires "
-                "packed=True (the lockstep layout is the synchronous "
-                "parity baseline)")
         # the in-flight packed step awaiting host commit (one deep — JAX
         # queues the dispatch, the donated pool serializes execution):
         # None, or the dict _dispatch_packed_async builds. See
@@ -1118,13 +1119,9 @@ class PagedEngine:
         # (hot sampling / speculative drafting need landed tokens)
         self.async_overlapped_steps = 0
         self.async_sync_fallbacks = 0
-        # token-lane telemetry: padding efficiency is lanes_valid/lanes_total;
-        # pad_lanes_skipped estimates the lanes the lockstep layout would
-        # have burned for the same steps (packing's analogue of the prefix
-        # index's prefill_tokens_skipped)
+        # token-lane telemetry: padding efficiency is lanes_valid/lanes_total
         self.lanes_valid = 0
         self.lanes_total = 0
-        self.pad_lanes_skipped = 0
         # query rows of the XLA packed step's attention grids (B decode
         # rows plus P*Wb chunk rows a step): lanes_valid / grid_rows is how
         # much of the attention core's work serves a token
@@ -1172,7 +1169,6 @@ class PagedEngine:
                 ("speculative", self.speculative),
                 ("preemption", self._robust and self._adm.preemption),
                 ("async_loop", self.async_loop),
-                ("packed=False", not self.packed),
                 ('kv_quant="int8"', self.quantized),
                 (f"decode_kernel={cfg.decode_kernel!r}",
                  cfg.decode_kernel != "none")) if on]
@@ -1181,9 +1177,8 @@ class PagedEngine:
                     f"{cfg.name} carries recurrent state per slot, which "
                     f"{', '.join(refused)} cannot keep: sharing, rollback "
                     "and preemption need state snapshots at block "
-                    "boundaries, and the async loop, the lockstep layout, "
-                    "int8 KV and the fused kernels are not built for the "
-                    "state arena")
+                    "boundaries, and the async loop, int8 KV and the fused "
+                    "kernels are not built for the state arena")
 
         # multi-turn sessions: submit(req, session=sid) prepends the stored
         # history (prompt + generated tokens of every prior turn) to the
@@ -1244,33 +1239,17 @@ class PagedEngine:
 
         cfg_ = cfg
 
-        # ONE step function, two traced shapes — (B, 1) pure decode and
-        # (B, block_size) chunk steps. Only the pool cache is donated (so XLA
-        # aliases it in place); the per-step steering arrays (block table,
-        # write targets, kv_len) ride in a separate undonated arg
-        @functools.partial(jax.jit, donate_argnums=(3,))
-        def _step(w, hccs, tokens, cache, extras, t_valid):
-            x, cache, _ = M.forward(w, hccs, {"tokens": tokens}, cfg_,
-                                    cache=dict(cache, **extras))
-            # each slot samples from its LAST VALID position (t_valid - 1):
-            # chunk steps are ragged — riding decode slots have t_valid == 1,
-            # mid-prompt slots discard their logits entirely
-            idx = jnp.maximum(t_valid - 1, 0)
-            h_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
-            logits = M.logits_from_hidden(w, h_last, cfg_)
-            return logits[:, 0], cache
-
-        self._step_fn = _step
-
         # packed token step: tokens ride the sequence axis of a batch-of-one
         # forward, steered by slot_ids / per-token positions / per-token
-        # kv_len. One traced shape per (chunk width, grid width, chunk rows)
-        # triple the traffic actually hits — the ladders are O(1)-sized and
-        # the chunk rows' rungs O(log max_batch), so the trace count is
-        # bounded, but callers timing steps must warm every shape their
-        # workload reaches (see the double warm-up note in
-        # benchmarks/serving_throughput.py). lane_idx picks each slot's
-        # LAST packed lane for sampling.
+        # kv_len. Only the pool cache is donated (so XLA aliases it in
+        # place); the per-step steering arrays (block table, write targets,
+        # kv_len, grids) ride in a separate undonated arg. One traced shape
+        # per (chunk width, grid width, chunk rows) triple the traffic
+        # actually hits — the ladders are O(1)-sized and the chunk rows'
+        # rungs O(log max_batch), so the trace count is bounded, but callers
+        # timing steps must warm every shape their workload reaches (see the
+        # double warm-up note in benchmarks/serving_throughput.py). lane_idx
+        # picks each slot's LAST packed lane for sampling.
         @functools.partial(jax.jit, donate_argnums=(4,))
         def _packed(w, hccs, tokens, positions, cache, extras, lane_idx):
             x, cache, _ = M.forward(
@@ -1592,8 +1571,7 @@ class PagedEngine:
         session's follow-up turn matches straight through prior replies."""
         return self.trie.match(prompt)
 
-    def _register_blocks(self, slot: int, req: Request,
-                         covered: int | None = None):
+    def _register_blocks(self, slot: int, req: Request, covered: int):
         """Index every block of this slot now FULLY covered by tokens whose
         values are known (frontier-crossing insertion). Without decode
         sharing that is the prompt-covered prefix; with it, the whole
@@ -1611,19 +1589,17 @@ class PagedEngine:
         chain stays rooted in index blocks even when this slot's table
         holds a COW copy or a duplicate).
 
-        `covered` overrides the written-token count to register up to: the
-        async loop commits one step BEHIND dispatch, so at commit time the
-        slot's _lengths/_prompt_pos already include the NEXT in-flight
-        step's frontier advance, whose token values are not landed yet —
-        the commit passes the pending step's own post-step coverage
-        instead. The in-flight step only writes rows at or past that
-        coverage, so no registered (hence shared-refcount) block is ever a
-        write target of the step racing this registration."""
+        `covered` is the written-token count to register up to: the
+        slot's post-step length with decode sharing, else its prompt
+        coverage. The async loop commits one step BEHIND dispatch, so at
+        commit time the slot's _lengths/_prompt_pos already include the
+        NEXT in-flight step's frontier advance, whose token values are not
+        landed yet — the commit passes the pending step's own post-step
+        coverage instead. The in-flight step only writes rows at or past
+        that coverage, so no registered (hence shared-refcount) block is
+        ever a write target of the step racing this registration."""
         bs = self.block_size
         plen = len(req.prompt)
-        if covered is None:
-            covered = (int(self._lengths[slot]) if self.decode_sharing
-                       else min(int(self._prompt_pos[slot]), plen))
         n_levels = covered // bs
         parent = int(self._reg_parent[slot])
         for j in range(int(self._reg_level[slot]), n_levels):
@@ -1712,11 +1688,7 @@ class PagedEngine:
         the decode frontier (multi-turn sessions re-matching prior replies)
         — `hits` stays the per-request union. followup_* restrict the
         token counters to session follow-up turns (the multi-turn acceptance
-        metric). pad_lanes_skipped is the OTHER prefill saving — token
-        lanes the packed step avoided versus the lockstep layout (zero with
-        packed=False) — reported here so the two are distinguishable in the
-        same printout: prefix sharing skips real prefill FLOPs, packing
-        skips padding FLOPs. The spec_* / *_tokens draft counters cover
+        metric). The spec_* / *_tokens draft counters cover
         trie-driven speculative decoding (drafted = accepted + rejected per
         verify step; acceptance_rate is None until something was drafted —
         launchers and benchmarks must guard the mid-run/empty case)."""
@@ -1745,23 +1717,20 @@ class PagedEngine:
             cow_copies=self.cow_copies, evictions=self.prefix_evictions,
             cached_blocks=len(self.trie),
             cached_prompt_blocks=cached["prompt"],
-            cached_decode_blocks=cached["decode"],
-            pad_lanes_skipped=self.pad_lanes_skipped)
+            cached_decode_blocks=cached["decode"])
 
     def padding_stats(self) -> dict:
         """Token-lane telemetry: efficiency = valid lanes / padded lanes over
-        every step so far (the packing win the benchmark records), plus the
-        estimated lanes the lockstep layout would have burned extra, and the
+        every step so far (the packing win the benchmark records), the
         state arena's traffic: slot-states read and written (every slot of
         each state-carrying layer kind, per step), those of slots with a
         token in the step, and the bytes of SSM and conv state read plus
         written (all zero without recurrent state), and the query rows of
         the XLA packed step's attention grids (zero on the fused kernels'
-        path and the lockstep layout)."""
+        path)."""
         return dict(lanes_valid=self.lanes_valid,
                     lanes_total=self.lanes_total,
                     efficiency=self.lanes_valid / max(self.lanes_total, 1),
-                    pad_lanes_skipped=self.pad_lanes_skipped,
                     grid_rows=self.grid_rows,
                     ctx_positions=self.ctx_positions,
                     sampled_lanes=self.sampled_lanes,
@@ -2013,105 +1982,25 @@ class PagedEngine:
                 drafts[slot] = d
         return drafts
 
-    def _write_positions(self, t_valid: np.ndarray, width: int) -> np.ndarray:
-        """Flat pool scatter targets (B, width): token i of slot b lands at
-        table[b, (len+i)//bs]*bs + (len+i)%bs while i < t_valid[b]; invalid
-        lanes are steered into the trash block (position i of block 0).
-
-        The per-slot length is also the partial-prefill start offset under
-        prefix sharing: a slot admitted with `start` matched tokens begins
-        with _lengths[slot] == start, so both the write targets here and the
-        RoPE positions in attention.py (cache["length"] + arange(t)) resume
-        exactly past the shared frontier. _cow_shared ran before this, so no
-        target block has refcount > 1."""
-        bs = self.block_size
-        wp = np.tile(np.arange(width, dtype=np.int64)[None, :],
-                     (self.max_batch, 1)) + TRASH_BLOCK * bs
-        for slot in np.flatnonzero(t_valid > 0):
-            tv = int(t_valid[slot])
-            wp[slot, :tv] = _slot_write_targets(
-                self._tables[slot], int(self._lengths[slot]), tv, bs)
-        return wp.astype(np.int32)
-
-    def _step(self, width: int) -> list[Request]:
-        """One lockstep batched step: chunk (width == block_size, some slot
-        is mid-prompt) or pure decode (width == 1). Returns newly finished."""
-        prof = self.telemetry.profiler
-        live = self._live.copy()
-        self.occupancy_sum += float(live.mean())
-        self.occupancy_steps += 1
-        with prof.phase("schedule"):
-            t_valid = np.zeros(self.max_batch, np.int32)
-            toks = np.zeros((self.max_batch, width), np.int32)
-            for slot in np.flatnonzero(live):
-                feed = self._feeds[slot]
-                pos = int(self._prompt_pos[slot])
-                if pos < len(feed):          # chunked prefill
-                    tv = min(width, len(feed) - pos)
-                    toks[slot, :tv] = feed[pos:pos + tv]
-                    t_valid[slot] = tv
-                else:                        # decode rides along, t_valid 1
-                    toks[slot, 0] = self._last[slot]
-                    t_valid[slot] = 1
-            self.lanes_valid += int(t_valid.sum())
-            self.lanes_total += self.max_batch * width
-            tv = t_valid.astype(np.int64)
-            self.ctx_positions += int((tv * self._lengths
-                                       + tv * (tv + 1) // 2).sum())
-        with prof.phase("alloc_cow"):
-            journal: list[tuple] = []
-            try:
-                self._grow_tables(t_valid, journal)
-                if self.prefix_sharing:
-                    self._cow_shared(t_valid, journal)
-            except BlockPoolExhausted:
-                self._unwind_allocs(journal)
-                raise
-        with prof.phase("schedule"):
-            # dirty-tracked device mirrors: _tables only changes when a
-            # frontier crosses a block boundary (every block_size tokens),
-            # so most decode steps re-use the uploaded copy instead of
-            # transferring the whole (B, nblk) table again
-            # the mirrors must ride in `extras` (undonated): the cache
-            # argument is donated, so a mirror passed inside it would have
-            # its buffer invalidated after the step. extras merge AFTER the
-            # cache inside the jitted fn, so "length" here overrides the
-            # stale length the previous step's returned cache carries.
-            cache = self._cache
-            extras = {"length": self._device_lengths(),
-                      "block_table": self._device_tables(),
-                      "write_pos": jnp.asarray(
-                          self._write_positions(t_valid, width)),
-                      "kv_len": jnp.asarray(self._lengths + t_valid)}
-            if self.quantized:
-                extras["fresh_blocks"] = jnp.asarray(self._take_fresh())
-        with prof.phase("device"):
-            logits, self._cache = self._call_device(
-                self._step_fn, self.w, self.hccs, jnp.asarray(toks), cache,
-                extras, jnp.asarray(t_valid))
-            if prof.enabled:
-                # fence async dispatch so device time lands in THIS phase
-                # instead of smearing into the host phases that follow
-                jax.block_until_ready(logits)
-        return self._sample_and_finish(live, t_valid, logits)
-
-    def _step_packed(self) -> list[Request]:
-        """One PACKED engine step — dispatches to the synchronous tail
-        (default) or the pipelined async loop (cfg.async_loop; see the
-        module docstring's pipeline diagram)."""
-        if self.async_loop:
-            return self._step_packed_async()
-        return self._step_packed_sync()
-
-    def _step_packed_sync(self) -> list[Request]:
-        """One PACKED token step: the step's work — a chunk of any length per
-        prefilling slot plus one token per decoding slot — flattened into a
-        ragged (1, width) token batch with per-token slot ids, positions and
-        causal frontiers. width is the smallest rung of the chunk-width
+    def _build_packed_step(self, live, pending=None) -> PackedStep:
+        """Build one packed step's inputs: the one host path the
+        synchronous, speculative and pipelined steps share. The step's work
+        — a chunk of any length per prefilling slot, one token per decoding
+        slot, plus its verify lanes when the slot drafts — is flattened into
+        a ragged (1, width) token batch with per-token slot ids, positions
+        and causal frontiers. width is the smallest rung of the chunk-width
         ladder covering the step's pending work (capped at token_budget);
-        pure decode lands on the max_batch rung. Returns newly finished."""
+        pure decode lands on the max_batch rung. Counts the step's work
+        (padding_stats), grows the block tables under a journal — unwound,
+        state exactly pre-step, before BlockPoolExhausted propagates — and
+        drains the fresh-block list.
+
+        Speculative engines propose drafts here; the pipelined loop never
+        builds a drafting step (it falls back to the synchronous one).
+        `pending` is the pipelined loop's in-flight record: a decode lane of
+        a slot it samples reads that sample on the device (`tok_src`)
+        instead of a host token."""
         prof = self.telemetry.profiler
-        live = self._live.copy()
         self.occupancy_sum += float(live.mean())
         self.occupancy_steps += 1
         with prof.phase("schedule"):
@@ -2167,12 +2056,19 @@ class PagedEngine:
             sid, off = pack_slot_ids(t_valid, width)
             toks = np.zeros(width, np.int32)
             positions = np.zeros(width, np.int32)
+            # the pipelined loop's decode-lane token indirection:
+            # tok_src[lane] = slot id whose token is the in-flight step's
+            # device sample (still unlanded on the host); -1 = host-fed
+            tok_src = np.full(width, -1, np.int32) if self.async_loop else None
             for slot in np.flatnonzero(t_valid > 0):
                 tv = int(t_valid[slot])
                 o = int(off[slot])
                 if remaining[slot] > 0:      # prefill chunk (budget-sized)
                     pos = int(self._prompt_pos[slot])
                     toks[o:o + tv] = self._feeds[slot][pos:pos + tv]
+                elif (pending is not None and pending["samples"][slot]
+                        and not pending["dead"][slot]):
+                    tok_src[o] = slot        # feed step N's device sample
                 else:                        # decode: one lane (+ drafts)
                     toks[o] = self._last[slot]
                     if tv > 1:
@@ -2185,20 +2081,6 @@ class PagedEngine:
                 self.state_slots_moved += self.max_batch
                 self.state_slots_live += int((t_valid > 0).sum())
                 self.state_bytes += self._state_step_bytes
-            # lanes the lockstep layout would burn for the SAME scheduled
-            # work: it caps each slot at block_size tokens per chunk step, so
-            # this step's largest per-slot chunk takes ceil(max tv / bs)
-            # lockstep steps of max_batch * block_size lanes each. Those
-            # extra lockstep steps would ALSO advance every decode rider by
-            # one token each — progress this packed step has not made — so
-            # credit the riders one future packed decode lane per extra step
-            # (decode-only steps themselves save nothing).
-            if (remaining > 0).any():
-                n_lockstep = -(-int(t_valid.max()) // self.block_size)
-                riders = int((live & (remaining == 0)).sum())
-                lockstep = n_lockstep * self.max_batch * self.block_size
-                self.pad_lanes_skipped += max(
-                    lockstep - width - (n_lockstep - 1) * riders, 0)
         with prof.phase("alloc_cow"):
             journal: list[tuple] = []
             try:
@@ -2233,64 +2115,90 @@ class PagedEngine:
             kv_len = np.where(sid >= 0, positions + 1, 0).astype(np.int32)
             self.ctx_positions += int(kv_len.sum())
             lane_idx = np.maximum(off + t_valid - 1, 0).astype(np.int32)
-            # dirty-tracked device mirrors (see _step): skip the per-step
-            # _tables/_lengths re-upload when the host copies are unchanged
-            # mirrors ride in `extras` (undonated; see _step) — the donated
-            # cache arg would invalidate them after the step
-            cache = self._cache
+            # dirty-tracked device mirrors: _tables only changes when a
+            # frontier crosses a block boundary (every block_size tokens),
+            # so most decode steps re-use the uploaded copy instead of
+            # transferring the whole (B, nblk) table again. The mirrors
+            # must ride in `extras` (undonated): the cache argument is
+            # donated, so a mirror passed inside it would have its buffer
+            # invalidated after the step. extras merge AFTER the cache
+            # inside the jitted fn, so "length" here overrides the stale
+            # length the previous step's returned cache carries.
             extras = {"length": self._device_lengths(),
                       "block_table": self._device_tables(),
                       "write_pos": jnp.asarray(wp[None]),
                       "kv_len": jnp.asarray(kv_len),
                       "slot_ids": jnp.asarray(sid)}
-            fresh_np = None
+            fresh = None
             if self.quantized:
-                fresh_np = self._take_fresh()
-                extras["fresh_blocks"] = jnp.asarray(fresh_np)
-            snap_blocks = snap = staged = None
-            if drafts and self.quantized:
-                # pre-step snapshot of every block the drafting slots'
-                # verify rows can touch: draft lanes fold with a CLAMPED
-                # scale (draft_rows -> paged_quant_scatter), so committed
-                # lanes read bit-exact history, and after verification the
-                # snapshot is restored and exactly the committed rows are
-                # re-folded grow-wise (_restore_and_replay). stage_rows
-                # makes each layer emit its raw KV rows for that replay.
-                bs = self.block_size
-                blks = []
-                for slot in sorted(drafts):
-                    lo = int(self._lengths[slot])
-                    hi = lo + int(t_valid[slot])
-                    blks.extend(int(self._tables[slot, j])
-                                for j in range(lo // bs, -(-hi // bs)))
-                snap_blocks = np.full(self._snap_cap, TRASH_BLOCK, np.int32)
-                snap_blocks[:len(blks)] = blks
-                extras["stage_rows"] = jnp.zeros((), jnp.int32)
-                draft_rows = np.zeros(width, bool)
-                for slot in drafts:
-                    draft_rows[off[slot] + 1:off[slot]
-                               + int(t_valid[slot])] = True
-                extras["draft_rows"] = jnp.asarray(draft_rows[None])
+                fresh = self._take_fresh()
+                extras["fresh_blocks"] = jnp.asarray(fresh)
             if self._use_grid:
-                extras.update(self._grid_extras(t_valid, off, sid))
+                g = grid_steering(t_valid, off, sid, self._lengths,
+                                  self._grid_widths, self._ssd_grid)
+                # the attention grids' query rows (padding_stats)
+                self.grid_rows += self.max_batch + (
+                    g["chunk_q_pos"].size if "chunk_q_pos" in g else 0)
+                extras.update({k: jnp.asarray(v) for k, v in g.items()})
+        return PackedStep(live=live, remaining=remaining, t_valid=t_valid,
+                          off=off, toks=toks, positions=positions,
+                          tok_src=tok_src, lane_idx=lane_idx, wp=wp,
+                          extras=extras, drafts=drafts,
+                          draft_allocs=draft_allocs, fresh=fresh)
+
+    def _advance_frontiers(self, s: PackedStep) -> dict:
+        """Advance each scheduled slot's frontiers past its lanes of step
+        `s`; returns what the commit reads of them (_commit_tokens): which
+        slots were prefilling, and the post-step `lengths_after` and
+        `prompt_pos_after` — the live arrays themselves, which the
+        pipelined loop's record copies."""
+        self._lengths_dev = None
+        self._lengths += s.t_valid
+        self._prompt_pos += np.minimum(s.t_valid, s.remaining).astype(
+            np.int32)
+        return {"was_prefill": s.live & (s.remaining > 0),
+                "lengths_after": self._lengths,
+                "prompt_pos_after": self._prompt_pos}
+
+    def _step_packed(self) -> list[Request]:
+        """One PACKED engine step — dispatches to the synchronous tail
+        (default) or the pipelined async loop (cfg.async_loop; see the
+        module docstring's pipeline diagram)."""
+        if self.async_loop:
+            return self._step_packed_async()
+        return self._step_packed_sync()
+
+    def _step_packed_sync(self) -> list[Request]:
+        """One synchronous packed step: build it, run it and commit its
+        tokens — or, when slots drafted, verify the drafts and roll the
+        rejected lanes back (_verify_and_finish). Returns newly
+        finished."""
+        prof = self.telemetry.profiler
+        s = self._build_packed_step(self._live.copy())
+        snap_blocks = snap = staged = None
+        if s.drafts:
+            with prof.phase("schedule"):
+                # verify lanes: row i of a drafting slot is its i-th packed
+                # lane (clamped to its last); non-drafting slots repeat
+                # their sampling lane across the row
+                lane_grid = np.tile(s.lane_idx[:, None],
+                                    (1, self.draft_len + 1))
+                for slot in s.drafts:
+                    lane_grid[slot] = s.off[slot] + np.minimum(
+                        np.arange(self.draft_len + 1),
+                        int(s.t_valid[slot]) - 1)
+                if self.quantized:
+                    snap_blocks = self._stage_rollback(s)
         with prof.phase("device"):
             if snap_blocks is not None:
                 snap = _gather_block_state(self._cache["layers"],
                                            jnp.asarray(snap_blocks))
-            if drafts:
-                # verify lanes: row i of a drafting slot is its i-th packed
-                # lane (clamped to its last); non-drafting slots repeat
-                # their sampling lane across the row
-                lane_grid = np.tile(lane_idx[:, None],
-                                    (1, self.draft_len + 1))
-                for slot in drafts:
-                    lane_grid[slot] = off[slot] + np.minimum(
-                        np.arange(self.draft_len + 1),
-                        int(t_valid[slot]) - 1)
+            if s.drafts:
                 logits, self._cache = self._call_device(
                     self._packed_spec_fn, self.w, self.hccs,
-                    jnp.asarray(toks[None]), jnp.asarray(positions[None]),
-                    cache, extras, jnp.asarray(lane_grid.astype(np.int32)))
+                    jnp.asarray(s.toks[None]), jnp.asarray(s.positions[None]),
+                    self._cache, s.extras,
+                    jnp.asarray(lane_grid.astype(np.int32)))
                 if self.quantized:
                     layers = dict(self._cache["layers"])
                     staged = (layers.pop("staged_k"),
@@ -2299,27 +2207,41 @@ class PagedEngine:
             else:
                 logits, self._cache = self._call_device(
                     self._packed_fn, self.w, self.hccs,
-                    jnp.asarray(toks[None]), jnp.asarray(positions[None]),
-                    cache, extras, jnp.asarray(lane_idx))
+                    jnp.asarray(s.toks[None]), jnp.asarray(s.positions[None]),
+                    self._cache, s.extras, jnp.asarray(s.lane_idx))
             if prof.enabled:
                 # fence async dispatch so device time lands in THIS phase
                 # instead of smearing into the host phases that follow
                 jax.block_until_ready(logits)
-        if drafts:
-            return self._verify_and_finish(live, t_valid, drafts, off, wp,
-                                           logits, draft_allocs,
-                                           snap_blocks, snap, staged,
-                                           fresh_np)
-        return self._sample_and_finish(live, t_valid, logits)
+        if s.drafts:
+            return self._verify_and_finish(s, logits, snap_blocks, snap,
+                                           staged)
+        return self._sample_and_finish(s, logits)
 
-    def _grid_extras(self, t_valid, off, sid) -> dict:
-        """This packed step's grid_steering arrays on the device, counting
-        the attention grids' query rows (padding_stats' grid_rows)."""
-        g = grid_steering(t_valid, off, sid, self._lengths,
-                          self._grid_widths, self._ssd_grid)
-        self.grid_rows += self.max_batch + (g["chunk_q_pos"].size
-                                            if "chunk_q_pos" in g else 0)
-        return {k: jnp.asarray(v) for k, v in g.items()}
+    def _stage_rollback(self, s: PackedStep) -> np.ndarray:
+        """An int8 verify step's rollback inputs: the pre-step snapshot
+        list of every block the drafting slots' verify rows can touch, and
+        two extras — draft lanes fold with a CLAMPED scale (draft_rows ->
+        paged_quant_scatter), so committed lanes read bit-exact history,
+        and stage_rows makes each layer emit its raw KV rows. After
+        verification the snapshot is restored and exactly the committed
+        rows are re-folded grow-wise (_restore_and_replay)."""
+        bs = self.block_size
+        blks = []
+        for slot in sorted(s.drafts):
+            lo = int(self._lengths[slot])
+            hi = lo + int(s.t_valid[slot])
+            blks.extend(int(self._tables[slot, j])
+                        for j in range(lo // bs, -(-hi // bs)))
+        snap_blocks = np.full(self._snap_cap, TRASH_BLOCK, np.int32)
+        snap_blocks[:len(blks)] = blks
+        draft_rows = np.zeros(s.toks.shape[0], bool)
+        for slot in s.drafts:
+            o = int(s.off[slot])
+            draft_rows[o + 1:o + int(s.t_valid[slot])] = True
+        s.extras["stage_rows"] = jnp.zeros((), jnp.int32)
+        s.extras["draft_rows"] = jnp.asarray(draft_rows[None])
+        return snap_blocks
 
     # ------------------------------------------- pipelined async loop --
 
@@ -2381,117 +2303,34 @@ class PagedEngine:
         return self._commit_pending_record(old)
 
     def _dispatch_packed_async(self, live) -> dict:
-        """Schedule + allocate + enqueue one packed step WITHOUT waiting for
-        its results: the greedy-sampling clone of _step_packed_sync's front
-        half (no drafts by construction — the caller falls back when
-        speculation is on). Advances the host frontiers (_lengths /
-        _prompt_pos) at dispatch so the NEXT dispatch schedules against the
-        post-step state, and returns the pending record _commit_pending
-        lands one step later. Raises BlockPoolExhausted (journal unwound,
-        state exactly pre-dispatch) like the sync path."""
+        """Build and enqueue one packed step WITHOUT waiting for its
+        results. Advances the host frontiers (_lengths / _prompt_pos) at
+        dispatch so the NEXT dispatch schedules against the post-step
+        state, and returns the pending record _commit_pending lands one
+        step later. Raises BlockPoolExhausted (journal unwound, state
+        exactly pre-dispatch) like the sync path."""
         prof = self.telemetry.profiler
-        self.occupancy_sum += float(live.mean())
-        self.occupancy_steps += 1
         p = self._pending
-        with prof.phase("schedule"):
-            remaining = np.zeros(self.max_batch, np.int64)
-            for slot in np.flatnonzero(live):
-                remaining[slot] = (len(self._feeds[slot])
-                                   - int(self._prompt_pos[slot]))
-            needed = int(np.where(
-                live, np.minimum(np.maximum(remaining, 1), self._chunk_cap),
-                0).sum())
-            needed = min(needed, self.token_budget)
-            width = next(w for w in self._widths if w >= needed)
-            t_valid = schedule_step_tokens(live, remaining, width,
-                                           self._chunk_cap)
-            if self._use_grid:
-                t_valid, width = fit_chunk_grid(
-                    t_valid, remaining > 0, width, self._widths,
-                    self._grid_widths)
-            sid, off = pack_slot_ids(t_valid, width)
-            toks = np.zeros(width, np.int32)
-            # decode-lane token indirection: tok_src[lane] = slot id whose
-            # token must be read from the in-flight step's device sample
-            # (still unlanded on the host); -1 = host-fed from toks
-            tok_src = np.full(width, -1, np.int32)
-            positions = np.zeros(width, np.int32)
-            for slot in np.flatnonzero(t_valid > 0):
-                tv = int(t_valid[slot])
-                o = int(off[slot])
-                if remaining[slot] > 0:      # prefill chunk (host tokens)
-                    pos = int(self._prompt_pos[slot])
-                    toks[o:o + tv] = self._feeds[slot][pos:pos + tv]
-                elif (p is not None and p["samples"][slot]
-                        and not p["dead"][slot]):
-                    tok_src[o] = slot        # feed step N's device sample
-                else:
-                    toks[o] = self._last[slot]
-                positions[o:o + tv] = (int(self._lengths[slot])
-                                       + np.arange(tv))
-            self.lanes_valid += int(t_valid.sum())
-            self.lanes_total += width
-            if (remaining > 0).any():        # see _step_packed_sync
-                n_lockstep = -(-int(t_valid.max()) // self.block_size)
-                riders = int((live & (remaining == 0)).sum())
-                lockstep = n_lockstep * self.max_batch * self.block_size
-                self.pad_lanes_skipped += max(
-                    lockstep - width - (n_lockstep - 1) * riders, 0)
-        with prof.phase("alloc_cow"):
-            journal: list[tuple] = []
-            try:
-                self._grow_tables(t_valid, journal)
-                if self.prefix_sharing:
-                    self._cow_shared(t_valid, journal)
-            except BlockPoolExhausted:
-                self._unwind_allocs(journal)
-                raise
-        with prof.phase("schedule"):
-            wp = packed_write_positions(t_valid, off, self._tables,
-                                        self._lengths, self.block_size,
-                                        width)
-            kv_len = np.where(sid >= 0, positions + 1, 0).astype(np.int32)
-            self.ctx_positions += int(kv_len.sum())
-            lane_idx = np.maximum(off + t_valid - 1, 0).astype(np.int32)
-            # mirrors ride in `extras` (undonated; see _step)
-            cache = self._cache
-            extras = {"length": self._device_lengths(),
-                      "block_table": self._device_tables(),
-                      "write_pos": jnp.asarray(wp[None]),
-                      "kv_len": jnp.asarray(kv_len),
-                      "slot_ids": jnp.asarray(sid)}
-            if self.quantized:
-                extras["fresh_blocks"] = jnp.asarray(self._take_fresh())
-            if self._use_grid:
-                extras.update(self._grid_extras(t_valid, off, sid))
-            prev = (p["sampled"] if p is not None
-                    else self._no_pending_tokens)
+        s = self._build_packed_step(live, pending=p)
         with prof.phase("device"):
             # NO fence here — landing results is _commit_pending's job, one
             # step later; this enqueue returns as soon as XLA accepts it
             logits, sampled, self._cache = self._call_device(
                 self._packed_async_fn, self.w, self.hccs,
-                jnp.asarray(toks[None]), jnp.asarray(tok_src), prev,
-                jnp.asarray(positions[None]), cache, extras,
-                jnp.asarray(lane_idx))
+                jnp.asarray(s.toks[None]), jnp.asarray(s.tok_src),
+                p["sampled"] if p is not None else self._no_pending_tokens,
+                jnp.asarray(s.positions[None]), self._cache, s.extras,
+                jnp.asarray(s.lane_idx))
         with prof.phase("sample"):
             # frontier advance AT DISPATCH (the commit reads the record's
             # snapshots, never the advanced arrays)
-            feed_len = np.asarray([len(f) if f is not None else 1 << 30
-                                   for f in self._feeds])
-            samples = live & (self._prompt_pos + t_valid >= feed_len)
+            samples = live & (s.t_valid >= s.remaining)
             self.sampled_lanes += int(samples.sum())
-            was_prefill = live & (self._prompt_pos < feed_len)
-            self._lengths_dev = None
-            for slot in np.flatnonzero(live):
-                tv = int(t_valid[slot])
-                self._lengths[slot] += tv
-                self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
-                                             feed_len[slot])
+            frontier = self._advance_frontiers(s)
         return {
             "live": live.copy(),
             "samples": samples,
-            "was_prefill": was_prefill,
+            "was_prefill": frontier["was_prefill"],
             "lengths_after": self._lengths.copy(),
             "prompt_pos_after": self._prompt_pos.copy(),
             "logits": logits,                # device handle, not landed
@@ -2511,14 +2350,12 @@ class PagedEngine:
 
     def _commit_pending_record(self, p) -> list[Request]:
         """The host back half of a pipelined step, one step late: fence the
-        step's device outputs, then run _sample_and_finish's commit loop
+        step's device outputs, then commit its landed device samples
         against the record's SNAPSHOTS (the live arrays already hold the
         next step's frontier advance). Slots released since dispatch
         (preempt / cancel / deadline / EOS at the previous commit) are
         dead-marked in the record and skipped — their landed token is
-        discarded exactly as if never scheduled. Prefix registration passes
-        the record's own coverage so no block the still-in-flight next step
-        writes is ever registered (= refcounted as shared)."""
+        discarded exactly as if never scheduled."""
         prof = self.telemetry.profiler
         with prof.phase("device"):
             if prof.enabled:
@@ -2526,45 +2363,13 @@ class PagedEngine:
                 # (the whole point of the pipeline): device time attributed
                 # to the step whose results are being landed
                 jax.block_until_ready(p["logits"])
-        finished: list[Request] = []
         with prof.phase("sample"):
-            live = p["live"] & ~p["dead"]
-            samples = p["samples"] & ~p["dead"]
             # np.asarray blocks until the step's outputs land (the
             # unprofiled path's only sync point)
             sampled = np.asarray(p["sampled"])
-            if self._robust and self._adm.nan_check:
-                bad = ~np.isfinite(np.asarray(p["logits"])).all(axis=-1)
-                for slot in np.flatnonzero(samples & bad):
-                    finished.append(self._fail_slot(int(slot),
-                                                    "nan_logits"))
-                    live[slot] = False
-                    samples[slot] = False
-            for slot in np.flatnonzero(live):
-                req = self._slots[slot]
-                if req is None:
-                    continue
-                if self.prefix_sharing and (p["was_prefill"][slot]
-                                            or self.decode_sharing):
-                    covered = (int(p["lengths_after"][slot])
-                               if self.decode_sharing
-                               else min(int(p["prompt_pos_after"][slot]),
-                                        len(req.prompt)))
-                    with prof.phase("register"):
-                        self._register_blocks(slot, req, covered=covered)
-                if not samples[slot]:
-                    continue                     # still mid-prompt at dispatch
-                tok = int(sampled[slot])
-                req.out_tokens.append(tok)
-                if self.telemetry.enabled and len(req.out_tokens) == 1:
-                    self.telemetry.metrics.on_first_token(req.uid)
-                self._last[slot] = tok
-                if (len(req.out_tokens) >= req.max_new_tokens or
-                        (self.eos_id is not None and tok == self.eos_id) or
-                        (not p["was_prefill"][slot] and
-                         p["lengths_after"][slot] >= self.max_len - 1)):
-                    finished.append(self._finish(slot))
-        return finished
+            return self._commit_tokens(p["live"] & ~p["dead"],
+                                       p["samples"] & ~p["dead"], sampled,
+                                       p["logits"], p)
 
     def _call_device(self, fn, *args):
         """Dispatch one jitted step function. In robust mode transient
@@ -2585,27 +2390,13 @@ class PagedEngine:
                 if attempt == retries:
                     raise
 
-    def _sample_and_finish(self, live, t_valid, logits) -> list[Request]:
-        """Shared step tail (lockstep and packed layouts): sample each slot
-        that produced a next token, advance frontiers, register prefixes,
-        finish slots at budget/EOS/cache-full."""
+    def _sample_and_finish(self, s: PackedStep, logits) -> list[Request]:
+        """The synchronous step's tail: sample on the host each slot that
+        produced a next token (decoding, or its feed completed within this
+        chunk), advance the frontiers, and commit (_commit_tokens)."""
         prof = self.telemetry.profiler
-        # a slot samples this step iff it produced a next token: decoding, or
-        # its feed completed within this chunk
         with prof.phase("sample"):
-            feed_len = np.asarray([len(f) if f is not None else 1 << 30
-                                   for f in self._feeds])
-            samples = live & (self._prompt_pos + t_valid >= feed_len)
-            finished: list[Request] = []
-            if self._robust and self._adm.nan_check:
-                # logits are a pure step OUTPUT (the KV write is unaffected),
-                # so only rows about to be sampled matter — a non-finite one
-                # fails its request with a reason instead of emitting garbage
-                bad = ~np.isfinite(np.asarray(logits)).all(axis=-1)
-                for slot in np.flatnonzero(samples & bad):
-                    finished.append(self._fail_slot(int(slot), "nan_logits"))
-                    live[slot] = False
-                    samples[slot] = False
+            samples = s.live & (s.t_valid >= s.remaining)
             # non-sampling slots go greedy (temp 0): their uid/index rows
             # are placeholders that never reach the categorical path
             nxt = sample_tokens(
@@ -2613,44 +2404,78 @@ class PagedEngine:
                 [r.uid if r else 0 for r in self._slots],
                 [len(r.out_tokens) if r else 0 for r in self._slots])
             self.sampled_lanes += int(samples.sum())
-            self._lengths_dev = None         # frontiers advance below
-            for slot in np.flatnonzero(live):
-                req = self._slots[slot]
-                tv = int(t_valid[slot])
-                was_prefill = self._prompt_pos[slot] < feed_len[slot]
-                self._lengths[slot] += tv
-                self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
-                                             feed_len[slot])
-                if self.prefix_sharing and (was_prefill
-                                            or self.decode_sharing):
-                    # registration precedes any possible _finish below, so
-                    # a prompt that completes (or a block that fills at the
-                    # decode frontier) on a terminating step still leaves
-                    # its full-block KV cached; with decode sharing this
-                    # runs every step, so generated blocks enter the trie
-                    # the step they fill
-                    with prof.phase("register"):
-                        self._register_blocks(slot, req)
-                if not samples[slot]:
-                    continue                     # still mid-prompt
-                tok = int(nxt[slot])
-                req.out_tokens.append(tok)
-                if self.telemetry.enabled and len(req.out_tokens) == 1:
-                    self.telemetry.metrics.on_first_token(req.uid)
-                self._last[slot] = tok
-                # the cache-full guard only applies to decode-written KV —
-                # the prefill-completion sample mirrors the continuous
-                # engine's admission sample, which is not length-guarded
-                if (len(req.out_tokens) >= req.max_new_tokens or
-                        (self.eos_id is not None and tok == self.eos_id) or
-                        (not was_prefill and
-                         self._lengths[slot] >= self.max_len - 1)):
-                    finished.append(self._finish(slot))
+            frontier = self._advance_frontiers(s)
+            return self._commit_tokens(s.live, samples, nxt, logits,
+                                       frontier)
+
+    def _commit_tokens(self, live, samples, tokens, logits,
+                       frontier) -> list[Request]:
+        """The commit loop of the synchronous and pipelined steps: register
+        each live slot's newly covered blocks, append each sampling slot's
+        token, and finish slots at budget, EOS or cache-full. `tokens` (B,)
+        are the step's samples — the host sampler's, or the landed device
+        argmax — and `frontier` holds the post-step `was_prefill`,
+        `lengths_after` and `prompt_pos_after` that registration and the
+        finish test read: the live arrays for the synchronous step, the
+        record's snapshots for the pipelined one, whose live arrays already
+        carry the next step's advance. Returns newly finished, including
+        slots failed for non-finite logits."""
+        finished: list[Request] = []
+        if self._robust and self._adm.nan_check:
+            # logits are a pure step OUTPUT (the KV write is unaffected),
+            # so only rows about to be sampled matter — a non-finite one
+            # fails its request with a reason instead of emitting garbage
+            bad = samples & ~np.isfinite(np.asarray(logits)).all(axis=-1)
+            for slot in np.flatnonzero(bad):
+                finished.append(self._fail_slot(int(slot), "nan_logits"))
+            live, samples = live & ~bad, samples & ~bad
+        was_prefill = frontier["was_prefill"]
+        lengths = frontier["lengths_after"]
+        prompt_pos = frontier["prompt_pos_after"]
+        for slot in np.flatnonzero(live):
+            if self._commit_slot(slot,
+                                 int(tokens[slot]) if samples[slot] else None,
+                                 was_prefill[slot], lengths[slot],
+                                 prompt_pos[slot]):
+                finished.append(self._finish(slot))
         return finished
 
-    def _verify_and_finish(self, live, t_valid, drafts, off, wp, logits,
-                           draft_allocs, snap_blocks, snap, staged,
-                           fresh_np) -> list[Request]:
+    def _commit_slot(self, slot, tok, was_prefill, length,
+                     prompt_pos) -> bool:
+        """Commit one slot's step, in every step tail: register its newly
+        covered blocks, then append `tok`, its sampled token (None while
+        the slot is still mid-prompt). `was_prefill`, `length` and
+        `prompt_pos` are the slot's post-step frontier (_commit_tokens).
+        Returns whether the request ends: at its budget, at EOS, or with
+        decode-written KV at the cache's end."""
+        req = self._slots[slot]
+        if self.prefix_sharing and (was_prefill or self.decode_sharing):
+            # registration precedes any possible finish, so a prompt that
+            # completes (or a block that fills at the decode frontier) on a
+            # terminating step still leaves its full-block KV cached; with
+            # decode sharing this runs every step, so generated blocks enter
+            # the trie the step they fill. The coverage is the frontier's: a
+            # pipelined step still in flight writes only rows at or past it,
+            # so no block it writes is registered (= refcounted as shared)
+            covered = (int(length) if self.decode_sharing
+                       else min(int(prompt_pos), len(req.prompt)))
+            with self.telemetry.profiler.phase("register"):
+                self._register_blocks(slot, req, covered)
+        if tok is None:
+            return False                     # still mid-prompt
+        req.out_tokens.append(tok)
+        if self.telemetry.enabled and len(req.out_tokens) == 1:
+            self.telemetry.metrics.on_first_token(req.uid)
+        self._last[slot] = tok
+        # the cache-full guard only applies to decode-written KV — the
+        # prefill-completion sample mirrors the continuous engine's
+        # admission sample, which is not length-guarded
+        return (len(req.out_tokens) >= req.max_new_tokens
+                or (self.eos_id is not None and tok == self.eos_id)
+                or (not was_prefill and length >= self.max_len - 1))
+
+    def _verify_and_finish(self, s: PackedStep, logits, snap_blocks, snap,
+                           staged) -> list[Request]:
         """Speculative step tail: sample EVERY verify lane with the owning
         request's per-(uid, position) key — bit-identical to the tokens a
         never-drafted engine samples one step at a time — accept the
@@ -2671,12 +2496,12 @@ class PagedEngine:
             restored pre-step blocks — exactly the never-drafted fold."""
         prof = self.telemetry.profiler
         bs = self.block_size
+        live, t_valid, drafts, off, wp = (s.live, s.t_valid, s.drafts, s.off,
+                                          s.wp)
         width = wp.shape[0]
         kk1 = logits.shape[1]
         with prof.phase("sample"):
-            feed_len = np.asarray([len(f) if f is not None else 1 << 30
-                                   for f in self._feeds])
-            samples = live & (self._prompt_pos + t_valid >= feed_len)
+            samples = live & (t_valid >= s.remaining)
             # one flat sampling batch over (slot, verify lane): lane i of a
             # drafting slot is generation index len(out_tokens) + i, so
             # every token folds exactly the key the never-drafted engine
@@ -2705,29 +2530,17 @@ class PagedEngine:
             for slot in np.flatnonzero(live):
                 req = self._slots[slot]
                 tv = int(t_valid[slot])
-                was_prefill = self._prompt_pos[slot] < feed_len[slot]
+                was_prefill = s.remaining[slot] > 0
                 if slot not in drafts:
-                    # identical to the never-drafted tail (_sample_and_finish),
-                    # except finishes are deferred until after rollback so EOS
-                    # frees append to a free list rollback already restored
+                    # the never-drafted commit, except finishes are
+                    # deferred until after rollback so EOS frees append to
+                    # a free list rollback already restored
                     self._lengths[slot] += tv
-                    self._prompt_pos[slot] = min(self._prompt_pos[slot] + tv,
-                                                 feed_len[slot])
-                    if self.prefix_sharing and (was_prefill
-                                                or self.decode_sharing):
-                        with prof.phase("register"):
-                            self._register_blocks(slot, req)
-                    if not samples[slot]:
-                        continue                 # still mid-prompt
-                    tok = int(toks[slot, 0])
-                    req.out_tokens.append(tok)
-                    if self.telemetry.enabled and len(req.out_tokens) == 1:
-                        self.telemetry.metrics.on_first_token(req.uid)
-                    self._last[slot] = tok
-                    if (len(req.out_tokens) >= req.max_new_tokens or
-                            (self.eos_id is not None and tok == self.eos_id) or
-                            (not was_prefill and
-                             self._lengths[slot] >= self.max_len - 1)):
+                    self._prompt_pos[slot] += min(tv, int(s.remaining[slot]))
+                    tok = int(toks[slot, 0]) if samples[slot] else None
+                    if self._commit_slot(slot, tok, was_prefill,
+                                         self._lengths[slot],
+                                         self._prompt_pos[slot]):
                         finished_slots.append(slot)
                     continue
                 # drafting decode slot: longest matching prefix wins
@@ -2769,12 +2582,13 @@ class PagedEngine:
                 req.out_tokens.extend(emitted)
                 if self.decode_sharing:
                     with prof.phase("register"):
-                        self._register_blocks(slot, req)
+                        self._register_blocks(slot, req,
+                                              int(self._lengths[slot]))
                 self._last[slot] = emitted[-1]
                 if fin:
                     finished_slots.append(slot)
         with prof.phase("rollback"):
-            for slot, jdx, blk, resv_dec in reversed(draft_allocs):
+            for slot, jdx, blk, resv_dec in reversed(s.draft_allocs):
                 if jdx < keep_blocks[slot]:
                     continue                 # covered by committed rows
                 self.alloc.free([blk])
@@ -2799,7 +2613,7 @@ class PagedEngine:
                 for slot in drafts:
                     row = self._tables[slot]
                     held.update(int(b) for b in row[row >= 0])
-                fresh_live = ((set(int(b) for b in fresh_np) & held)
+                fresh_live = ((set(int(b) for b in s.fresh) & held)
                               - {TRASH_BLOCK})
                 fresh_mask = np.asarray(
                     [int(b) in fresh_live for b in snap_blocks], bool)
@@ -2869,16 +2683,7 @@ class PagedEngine:
                     "admission stalled with free pool"
                 return finished
             try:
-                if self.packed:
-                    finished.extend(self._step_packed())
-                else:
-                    prefilling = any(
-                        self._live[s]
-                        and self._prompt_pos[s] < len(self._feeds[s])
-                        for s in range(self.max_batch)
-                        if self._slots[s] is not None)
-                    finished.extend(
-                        self._step(self.block_size if prefilling else 1))
+                finished.extend(self._step_packed())
             except BlockPoolExhausted:
                 if not (self._robust and self._adm.graceful_exhaustion):
                     raise

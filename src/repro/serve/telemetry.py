@@ -92,8 +92,10 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 # the `padding` section's ctx_positions / sampled_lanes work counters; v4
 # its state_slots_moved / state_slots_live / state_bytes state-arena
 # counters (zero without recurrent state); v5 its grid_rows counter (query
-# rows of the XLA packed step's attention grids)
-SNAPSHOT_SCHEMA_VERSION = 5
+# rows of the XLA packed step's attention grids); v6 dropped
+# pad_lanes_skipped from `padding` and `prefix` (it estimated the removed
+# lockstep layout's lanes)
+SNAPSHOT_SCHEMA_VERSION = 6
 
 # admission-wait histogram bucket edges (milliseconds, log-spaced); the last
 # bucket is open-ended

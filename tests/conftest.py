@@ -54,28 +54,22 @@ def pytest_addoption(parser):
                           "trie as they fill; implies prefix sharing); only "
                           "meaningful with --cache-layout paged (CI runs a "
                           "decode-sharing leg)")
-    parser.addoption("--packed-step", default="off", choices=("on", "off"),
-                     help="run the engine-level suites with the paged "
-                          "engine's token-centric PACKED step (ragged token "
-                          "batches) instead of the lockstep "
-                          "(B, block_size)/(B, 1) layout; only meaningful "
-                          "with --cache-layout paged (CI runs a packed leg)")
     parser.addoption("--kv-quant", default="none", choices=("none", "int8"),
                      help="run the engine-level suites with int8-quantized "
                           "paged KV blocks (per-block per-kv-head scales); "
                           "only meaningful with --cache-layout paged "
-                          "(CI runs packed + lockstep int8 legs)")
+                          "(CI runs int8 legs)")
     parser.addoption("--speculative", default="off", choices=("on", "off"),
                      help="run the engine-level suites with trie-driven "
                           "speculative decoding (draft/verify/rollback); "
                           "only meaningful with --cache-layout paged "
-                          "--packed-step on (CI runs speculative legs)")
+                          "(CI runs speculative legs)")
     parser.addoption("--async-loop", default="off", choices=("on", "off"),
                      help="run the engine-level suites with the paged "
                           "engine's pipelined async step loop (dispatch "
                           "step N+1 before committing step N); only "
                           "meaningful with --cache-layout paged "
-                          "--packed-step on (CI runs async legs)")
+                          "(CI runs async legs)")
 
 
 def pytest_collection_modifyitems(config, items):
@@ -112,12 +106,6 @@ def prefix_sharing(request):
 
 
 @pytest.fixture
-def packed_step(request):
-    """The --packed-step option as a bool (paged engines only)."""
-    return request.config.getoption("--packed-step") == "on"
-
-
-@pytest.fixture
 def decode_sharing(request):
     """The --decode-sharing option as a bool (paged engines only)."""
     return request.config.getoption("--decode-sharing") == "on"
@@ -131,24 +119,24 @@ def kv_quant(request):
 
 @pytest.fixture
 def speculative(request):
-    """The --speculative option as a bool (paged packed engines only)."""
+    """The --speculative option as a bool (paged engines only)."""
     return request.config.getoption("--speculative") == "on"
 
 
 @pytest.fixture
 def async_loop(request):
-    """The --async-loop option as a bool (paged packed engines only)."""
+    """The --async-loop option as a bool (paged engines only)."""
     return request.config.getoption("--async-loop") == "on"
 
 
 @pytest.fixture
-def make_engine(cache_layout, prefix_sharing, decode_sharing, packed_step,
-                kv_quant, speculative, async_loop):
+def make_engine(cache_layout, prefix_sharing, decode_sharing, kv_quant,
+                speculative, async_loop):
     """Factory building the continuous-batching engine for the selected
     cache layout: ContinuousEngine (slot arena) or PagedEngine (block pool,
     optionally with --prefix-sharing prompt-prefix reuse, --decode-sharing
-    generated-block reuse, the --packed-step token-centric step layout,
-    and/or --kv-quant int8 block quantization). Both schedule mixed-length
+    generated-block reuse, --kv-quant int8 block quantization,
+    --speculative decoding and/or the --async-loop). Both schedule mixed-length
     traffic step-by-step, so engine-level tests are layout-agnostic through
     this fixture. kv_quant rides on cfg (the single source the engine and
     cache init read), so it only applies to the paged layout — the slot
@@ -161,12 +149,8 @@ def make_engine(cache_layout, prefix_sharing, decode_sharing, packed_step,
             kw.setdefault("block_size", 16)
             kw.setdefault("prefix_sharing", prefix_sharing)
             kw.setdefault("decode_sharing", decode_sharing)
-            kw.setdefault("packed", packed_step)
-            # speculative decoding and the async loop ride the packed step
-            # only; explicit lockstep engines built by individual tests
-            # stay non-spec and synchronous
-            kw.setdefault("speculative", speculative and kw["packed"])
-            kw.setdefault("async_loop", async_loop and kw["packed"])
+            kw.setdefault("speculative", speculative)
+            kw.setdefault("async_loop", async_loop)
             return PagedEngine(params, cfg, **kw)
         from repro.serve import ContinuousEngine
         return ContinuousEngine(params, cfg, **kw)

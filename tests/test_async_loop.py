@@ -44,7 +44,7 @@ def _requests(seed=7, n=10, shared_len=32, temps=None):
 
 def _serve(params, cfg, async_loop, *, eos_id=5, temps=None, **kw):
     eng = PagedEngine(params, cfg, max_batch=4, max_len=128, block_size=16,
-                      packed=True, async_loop=async_loop, eos_id=eos_id,
+                      async_loop=async_loop, eos_id=eos_id,
                       **kw)
     for req in _requests(temps=temps):
         eng.submit(req)
@@ -131,7 +131,7 @@ def test_async_multi_turn_sessions(served):
 
     def serve_turns(async_loop):
         eng = PagedEngine(params, cfg, max_batch=3, max_len=192,
-                          block_size=16, packed=True, prefix_sharing=True,
+                          block_size=16, prefix_sharing=True,
                           decode_sharing=True, async_loop=async_loop)
         rng = np.random.default_rng(11)
         out = {}
@@ -152,8 +152,10 @@ def test_async_multi_turn_sessions(served):
 
 
 def test_async_requires_packed(served):
+    """packed=False is refused outright, the async loop included: the
+    lockstep layout it asked for was removed."""
     cfg, params = served
-    with pytest.raises(ValueError, match="packed"):
+    with pytest.raises(ValueError, match="lockstep step layout was removed"):
         PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=16,
                     packed=False, async_loop=True)
 
@@ -200,7 +202,7 @@ def test_async_chaos_invariants(tiny_cfg, quant):
                    **({"kv_quant": quant} if quant != "none" else {}))
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     eng = PagedEngine(params, cfg, max_batch=3, max_len=64, block_size=8,
-                      num_blocks=14, packed=True, async_loop=True, eos_id=5,
+                      num_blocks=14, async_loop=True, eos_id=5,
                       admission=AdmissionConfig(
                           max_queue=8,
                           backpressure="shed-lowest-priority",
@@ -223,7 +225,7 @@ def test_async_profiler_coverage(served):
     cfg, params = served
     tel = Telemetry(enabled=True)
     eng = PagedEngine(params, cfg, max_batch=4, max_len=128, block_size=16,
-                      packed=True, async_loop=True, telemetry=tel)
+                      async_loop=True, telemetry=tel)
     for req in _requests():
         eng.submit(req)
     eng.run()
@@ -244,23 +246,24 @@ def _count_fences(monkeypatch):
     return fences
 
 
-@pytest.mark.parametrize("packed,async_loop", [
-    (True, False), (False, False), (True, True)])
-def test_paged_unprofiled_steps_issue_no_fence(served, monkeypatch, packed,
-                                               async_loop):
-    """With telemetry off, no paged step path calls jax.block_until_ready —
-    the profiler's phase-attribution fence is strictly gated on
-    prof.enabled (host syncs happen only through the data dependency on
-    sampled tokens). Guards against re-introducing a per-step forced
-    sync on the hot path."""
+@pytest.mark.parametrize("speculative,async_loop", [
+    (False, False), (True, False), (False, True)])
+def test_paged_unprofiled_steps_issue_no_fence(served, monkeypatch,
+                                               speculative, async_loop):
+    """With telemetry off, no paged step path — synchronous, speculative
+    verify or pipelined — calls jax.block_until_ready: the profiler's
+    phase-attribution fence is strictly gated on prof.enabled (host syncs
+    happen only through the data dependency on sampled tokens). Guards
+    against re-introducing a per-step forced sync on the hot path."""
     cfg, params = served
     eng = PagedEngine(params, cfg, max_batch=4, max_len=128, block_size=16,
-                      packed=packed, async_loop=async_loop)
+                      speculative=speculative, async_loop=async_loop)
     for req in _requests(n=6):
         eng.submit(req)
     fences = _count_fences(monkeypatch)
     eng.run()
     assert not fences, f"unprofiled path issued {len(fences)} device fences"
+    assert not speculative or eng.spec_steps > 0
 
 
 def test_continuous_unprofiled_steps_issue_no_fence(served, monkeypatch):

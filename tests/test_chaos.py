@@ -8,7 +8,7 @@ Two tiers:
 * fixed legs (below) run in the tier-1 hypothesis CI step — deterministic
   from (seed, leg), no wall-clock dependence (no deadlines);
 * the option-driven leg (slow) rides the cache-layouts matrix chaos job,
-  inheriting --prefix-sharing/--packed-step/--kv-quant/--decode-sharing.
+  inheriting --prefix-sharing/--kv-quant/--decode-sharing/--async-loop.
 """
 import jax
 import numpy as np
@@ -37,19 +37,19 @@ def _params(tiny_cfg, **cfg_kw):
     return M.init_params(jax.random.PRNGKey(0), cfg), cfg
 
 
-@pytest.mark.parametrize("packed,sharing,quant", [
-    (True, True, "none"),            # the default serving leg
-    (False, False, "none"),          # lockstep, no trie
-    (True, False, "int8"),           # quantized pool, packed
-    (True, True, "int8"),            # quantized + sharing (COW on int8)
+@pytest.mark.parametrize("sharing,quant", [
+    (True, "none"),                  # the default serving leg
+    (False, "none"),                 # no trie
+    (False, "int8"),                 # quantized pool
+    (True, "int8"),                  # quantized + sharing (COW on int8)
 ])
-def test_chaos_smoke_fixed_legs(tiny_cfg, packed, sharing, quant):
+def test_chaos_smoke_fixed_legs(tiny_cfg, sharing, quant):
     """Every seeded chaos run passes the invariant checker at every step
-    and drains the pool to empty, across packed x sharing x int8 legs."""
+    and drains the pool to empty, across sharing x int8 legs."""
     params, cfg = _params(tiny_cfg,
                           **({"kv_quant": quant} if quant != "none" else {}))
     eng = PagedEngine(params, cfg, max_batch=3, max_len=64, block_size=8,
-                      num_blocks=14, prefix_sharing=sharing, packed=packed,
+                      num_blocks=14, prefix_sharing=sharing,
                       admission=AdmissionConfig(
                           max_queue=8,
                           backpressure="shed-lowest-priority",
@@ -72,7 +72,7 @@ def test_chaos_seed_reproducible(tiny_cfg):
     for _ in range(2):
         params, cfg = _params(tiny_cfg)
         eng = PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=8,
-                          num_blocks=12, prefix_sharing=True, packed=True,
+                          num_blocks=12, prefix_sharing=True,
                           admission=AdmissionConfig(preemption=True))
         rep = ChaosMonkey(eng, seed=3, make_request=_maker(),
                           n_requests=10, max_steps=1500).run()
@@ -96,7 +96,7 @@ def test_chaos_restores_step_fns(tiny_cfg):
     clean run sees no injected faults."""
     params, cfg = _params(tiny_cfg)
     eng = PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=8,
-                      num_blocks=12, prefix_sharing=True, packed=True,
+                      num_blocks=12, prefix_sharing=True,
                       admission=AdmissionConfig(preemption=True))
     monkey = ChaosMonkey(eng, seed=1, make_request=_maker(), n_requests=6,
                          max_steps=1500)

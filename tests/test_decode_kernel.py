@@ -6,7 +6,7 @@ causal semantics, GQA packing, per-slot padded lengths, and per-head theta.
 hccs_paged_decode (the block-table gather variant) is asserted against its
 own oracle and against hccs_decode on an equivalent contiguous layout,
 covering sentinel skipping, scrambled physical block order, and sub-block
-tiling. hccs_packed_prefill (the token-centric packed-step variant) is
+tiling. hccs_packed_prefill (the packed step's token-centric variant) is
 asserted against its own oracle and against hccs_paged_decode with tokens
 expanded to slots, covering the slot-id indirection, per-token frontiers,
 and pad lanes. All cases run in interpret mode (CPU); on TPU they lower to

@@ -81,14 +81,12 @@ def serve(setup, prompts, max_new, max_batch=3, first=None, **kw):
     seen = {}
     real = eng._sample_and_finish
 
-    def record(live, t_valid, logits):
-        feed_len = np.asarray([len(f) if f is not None else 1 << 30
-                               for f in eng._feeds])
-        samples = live & (eng._prompt_pos + t_valid >= feed_len)
+    def record(step, logits):
+        samples = step.live & (step.t_valid >= step.remaining)
         for slot in np.flatnonzero(samples):
             seen.setdefault(eng._slots[slot].uid, []).append(
                 np.asarray(logits[slot], np.float32))
-        return real(live, t_valid, logits)
+        return real(step, logits)
 
     eng._sample_and_finish = record
     reqs = [Request(uid=i, prompt=p, max_new_tokens=n)
@@ -237,8 +235,12 @@ def test_the_conv_state_carries_across_step_boundaries(setup):
 ], ids=lambda v: v if isinstance(v, str) else None)
 def test_switches_a_slot_state_cannot_keep_are_refused(setup, cfg_kw, eng_kw,
                                                        name):
+    """Each switch the state arena cannot keep is refused by name; the
+    lockstep layout (packed=False) is refused for every config."""
     from repro.serve import PagedEngine
     c, arch, cfg, params = setup
-    with pytest.raises(ValueError, match=f"recurrent state.*{name}"):
+    match = ("lockstep step layout was removed" if name == "packed=False"
+             else f"recurrent state.*{name}")
+    with pytest.raises(ValueError, match=match):
         PagedEngine(params, cfg.replace(**cfg_kw), max_batch=2, max_len=64,
                     block_size=8, num_blocks=20, **eng_kw)

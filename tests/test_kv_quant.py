@@ -8,7 +8,7 @@ The central design fact under test: the quantizing write is a PER-ROW FOLD
 block's scale monotonically and requantizes the existing payload by the
 old/new ratio, so a block's bytes are a pure function of (row values, write
 order), independent of how steps partition the rows. That is what makes
-packed vs lockstep, sharing on/off, and engine reuse BIT-IDENTICAL under
+token budgets, sharing on/off, and engine reuse BIT-IDENTICAL under
 quantization; only int8-vs-fp drift needs a tolerance regime.
 """
 import copy
@@ -172,7 +172,7 @@ class TestQuantScatterFold:
 
     def test_partition_independent(self, rng):
         """Folding the same rows through ANY step partition yields the same
-        final bytes — the invariant that makes packed vs lockstep steps
+        final bytes — the invariant that makes steps of any token budget
         bit-identical under quantization."""
         t = 2 * self.BS
         rows = self._rows(rng, t)
@@ -239,37 +239,48 @@ class TestQuantScatterFold:
 
 class TestEnginePartitionParity:
     """Greedy outputs under kv_quant="int8" are BIT-IDENTICAL across every
-    step partitioning of the same token stream — packed vs lockstep, sharing
+    step partitioning of the same token stream — two token budgets, sharing
     on/off, fused kernel vs XLA — because the per-row fold makes block bytes
     partition-independent. (int8 vs fp is the only tolerance-gated axis; see
     TestDriftTolerance.)"""
+
+    # a budget of max_batch lanes: every prompt prefills one lane per step
+    # beside the decoding slots, where the default budget (4 x 16) takes
+    # whole chunks — the most and the fewest step boundaries
+    SMALL_BUDGET = 4
 
     def _cfgs(self, served):
         cfg, params = served
         return cfg.replace(kv_quant="int8"), params
 
     def test_packed_matches_lockstep(self, served, rng):
+        """The default budget's whole-chunk steps and a max_batch budget's
+        one-lane-per-prompt steps fold the same rows."""
         cfg, params = self._cfgs(served)
         reqs = _requests(rng, 6)
-        packed, _ = _serve(params, cfg, reqs, packed=True)
-        lockstep, _ = _serve(params, cfg, reqs, packed=False)
-        assert packed == lockstep
+        wide, wide_eng = _serve(params, cfg, reqs)
+        narrow, narrow_eng = _serve(params, cfg, reqs,
+                                    token_budget=self.SMALL_BUDGET)
+        assert wide == narrow
+        assert narrow_eng.occupancy_steps > wide_eng.occupancy_steps
 
-    @pytest.mark.parametrize("packed", [True, False])
-    def test_sharing_matches_isolated(self, served, rng, packed):
+    @pytest.mark.parametrize("small_budget", [True, False])
+    def test_sharing_matches_isolated(self, served, rng, small_budget):
         """Prefix + decode sharing reuse quantized blocks and COW-copy them
-        (payload + scales): outputs must equal the sharing-off run."""
+        (payload + scales): outputs must equal the sharing-off run, at the
+        default token budget and at max_batch lanes."""
         cfg, params = self._cfgs(served)
+        budget = self.SMALL_BUDGET if small_budget else None
         shared = rng.integers(0, 256, 16).astype(np.int32)
         reqs = [Request(uid=i, prompt=np.concatenate(
                     [shared, rng.integers(0, 256, 5).astype(np.int32)]),
                         max_new_tokens=8) for i in range(4)]
         plain, _ = _serve(params, cfg.replace(prefix_sharing=False,
                                               decode_sharing=False),
-                          reqs, packed=packed)
+                          reqs, token_budget=budget)
         share, eng = _serve(params, cfg.replace(prefix_sharing=True,
                                                 decode_sharing=True),
-                            reqs, packed=packed)
+                            reqs, token_budget=budget)
         assert plain == share
         assert eng.prefix_hits > 0            # sharing actually engaged
 

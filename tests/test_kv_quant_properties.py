@@ -5,7 +5,7 @@ The fold's contract, fuzzed here rather than spot-checked:
     requant rule (float32 arithmetic end to end);
   * PARTITION INDEPENDENCE — folding the same rows through any sequence of
     write groups produces identical pool bytes and scales (the invariant
-    that makes packed vs lockstep engine steps bit-identical under
+    that makes engine steps of any token budget bit-identical under
     quantization);
   * scales grow monotonically and always cover the rows written so far
     (every landed row's amax <= 127 * scale, so no row is ever clipped by a

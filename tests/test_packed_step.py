@@ -299,7 +299,7 @@ def test_warm_up_lands_on_the_floor_rung(tiny_cfg, config):
     cfg = tiny_cfg(attention_prob="hccs", hccs_mode="i16_div")
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     eng = PagedEngine(params, cfg, max_batch=b, max_len=8 * bs,
-                      block_size=bs, packed=True)
+                      block_size=bs)
     for w, wb, n, p in harness.ladder(sv):
         live = np.arange(b) < n
         remaining = np.where(live, p, 0)

@@ -187,7 +187,7 @@ def test_finished_slot_cache_isolated(served, rng):
 def test_decode_kernel_engine_parity(tiny_cfg, rng, mode, make_engine):
     """The fused decode-kernel dispatch generates the same greedy tokens as
     the XLA STE decode path — for BOTH cache layouts (run with
-    ``--cache-layout paged`` to drive hccs_paged_decode instead of
+    ``--cache-layout paged`` to drive hccs_packed_prefill instead of
     hccs_decode). For i8 modes the dispatch must fall back to the XLA path
     (the kernel cannot reproduce per-element i8 truncation), so parity there
     is trivially exact — the test guards against silent remapping."""
@@ -397,12 +397,12 @@ def test_prefix_sharing_eviction_under_pool_pressure(served, rng):
         assert eng.alloc.ref(blk) >= 1
 
 
-@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("async_loop", [False, True])
 @pytest.mark.parametrize("sharing", [False, True])
-def test_multi_turn_session_parity(served, rng, sharing, packed):
+def test_multi_turn_session_parity(served, rng, sharing, async_loop):
     """Acceptance: session-continued greedy outputs are token-identical to
     re-feeding the full concatenated history from scratch — with
-    decode-block sharing both OFF and ON, and with the packed step layout
+    decode-block sharing both OFF and ON, and with the pipelined async loop
     both OFF and ON. The reference engine never uses sessions or sharing:
     each turn it is fed the manually concatenated history (prior prompts +
     generated replies) as a plain prompt, so any divergence in the session
@@ -417,9 +417,8 @@ def test_multi_turn_session_parity(served, rng, sharing, packed):
              for n in (25, 7, 22)] for _ in range(n_sessions)]
     sess = PagedEngine(params, cfg, max_batch=2, max_len=128, block_size=16,
                        prefix_sharing=sharing, decode_sharing=sharing,
-                       packed=packed)
-    ref = PagedEngine(params, cfg, max_batch=2, max_len=128, block_size=16,
-                      packed=packed)
+                       async_loop=async_loop)
+    ref = PagedEngine(params, cfg, max_batch=2, max_len=128, block_size=16)
     hist = [np.zeros(0, np.int32)] * n_sessions
     for turn in range(turns):
         srun, rrun = [], []
@@ -588,14 +587,13 @@ def test_exhaustion_rollback_byte_identical(served, rng):
     token-identical to an uncontended run."""
     cfg, params = served
     reqs = _requests(rng, 2, lens=(13,), max_new=12)
-    ref_eng = PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=8,
-                          packed=False)
+    ref_eng = PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=8)
     for r in copy.deepcopy(reqs):
         ref_eng.submit(r)
     ref_out = {r.uid: r.out_tokens for r in ref_eng.run()}
 
     eng = PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=8,
-                      num_blocks=9, packed=False)
+                      num_blocks=9)
 
     def snap(e):
         return (list(e.alloc._free), dict(e.alloc._ref),
@@ -732,10 +730,10 @@ def test_multi_turn_followup_skip_acceptance(served, rng):
 @pytest.mark.parametrize("sharing", [False, True])
 def test_packed_step_parity_with_lockstep(served, rng, sharing):
     """Acceptance: the packed token step produces greedy outputs
-    token-identical to the lockstep (B, block_size)/(B, 1) layout on a mixed
+    token-identical to the continuous engine's slot arena on a mixed
     workload — under prefix sharing both off AND on (the shared-prefix set
     includes a full-prompt hit, so the packed path exercises COW and the
-    re-fed last token too) — while padding out far fewer token lanes."""
+    re-fed last token too)."""
     cfg, params = served
     shared = rng.integers(0, 256, 32).astype(np.int32)   # 2 full 16-blocks
     prompts = ([rng.integers(0, 256, int(n)).astype(np.int32)
@@ -748,22 +746,16 @@ def test_packed_step_parity_with_lockstep(served, rng, sharing):
     reqs = [Request(uid=i, prompt=p, max_new_tokens=5)
             for i, p in enumerate(prompts)]
     reqs[1].max_new_tokens = 1
-    outs, engines = {}, {}
-    for packed in (False, True):
-        eng = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
-                          packed=packed, prefix_sharing=sharing)
+    outs = {}
+    for eng in (ContinuousEngine(params, cfg, max_batch=4, max_len=64),
+                PagedEngine(params, cfg, max_batch=4, max_len=64,
+                            block_size=16, prefix_sharing=sharing)):
         for r in copy.deepcopy(reqs):
             eng.submit(r)
-        outs[packed] = {r.uid: r.out_tokens for r in eng.run()}
-        engines[packed] = eng
-    assert outs[False] == outs[True]
-    # prefix telemetry is scheduling-independent...
-    assert (engines[False].prefix_stats()["prefill_tokens_skipped"]
-            == engines[True].prefix_stats()["prefill_tokens_skipped"])
-    # ...but the packed layout burns strictly fewer padded token lanes
-    pf, pt = engines[False].padding_stats(), engines[True].padding_stats()
-    assert pt["efficiency"] > pf["efficiency"]
-    assert pt["pad_lanes_skipped"] > 0 and pf["pad_lanes_skipped"] == 0
+        outs[type(eng)] = {r.uid: r.out_tokens for r in eng.run()}
+    assert outs[ContinuousEngine] == outs[PagedEngine]
+    # sharing engaged exactly when it was on
+    assert (eng.prefix_stats()["prefill_tokens_skipped"] > 0) == sharing
 
 
 @pytest.mark.parametrize("mode", ["sync", "async", "blockwise",
@@ -771,7 +763,8 @@ def test_packed_step_parity_with_lockstep(served, rng, sharing):
 def test_packed_split_grids_parity_with_lockstep(served, rng, monkeypatch,
                                                  mode):
     """The XLA packed step's two attention grids give greedy outputs
-    token-identical to the lockstep layout. Request 0 decodes while three
+    token-identical to the continuous engine's slot arena. Request 0 decodes
+    while three
     prompts of 6, 7 and 1 tokens arrive: at width 16 and Wb 16 the chunk
     grid's floor is one row, so the 6-token chunk takes it and the 7-token
     prompt one lane, beside the one-token prefill and the decode rider.
@@ -796,17 +789,16 @@ def test_packed_split_grids_parity_with_lockstep(served, rng, monkeypatch,
                       g["chunk_q_pos"].shape if "chunk_q_pos" in g else None))
         return g
     monkeypatch.setattr(paged, "grid_steering", record)
+    kw = dict(speculative=True, draft_len=2) if mode == "speculative" else {}
+    paged = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
+                        async_loop=mode == "async", **kw)
+    if kw:
+        paged._propose_drafts = lambda live, remaining: {
+            int(s): [7, 7] for s in np.flatnonzero(
+                np.asarray(live) & (np.asarray(remaining) == 0))}
     outs = {}
-    for packed in (False, True):
-        kw = dict(speculative=True, draft_len=2) if (
-            packed and mode == "speculative") else {}
-        eng = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
-                          packed=packed, async_loop=packed and mode == "async",
-                          **kw)
-        if kw:
-            eng._propose_drafts = lambda live, remaining: {
-                int(s): [7, 7] for s in np.flatnonzero(
-                    np.asarray(live) & (np.asarray(remaining) == 0))}
+    for eng in (ContinuousEngine(params, cfg, max_batch=4, max_len=64),
+                paged):
         reqs = [Request(uid=i, prompt=p, max_new_tokens=6)
                 for i, p in enumerate(prompts)]
         for r in reqs[:first]:
@@ -815,8 +807,9 @@ def test_packed_split_grids_parity_with_lockstep(served, rng, monkeypatch,
         for r in reqs[first:]:
             eng.submit(r)
         done = eng.run()
-        outs[packed] = {r.uid: r.out_tokens for r in done}
-    assert outs[False] == outs[True] and len(outs[True]) == 4
+        outs[type(eng)] = {r.uid: r.out_tokens for r in done}
+    assert (outs[ContinuousEngine] == outs[PagedEngine]
+            and len(outs[PagedEngine]) == 4)
     if mode == "speculative":
         assert any(shape and shape[0] > max(1, w // shape[1])
                    for _, w, shape in steps), steps
@@ -824,29 +817,35 @@ def test_packed_split_grids_parity_with_lockstep(served, rng, monkeypatch,
         assert ([1, 6, 1, 1], 16, (1, 16)) in steps, steps
 
 
+# Token lanes the removed lockstep (B, block_size)/(B, 1) layout spent on
+# test_packed_step_prefill_heavy_efficiency's traffic: 204 valid of 612, its
+# padding_stats() on the last tree that had it, where the packed step read
+# 204 of 288.
+LOCKSTEP_EFFICIENCY = 204 / 612
+
+
 def test_packed_step_prefill_heavy_efficiency(served, rng):
     """The packing acceptance regime: long prompts chunk-prefilling while
-    short-prompt long-output requests decode alongside (every lockstep chunk
-    step pads each rider to a full block_size row). The packed step's
+    short-prompt long-output requests decode alongside (a lockstep chunk
+    step padded each rider to a full block_size row). The packed step's
     padding efficiency must be >= 2x the lockstep layout's on the same
-    workload (the benchmark gates the same ratio plus tok/s on its
-    prefill-heavy workload)."""
+    workload, and its outputs those of the continuous engine."""
     cfg, params = served
     reqs = ([Request(uid=i, prompt=rng.integers(0, 256, 5).astype(np.int32),
                      max_new_tokens=16) for i in range(3)]
             + [Request(uid=3 + j,
                        prompt=rng.integers(0, 256, 45).astype(np.int32),
                        max_new_tokens=4) for j in range(3)])
-    eff, outs = {}, {}
-    for packed in (False, True):
-        eng = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
-                          num_blocks=17, packed=packed)
+    outs = {}
+    for eng in (ContinuousEngine(params, cfg, max_batch=4, max_len=64),
+                PagedEngine(params, cfg, max_batch=4, max_len=64,
+                            block_size=16, num_blocks=17)):
         for r in copy.deepcopy(reqs):
             eng.submit(r)
-        outs[packed] = {r.uid: r.out_tokens for r in eng.run()}
-        eff[packed] = eng.padding_stats()["efficiency"]
-    assert outs[False] == outs[True]
-    assert eff[True] >= 2 * eff[False], eff
+        outs[type(eng)] = {r.uid: r.out_tokens for r in eng.run()}
+    assert outs[ContinuousEngine] == outs[PagedEngine]
+    eff = eng.padding_stats()["efficiency"]
+    assert eff >= 2 * LOCKSTEP_EFFICIENCY, eff
 
 
 def test_packed_step_budget_drives_chunk_size(served, rng):
@@ -859,7 +858,7 @@ def test_packed_step_budget_drives_chunk_size(served, rng):
     outs, steps = [], []
     for budget in (4, 48):
         eng = PagedEngine(params, cfg, max_batch=2, max_len=64, block_size=16,
-                          packed=True, token_budget=budget)
+                          token_budget=budget)
         eng.submit(Request(uid=0, prompt=prompt.copy(), max_new_tokens=4))
         (done,) = eng.run()
         outs.append(done.out_tokens)
@@ -869,7 +868,7 @@ def test_packed_step_budget_drives_chunk_size(served, rng):
     assert steps[1] < steps[0]
     with pytest.raises(ValueError):
         PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
-                    packed=True, token_budget=2)      # below max_batch
+                    token_budget=2)                   # below max_batch
 
 
 @pytest.mark.parametrize("mode", ["i16_div", "wide"])
@@ -884,8 +883,7 @@ def test_packed_decode_kernel_engine_parity(tiny_cfg, rng, mode):
     reqs = _requests(rng, 4, lens=(5, 9, 19), max_new=4)
     outs = []
     for cfg in (cfg0, cfgk):
-        eng = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16,
-                          packed=True)
+        eng = PagedEngine(params, cfg, max_batch=4, max_len=64, block_size=16)
         for r in copy.deepcopy(reqs):
             eng.submit(r)
         outs.append({r.uid: r.out_tokens for r in eng.run()})

@@ -42,7 +42,6 @@ def _engine(params, cfg, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_len", 256)
     kw.setdefault("block_size", 16)
-    kw.setdefault("packed", True)
     kw.setdefault("draft_len", 4)
     return PagedEngine(params, cfg, **kw)
 
@@ -337,8 +336,11 @@ class TestConfigGuards:
             tiny_cfg(cache_layout="paged", speculative=True, draft_len=0)
 
     def test_speculative_requires_packed_step(self, served):
+        """packed=False is refused outright, speculation included: the
+        lockstep layout it asked for was removed."""
         cfg, params = served
-        with pytest.raises(ValueError, match="packed"):
+        with pytest.raises(ValueError,
+                           match="lockstep step layout was removed"):
             _engine(params, cfg, packed=False, speculative=True)
 
     def test_engine_kwarg_overrides_cfg(self, served):

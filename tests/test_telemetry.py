@@ -32,11 +32,10 @@ LATENCY_KEYS = {"requests", "ttft", "tpot", "e2e", "queue_wait",
 DIST_KEYS = {"count", "mean", "p50", "p95", "p99"}
 PHASES_KEYS = {"steps", "step_seconds", "coverage", "phases"}
 # v3 added the ctx_positions / sampled_lanes work counters, v4 the state
-# arena's, v5 grid_rows
+# arena's, v5 grid_rows; v6 dropped pad_lanes_skipped
 PADDING_KEYS = {"lanes_valid", "lanes_total", "efficiency",
-                "pad_lanes_skipped", "ctx_positions", "sampled_lanes",
-                "state_slots_moved", "state_slots_live", "state_bytes",
-                "grid_rows"}
+                "ctx_positions", "sampled_lanes", "state_slots_moved",
+                "state_slots_live", "state_bytes", "grid_rows"}
 REPO = Path(__file__).resolve().parents[1]
 
 
@@ -63,7 +62,7 @@ def _engines(params, cfg, telemetry):
         "continuous": ContinuousEngine(params, cfg, max_batch=4, max_len=64,
                                        telemetry=telemetry),
         "paged": PagedEngine(params, paged_cfg, max_batch=4, max_len=64,
-                             block_size=8, packed=True, telemetry=telemetry),
+                             block_size=8, telemetry=telemetry),
     }
 
 
@@ -265,7 +264,7 @@ def test_device_fence_only_with_telemetry(served, rng, monkeypatch, name,
     engines = _engines(params, cfg, tel)
     if name == "paged_async":
         eng = PagedEngine(params, cfg.replace(cache_layout="paged"),
-                          max_batch=4, max_len=64, block_size=8, packed=True,
+                          max_batch=4, max_len=64, block_size=8,
                           async_loop=True, telemetry=tel)
     else:
         eng = engines[name]
@@ -288,40 +287,34 @@ def _count_step_inputs(eng):
 
         def counted(*a):
             ex = a[idx]
-            if "slot_ids" in ex:             # packed: per-token frontiers
-                valid = np.asarray(ex["slot_ids"]) >= 0
-                seen["lanes"] += int(valid.sum())
-                seen["ctx"] += int(np.asarray(ex["kv_len"])[valid].sum())
-                if "dec_kv_len" in ex:
-                    seen["grid"] += ex["dec_kv_len"].size + (
-                        ex["chunk_q_pos"].size if "chunk_q_pos" in ex else 0)
-            else:                            # lockstep: per-slot frontiers
-                tv = np.asarray(a[5]).astype(np.int64)
-                kv = np.asarray(ex["kv_len"]).astype(np.int64)
-                seen["lanes"] += int(tv.sum())
-                seen["ctx"] += int((tv * kv - tv * (tv - 1) // 2).sum())
+            valid = np.asarray(ex["slot_ids"]) >= 0
+            seen["lanes"] += int(valid.sum())
+            seen["ctx"] += int(np.asarray(ex["kv_len"])[valid].sum())
+            seen["grid"] += ex["dec_kv_len"].size + (
+                ex["chunk_q_pos"].size if "chunk_q_pos" in ex else 0)
             return fn(*a)
         setattr(eng, attr, counted)
     for attr, idx in (("_packed_fn", 5), ("_packed_spec_fn", 5),
-                      ("_packed_async_fn", 7), ("_step_fn", 4)):
+                      ("_packed_async_fn", 7)):
         wrap(attr, idx)
     return seen
 
 
 @pytest.mark.parametrize("layout", ["packed", "async", "speculative",
-                                    "lockstep"])
+                                    "small_budget"])
 def test_work_counters_match_step_inputs(served, rng, layout):
     """padding_stats()' ctx_positions is the sum of every valid lane's
     causal context the step functions are handed, sampled_lanes the lanes
     sampled and grid_rows the attention grids' query rows, on every step
-    path."""
+    path, and at a token budget of max_batch lanes."""
     cfg, params = served
     kw = dict(max_batch=4, max_len=128, block_size=8)
     if layout == "speculative":
         kw.update(prefix_sharing=True, decode_sharing=True, speculative=True,
                   draft_len=4)
+    if layout == "small_budget":
+        kw.update(token_budget=4)
     eng = PagedEngine(params, cfg.replace(cache_layout="paged"),
-                      packed=layout != "lockstep",
                       async_loop=layout == "async", **kw)
     seen = _count_step_inputs(eng)
     done = []
@@ -341,8 +334,7 @@ def test_work_counters_match_step_inputs(served, rng, layout):
     pad = eng.padding_stats()
     assert pad["ctx_positions"] == seen["ctx"] > 0
     assert pad["lanes_valid"] == seen["lanes"]
-    assert pad["grid_rows"] == seen["grid"]
-    assert (pad["grid_rows"] > 0) == (layout != "lockstep")
+    assert pad["grid_rows"] == seen["grid"] > 0
     tokens = sum(len(r.out_tokens) for r in done)
     if layout == "speculative":
         assert pad["sampled_lanes"] >= tokens
@@ -382,7 +374,7 @@ def test_snapshot_schema_stability(served, rng, enabled):
     for name, eng in engines.items():
         snap = eng.snapshot()
         assert set(snap) == SNAPSHOT_KEYS
-        assert snap["schema_version"] == 5
+        assert snap["schema_version"] == 6
         assert snap["engine"] == name
         assert snap["robustness"] is None    # none of these are robust
         assert set(snap["kv_cache"]) == {"cache_bytes_logical",
@@ -444,7 +436,7 @@ def test_snapshot_mid_run_never_crashes(served, rng):
     cfg, params = served
     eng = PagedEngine(params, cfg.replace(cache_layout="paged",
                                           speculative=True),
-                      max_batch=4, max_len=64, block_size=8, packed=True,
+                      max_batch=4, max_len=64, block_size=8,
                       prefix_sharing=True, decode_sharing=True,
                       telemetry=Telemetry(enabled=True))
     # before ANY work: no steps, no finished requests, empty trie
@@ -546,7 +538,7 @@ def test_deadline_and_telemetry_share_one_clock(served, name):
               admission=AdmissionConfig())          # note: no clock override
     if name == "paged":
         eng = PagedEngine(params, cfg.replace(cache_layout="paged"),
-                          block_size=8, packed=True, **kw)
+                          block_size=8, **kw)
     else:
         eng = ContinuousEngine(params, cfg, **kw)
     rng = np.random.default_rng(0)

@@ -111,7 +111,7 @@ def test_packed_prefill_compiles(one_chip, dtype, n, bs, static_max):
 
 @pytest.mark.parametrize("dtype,n,bs,static_max", POOLS)
 def test_paged_decode_compiles(one_chip, dtype, n, bs, static_max):
-    """The lockstep decode kernel with 32k-token block tables, whose (B, nblk)
+    """The paged decode kernel with 32k-token block tables, whose (B, nblk)
     table is scalar-prefetched into SMEM."""
     s = _spec(one_chip)
     quant = dtype == jnp.int8
